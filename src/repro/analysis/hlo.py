@@ -49,12 +49,8 @@ COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
 
 
 def xla_cost_analysis(compiled) -> dict:
-    """Version-proof ``compiled.cost_analysis()``: jax <= 0.4.x returns a
-    one-element list of dicts (per program), newer jax returns the dict."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
+    """``compiled.cost_analysis()``, or {} where the backend has none."""
+    return compiled.cost_analysis() or {}
 
 
 def type_bytes(type_str: str) -> int:

@@ -19,6 +19,7 @@ from pathlib import Path
 
 from repro.core.appspec import AppSpec, parse_appfile
 from repro.core.build import BuildService
+from repro.core.jobs import JobState
 from repro.core.jobspec import JobSpec, parse_jobspec
 from repro.core.middleware import Middleware
 from repro.core.package import write_package
@@ -66,7 +67,7 @@ def run_easey(appspec: AppSpec, target_name: str, jobspec: JobSpec,
     return mw, job_id, result
 
 
-def _cli():
+def _cli(argv=None):
     p = argparse.ArgumentParser(prog="easey")
     sub = p.add_subparsers(dest="cmd", required=True)
 
@@ -86,7 +87,7 @@ def _cli():
     r.add_argument("--target", required=True)
     r.add_argument("--config", required=True)
 
-    args = p.parse_args()
+    args = p.parse_args(argv)
     if args.cmd == "build":
         spec = parse_appfile(Path(args.appfile).read_text())
         res = BuildService().build(spec, args.target)
@@ -103,11 +104,16 @@ def _cli():
         spec = parse_jobspec(Path(args.config).read_text())
         mw, job_id, _ = run_easey(app, args.target, spec)
         out, err = mw.logs(job_id)
-        print(f"jobID={job_id} state={mw.status(job_id).value}")
+        state = mw.status(job_id)
+        print(f"jobID={job_id} state={state.value}")
         print(out)
         if err:
             print("STDERR:", err)
+        if state is not JobState.FINISHED:
+            raise SystemExit(1)
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     _cli()

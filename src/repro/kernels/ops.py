@@ -1,8 +1,13 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-`interpret` defaults to True unless running on a real TPU — the EASEY
-AutoTuner flips the implementation per target (plan.kernels), which is the
-paper's `###includelocalmpi###` mechanism applied to compute libraries.
+``interpret_mode`` is the one place the Pallas execution mode is decided:
+compiled on a TPU, interpreted on any other platform.  The wrappers take
+no ``interpret`` argument, so no caller can run a kernel interpreted on a
+TPU; the serving engine prints the mode once, so a run always says how
+its kernels executed.  The EASEY
+AutoTuner picks kernel vs reference ops per target (plan.kernels), which
+is the paper's `###includelocalmpi###` mechanism applied to compute
+libraries.
 """
 
 from __future__ import annotations
@@ -17,45 +22,39 @@ from repro.kernels.rmsnorm import rmsnorm_pallas
 from repro.kernels.sedov_stencil import cfl_dt, sedov_step_pallas
 
 
-def _default_interpret() -> bool:
+def interpret_mode() -> bool:
+    """True (Pallas interpreter) unless JAX's default backend is a TPU,
+    which always runs the kernels compiled."""
     return jax.default_backend() != "tpu"
 
 
-@partial(jax.jit, static_argnames=("causal", "block_q", "block_k", "kv_len",
-                                   "interpret"))
+@partial(jax.jit, static_argnames=("causal", "block_q", "block_k", "kv_len"))
 def flash_attention(q, k, v, causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, kv_len: int | None = None,
-                    interpret: bool | None = None):
-    interpret = _default_interpret() if interpret is None else interpret
+                    block_k: int = 128, kv_len: int | None = None):
     return flash_attention_pallas(q, k, v, causal=causal, block_q=block_q,
                                   block_k=block_k, kv_len=kv_len,
-                                  interpret=interpret)
+                                  interpret=interpret_mode())
 
 
-@partial(jax.jit, static_argnames=("interpret",))
-def paged_attention(q, k_pages, v_pages, page_table, kv_len,
-                    interpret: bool | None = None):
+@jax.jit
+def paged_attention(q, k_pages, v_pages, page_table, kv_len):
     """Fused paged decode attention (see kernels/paged_attention.py).
 
     q: (slots, H, dh); k_pages/v_pages: (num_pages, page_size, K, dh);
     page_table: (slots, max_pages) int32; kv_len: (slots,) int32.
     """
-    interpret = _default_interpret() if interpret is None else interpret
     return paged_attention_pallas(q, k_pages, v_pages, page_table, kv_len,
-                                  interpret=interpret)
+                                  interpret=interpret_mode())
 
 
-@partial(jax.jit, static_argnames=("eps", "block_rows", "interpret"))
-def rmsnorm(x, w, eps: float = 1e-6, block_rows: int = 256,
-            interpret: bool | None = None):
-    interpret = _default_interpret() if interpret is None else interpret
+@partial(jax.jit, static_argnames=("eps", "block_rows"))
+def rmsnorm(x, w, eps: float = 1e-6, block_rows: int = 256):
     return rmsnorm_pallas(x, w, eps=eps, block_rows=block_rows,
-                          interpret=interpret)
+                          interpret=interpret_mode())
 
 
-def sedov_step_kernel(state: dict, cfg, block_x: int = 16,
-                      interpret: bool | None = None) -> dict:
+def sedov_step_kernel(state: dict, cfg, block_x: int = 16) -> dict:
     """Fused LULESH step: global CFL reduction + Pallas stencil update."""
-    interpret = _default_interpret() if interpret is None else interpret
     dt = cfl_dt(state)
-    return sedov_step_pallas(state, dt, block_x=block_x, interpret=interpret)
+    return sedov_step_pallas(state, dt, block_x=block_x,
+                             interpret=interpret_mode())

@@ -12,12 +12,7 @@ from __future__ import annotations
 import math
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.6: explicit axis types (Auto matches the old behaviour)
-    from jax.sharding import AxisType
-except ImportError:  # older jax: make_mesh has no axis_types kwarg
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
@@ -29,9 +24,8 @@ def _mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
             f"available — the dry-run must set "
             f"XLA_FLAGS=--xla_force_host_platform_device_count=512 before "
             f"importing jax (see launch/dryrun.py)")
-    kw = {} if AxisType is None else \
-        {"axis_types": (AxisType.Auto,) * len(axes)}
-    return jax.make_mesh(shape, axes, devices=devs[:need], **kw)
+    return jax.make_mesh(shape, axes, devices=devs[:need],
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

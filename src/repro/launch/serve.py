@@ -90,7 +90,7 @@ def _resolve_slo(slo_ttft: int, slo_e2e: int, plan) -> tuple[int, int]:
 
 def serve_main(arch: str = "deepseek-7b-smoke", batch: int = 4,
                prefill_len: int = 64, decode_tokens: int = 16,
-               target: str = "local:cpu", seed: int = 0,
+               target: str | None = None, seed: int = 0,
                mode: str = "continuous", requests: int = 0,
                max_len: int = 0, kv_layout: str = "contiguous",
                page_size: int = 0, temperature: float = 0.0,
@@ -381,7 +381,7 @@ def _write_telemetry(metrics, tracer, trace_out, metrics_out, prom_out,
 
 
 def _legacy_serve_main(arch: str, batch: int, prefill_len: int,
-                       decode_tokens: int, target: str, seed: int,
+                       decode_tokens: int, target: str | None, seed: int,
                        log=print) -> dict:
     """Fixed-batch prefill-all/decode-all (pre-engine behaviour)."""
     import jax
@@ -389,7 +389,7 @@ def _legacy_serve_main(arch: str, batch: int, prefill_len: int,
 
     from repro.core.appspec import AppSpec
     from repro.core.build import BuildService
-    from repro.core.target import get_target
+    from repro.core.target import serve_target
     from repro.models.params import init_params
     from repro.models.transformer import model_for
     from repro.training.steps import build_decode_step, build_prefill_step
@@ -398,7 +398,7 @@ def _legacy_serve_main(arch: str, batch: int, prefill_len: int,
                   shape_overrides={"seq_len": prefill_len,
                                    "global_batch": batch},
                   run=f"serve --decode {decode_tokens}")
-    tgt = get_target(target)
+    tgt = serve_target(target)
     result = BuildService().build(app, tgt, lower=False)
     cfg = app.model_config
     model = model_for(cfg, remat="none")
@@ -609,4 +609,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

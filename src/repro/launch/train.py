@@ -28,7 +28,7 @@ from repro.training.steps import init_train_state
 
 
 def train_main(arch: str = "deepseek-7b-smoke", steps: int = 20,
-               target: str = "local:cpu", seq_len: int = 64,
+               target: str | None = None, seq_len: int = 64,
                global_batch: int = 4, ckpt_dir: str | None = None,
                ckpt_every: int = 5, async_ckpt: bool = True,
                fail_at: tuple[int, ...] = (), resume: bool = True,
@@ -104,7 +104,8 @@ def main(argv=None):
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default="deepseek-7b-smoke")
     p.add_argument("--steps", type=int, default=20)
-    p.add_argument("--target", default="local:cpu")
+    p.add_argument("--target", default=None,
+                   help="registered target (default: the attached devices)")
     p.add_argument("--seq-len", type=int, default=64)
     p.add_argument("--global-batch", type=int, default=4)
     p.add_argument("--ckpt-dir", default=None)
@@ -120,4 +121,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -18,6 +18,7 @@ for* a target rather than edited by the user.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Callable
 
@@ -64,8 +65,23 @@ def param_count(table: ParamTable) -> int:
     return total
 
 
+@functools.partial(jax.jit, static_argnums=(1, 3))
+def _normal_leaf(key: jax.Array, shape: tuple[int, ...], scale: jax.Array,
+                 dtype) -> jax.Array:
+    # one jitted program per leaf: the float32 draw fuses into the cast,
+    # so a stacked full-width leaf never holds a float32 copy of itself.
+    # The (no-op) reduce_precision keeps XLA from folding `scale` into
+    # the draw's own sqrt(2) factor, so the bits equal the eager
+    # draw-then-scale that earlier weights were made with
+    x = jax.lax.reduce_precision(jax.random.normal(key, shape, jnp.float32),
+                                 exponent_bits=8, mantissa_bits=23)
+    return (x * scale).astype(dtype)
+
+
 def init_params(table: ParamTable, rng: jax.Array, dtype=None):
-    """Materialize weights. Only used for runnable (small/smoke) configs."""
+    """Materialize weights from ``rng`` (smoke configs on the CPU, and
+    full-width configs on an accelerator: the peak is the weights plus one
+    program's temporaries, not a float32 copy of the largest leaf)."""
     leaves, treedef = jax.tree.flatten(
         _map_table(table, lambda d: d), is_leaf=lambda x: isinstance(x, ParamDef))
     keys = jax.random.split(rng, len(leaves))
@@ -84,7 +100,7 @@ def init_params(table: ParamTable, rng: jax.Array, dtype=None):
             else:
                 fan_in = d.shape[0] if len(d.shape) >= 2 else max(d.shape[-1], 1)
                 scale = 1.0 / math.sqrt(fan_in)
-            out.append((jax.random.normal(key, d.shape, jnp.float32) * scale).astype(dt))
+            out.append(_normal_leaf(key, d.shape, jnp.float32(scale), dt))
     return jax.tree.unflatten(treedef, out)
 
 

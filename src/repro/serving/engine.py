@@ -52,6 +52,12 @@ comes from, never its bits.
 * ``"auto"`` (default) — follow the tuner (``plan.serve_kv_kernel``:
   pallas targets get the kernel, reference targets the gather).
 
+The kernel runs compiled on a TPU and in the Pallas interpreter on any
+other platform (``kernels.ops.interpret_mode``); the engine logs its
+target, platform, kernel and interpret mode when it is built.
+``target=None`` (default) is the one-chip target of the first attached
+device (``core.target.serve_target``): serving runs on one chip.
+
 Both implementations are token-identical (the equivalence sweep in
 tests/test_kernels_paged.py and the engine-level stream check in
 tests/test_serving_paged.py hold them to it).
@@ -92,7 +98,8 @@ import jax.numpy as jnp
 
 from repro.core.appspec import AppSpec
 from repro.core.build import BuildService
-from repro.core.target import get_target
+from repro.core.target import serve_target
+from repro.kernels.ops import interpret_mode
 from repro.models.params import init_params
 from repro.models.transformer import model_for
 from repro.serving.pool import KVCachePool, PagedKVCachePool
@@ -115,7 +122,7 @@ class ServeEngine:
     """One model + one KV pool + jitted steps; runs request traces."""
 
     def __init__(self, arch: str = "deepseek-7b-smoke",
-                 target: str = "local:cpu", num_slots: int = 8,
+                 target: str | None = None, num_slots: int = 8,
                  max_len: int = 128, seed: int = 0,
                  eos_id: int | None = None, kv_layout: str = "contiguous",
                  page_size: int = 0, num_pages: int = 0,
@@ -159,7 +166,7 @@ class ServeEngine:
             raise NotImplementedError(
                 "slot-wise decode does not support sliding-window attention "
                 "yet (the pool would attend the full history)")
-        tgt = get_target(target)
+        tgt = serve_target(target)
         result = BuildService().build(app, tgt, lower=False)
         self.plan = result.plan
         self.kv_layout = kv_layout
@@ -238,6 +245,10 @@ class ServeEngine:
             decode = build_decode_step_slots(self.model, self.mesh)
             chunk = build_prefill_chunk_step(self.model, self.mesh)
             verify = build_verify_step_slots(self.model, self.mesh)
+        log(f"[serve] target={tgt.name} platform={jax.default_backend()} "
+            f"layers={cfg.num_layers} kv_layout={kv_layout} "
+            f"kv_kernel={self.kv_kernel} interpret="
+            f"{interpret_mode() if self.kv_kernel == 'pallas' else None}")
         self._decode = jax.jit(decode, donate_argnums=(1,))
         # kv_bound (arg 6) is static: it sizes the chunk's KV read-back,
         # so the chunk jit cache is (chunk buckets) x (bound buckets)

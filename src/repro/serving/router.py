@@ -582,7 +582,7 @@ class ReplicaRouter:
 
     @classmethod
     def build(cls, arch: str = "deepseek-7b-smoke",
-              target: str = "local:cpu", replicas: int = 2,
+              target: str | None = None, replicas: int = 2,
               kv_layout: str = "contiguous", num_slots: int = 8,
               max_len: int = 128, seed: int = 0, eos_id: int | None = None,
               policy: str = "least_loaded", page_size: int = 0,

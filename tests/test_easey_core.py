@@ -3,6 +3,7 @@ package integrity, middleware staging, job state machine, tuner."""
 
 import json
 import tarfile
+from pathlib import Path
 
 import pytest
 
@@ -222,3 +223,81 @@ def test_tuner_replica_split_napkin_renders_and_roundtrips():
     assert again.serve_num_pages == plan.serve_num_pages
     # replicas=1 keeps the original single-engine phrasing
     assert "per replica" not in _serve_plan(1).napkin["serve_pool"]
+
+
+@pytest.mark.parametrize("platform,kind,count,want", [
+    ("cpu", "cpu", 1, "local:cpu"),
+    ("tpu", "TPU v5 lite", 1, "local:tpu-v5e"),
+    ("tpu", "TPU v5 lite", 4, "local:tpu-v5e-2x2"),
+    ("tpu", "TPU v9 imaginary", 1, None),
+    ("tpu", "TPU v5 lite", 3, None),
+])
+def test_target_follows_the_attached_devices(platform, kind, count, want):
+    """Entry points default to the attached devices' target; a device the
+    registry does not know is an error, never another chip's peaks."""
+    import types
+    from repro.core.target import target_for_devices
+    devs = [types.SimpleNamespace(platform=platform, device_kind=kind)] * count
+    if want is None:
+        with pytest.raises(KeyError, match="no target"):
+            target_for_devices(devs)
+        return
+    t = target_for_devices(devs)
+    assert t.name == want and t.num_chips == count
+    if platform == "tpu":
+        assert t.kernels == "pallas" and t.hbm_bytes == 16 * 2**30
+    assert get_target().name == "local:cpu"     # the tests' own platform
+
+
+def test_compile_cache_dir_is_the_env_or_a_fixed_checkout_path(
+        monkeypatch, tmp_path):
+    """The entry points' compile cache: JAX's own variable wins and the
+    code sets nothing; unset, it is <checkout>/.jax_cache every time (no
+    temp name, pid or time).  jax.config.update is captured, so this test
+    never turns the cache on."""
+    import jax
+    from repro.launch import compile_cache as cc
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *a: calls.append(a))
+    monkeypatch.setenv(cc.CACHE_ENV, str(tmp_path))
+    assert cc.enable_compile_cache() == str(tmp_path)
+    assert calls == []
+    monkeypatch.delenv(cc.CACHE_ENV)
+    first = cc.enable_compile_cache()
+    assert first == cc.enable_compile_cache()
+    assert first == str(Path(__file__).resolve().parents[1] / ".jax_cache")
+    assert calls == [("jax_compilation_cache_dir", first)] * 2
+
+
+@pytest.mark.parametrize("count", [1, 4])
+def test_serving_takes_one_chip_of_the_host(monkeypatch, count):
+    """Serving never shards over a mesh: with no target named, the engine
+    and the serve CLI take the first attached chip alone, on a four-chip
+    host too, while the build/train default takes the whole host."""
+    import types
+
+    import jax
+    from repro.core.target import serve_target
+    from repro.serving import engine as engine_mod
+    from repro.serving.router import ReplicaRouter
+    devs = [types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+            ] * count
+    monkeypatch.setattr(jax, "devices", lambda *a: devs)
+    assert serve_target().name == "local:tpu-v5e"
+    assert serve_target().num_chips == 1
+    assert get_target().num_chips == count
+    assert serve_target("local:cpu").name == "local:cpu"
+
+    # the engine and the router build with that target by default
+    class Built(Exception):
+        pass
+
+    def build(self, app, target, **kw):
+        raise Built(target.name)
+
+    monkeypatch.setattr(engine_mod.BuildService, "build", build)
+    for make in (lambda: engine_mod.ServeEngine(kv_layout="paged"),
+                 lambda: ReplicaRouter.build(replicas=1)):
+        with pytest.raises(Built, match="^local:tpu-v5e$"):
+            make()

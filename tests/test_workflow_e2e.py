@@ -124,3 +124,31 @@ def test_deployment_equivalence_easey_vs_direct(small_app):
                      out_shardings=res.out_shardings,
                      donate_argnums=(0,)).lower(*res.in_structs)
     assert direct.as_text() == easey_hlo
+
+
+@pytest.mark.parametrize("command,code", [
+    ("train --steps 1 --seq-len 32 --global-batch 2", None),
+    ("explode --now", 1),
+])
+def test_easey_run_cli_exit_code_follows_job_state(tmp_path, small_app,
+                                                   command, code, capsys):
+    """`easey run` exits 0 only when the job finished: a failed job
+    prints state=failed and exits non-zero, so a shell or a CI step sees
+    the failure."""
+    from repro.core.workflow import _cli
+    appfile = tmp_path / "Appfile"
+    appfile.write_text(small_app.to_appfile())
+    config = tmp_path / "job.json"
+    config.write_text(json.dumps({
+        "job": {"name": "cli"},
+        "execution": [{"serial": {"command": command}}]}))
+    argv = ["run", str(appfile), "--target", "local:cpu",
+            "--config", str(config)]
+    if code is None:
+        _cli(argv)
+        assert "state=finished" in capsys.readouterr().out
+    else:
+        with pytest.raises(SystemExit) as exc:
+            _cli(argv)
+        assert exc.value.code == code
+        assert "state=failed" in capsys.readouterr().out
