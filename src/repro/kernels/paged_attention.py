@@ -216,6 +216,8 @@ def paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((slots, G, K, dh), q.dtype),
         interpret=interpret,
+        # the op's name in a profiler trace, whatever wraps the call
+        name="paged_attention",
     )(page_table.astype(jnp.int32), kv_len.astype(jnp.int32),
       qg, k_pages, v_pages)
     return out.transpose(0, 2, 1, 3).reshape(slots, H, dh)

@@ -14,6 +14,7 @@ legacy fixed-batch path so `serve --arch xlstm-1.3b-smoke` still works.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import time
 from pathlib import Path
@@ -104,7 +105,8 @@ def serve_main(arch: str = "deepseek-7b-smoke", batch: int = 4,
                slo_e2e: int = 0, admission: str = "queue",
                autoscale: int = 0, trace_out: str | None = None,
                metrics_out: str | None = None,
-               prom_out: str | None = None, log=print) -> dict:
+               prom_out: str | None = None,
+               profile_dir: str | None = None, log=print) -> dict:
     """Serve `requests` requests (default: one per slot) of `prefill_len`
     prompts, `decode_tokens` generations each.  Reports per-request latency
     and aggregate tokens/sec.  With ``replicas`` > 1 the requests flow
@@ -143,7 +145,10 @@ def serve_main(arch: str = "deepseek-7b-smoke", batch: int = 4,
     per replica, one "thread" per slot, all timestamps in virtual steps
     — byte-identical across identical runs); ``metrics_out`` writes the
     flat ``to_metrics()`` snapshot as JSON (NaN -> null); ``prom_out``
-    writes the same snapshot in Prometheus text exposition format."""
+    writes the same snapshot in Prometheus text exposition format.
+    ``profile_dir`` runs the drain under ``jax.profiler.trace``: the
+    scheduler's ``serve.*`` spans and the device's ops, scoped by
+    ``jax.named_scope``, on one clock (README, "Profiling")."""
     cfg = get_config(arch)
     if trace not in TRACES:
         raise ValueError(f"trace {trace!r} not in {tuple(TRACES)}")
@@ -161,9 +166,10 @@ def serve_main(arch: str = "deepseek-7b-smoke", batch: int = 4,
             f"--autoscale {autoscale} must be in [1, --replicas={replicas}]")
     from repro.serving.engine import SERVABLE_FAMILIES
     if cfg.family not in SERVABLE_FAMILIES:
-        if trace_out or metrics_out or prom_out:
+        if trace_out or metrics_out or prom_out or profile_dir:
             raise NotImplementedError(
-                f"--trace-out/--metrics-out/--prom-out need an engine-"
+                f"--trace-out/--metrics-out/--prom-out/--profile-dir need "
+                f"an engine-"
                 f"servable family {SERVABLE_FAMILIES}; {arch} "
                 f"({cfg.family}) is served by the legacy static path, "
                 f"which has no scheduler to trace")
@@ -195,7 +201,7 @@ def serve_main(arch: str = "deepseek-7b-smoke", batch: int = 4,
             arrivals=arrivals, arrival_gap=arrival_gap, slo_ttft=slo_ttft,
             slo_e2e=slo_e2e, admission=admission, autoscale=autoscale,
             trace_out=trace_out, metrics_out=metrics_out,
-            prom_out=prom_out, log=log)
+            prom_out=prom_out, profile_dir=profile_dir, log=log)
     engine = ServeEngine(arch=arch, target=target, num_slots=batch,
                          max_len=pool_len, seed=seed, kv_layout=kv_layout,
                          page_size=page_size, prefill_chunk=prefill_chunk,
@@ -213,8 +219,9 @@ def serve_main(arch: str = "deepseek-7b-smoke", batch: int = 4,
     if trace_out:
         from repro.serving import Tracer
         tracer = Tracer()
-    stats = engine.run(reqs, policy=mode, slo_ttft_steps=slo_ttft,
-                       slo_e2e_steps=slo_e2e, tracer=tracer)
+    with _profiled(profile_dir, log):
+        stats = engine.run(reqs, policy=mode, slo_ttft_steps=slo_ttft,
+                           slo_e2e_steps=slo_e2e, tracer=tracer)
     for r in stats.results:
         log(f"[serve]   req {r.rid}: {r.prompt_len}+{len(r.tokens)} tokens, "
             f"ttft {r.ttft_s*1e3:.1f}ms, latency {r.latency_s*1e3:.1f}ms")
@@ -273,7 +280,7 @@ def _router_serve_main(arch, batch, prefill_len, decode_tokens, target,
                        trace="uniform", arrivals="closed", arrival_gap=4.0,
                        slo_ttft=0, slo_e2e=0, admission="queue",
                        autoscale=0, trace_out=None, metrics_out=None,
-                       prom_out=None, log=print) -> dict:
+                       prom_out=None, profile_dir=None, log=print) -> dict:
     """Multi-replica path: ReplicaRouter over N tuner-split engines."""
     from repro.serving import AutoscalePolicy, ReplicaRouter, with_arrivals
     cfg = get_config(arch)
@@ -297,9 +304,10 @@ def _router_serve_main(arch, batch, prefill_len, decode_tokens, target,
     if trace_out:
         from repro.serving import Tracer
         tracer = Tracer()
-    stats = router.run(reqs, policy=mode, slo_ttft_steps=slo_ttft,
-                       slo_e2e_steps=slo_e2e, admission=admission,
-                       autoscale=policy_obj, tracer=tracer)
+    with _profiled(profile_dir, log):
+        stats = router.run(reqs, policy=mode, slo_ttft_steps=slo_ttft,
+                           slo_e2e_steps=slo_e2e, admission=admission,
+                           autoscale=policy_obj, tracer=tracer)
     for rej in stats.rejected:
         log(f"[serve]   req {rej.rid} REJECTED at v{rej.v_reject}: "
             f"{rej.reason}")
@@ -349,6 +357,15 @@ def _router_serve_main(arch, batch, prefill_len, decode_tokens, target,
     _write_telemetry(out["metrics"], tracer, trace_out, metrics_out,
                      prom_out, log)
     return out
+
+
+def _profiled(profile_dir, log=print):
+    """``jax.profiler.trace(profile_dir)`` around the drain, or nothing."""
+    if not profile_dir:
+        return contextlib.nullcontext()
+    import jax
+    log(f"[serve] profiling the drain -> {profile_dir}")
+    return jax.profiler.trace(profile_dir)
 
 
 def _write_telemetry(metrics, tracer, trace_out, metrics_out, prom_out,
@@ -583,6 +600,14 @@ def main(argv=None):
     p.add_argument("--prom-out", default=None,
                    help="write the metrics snapshot in Prometheus text "
                         "exposition format to PATH after the run")
+    p.add_argument("--profile-dir", default=None,
+                   help="run the drain under jax.profiler.trace, writing "
+                        "the profile to DIR: the scheduler's serve.* "
+                        "spans and the device's ops (named by "
+                        "jax.named_scope) on one clock.  serve.step "
+                        "carries the tick's vstep, which lines it up "
+                        "with --trace-out.  Load in TensorBoard's "
+                        "profile plugin or https://ui.perfetto.dev")
     p.add_argument("--temperature", type=float, default=0.0,
                    help="sampling temperature (0 = greedy)")
     p.add_argument("--top-k", type=int, default=0,
@@ -605,7 +630,8 @@ def main(argv=None):
                arrival_gap=a.arrival_gap, slo_ttft=a.slo_ttft,
                slo_e2e=a.slo_e2e, admission=a.admission,
                autoscale=a.autoscale, trace_out=a.trace_out,
-               metrics_out=a.metrics_out, prom_out=a.prom_out)
+               metrics_out=a.metrics_out, prom_out=a.prom_out,
+               profile_dir=a.profile_dir)
 
 
 if __name__ == "__main__":

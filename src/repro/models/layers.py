@@ -236,25 +236,34 @@ def attention(p: dict, x: jax.Array, cfg, mesh, *, positions: jax.Array,
     kv_source: if given, cross-attention (keys/values from encoder output,
     non-causal, no rope on kv positions beyond source positions).
     Returns (out, new_cache).
+
+    The device trace names each part by ``jax.named_scope``: ``qkv``,
+    ``kv_write`` (the new K/V into the cache and the reshapes that feed
+    the read), ``attn`` (the kernel or the gather and softmax) and
+    ``out_proj``.
     """
     b, s, _ = x.shape
     cross = kv_source is not None
     src = kv_source if cross else x
-    q = jnp.einsum("bse,ehd->bshd", x, p["wq"])
-    k = jnp.einsum("bte,ekd->btkd", src, p["wk"])
-    v = jnp.einsum("bte,ekd->btkd", src, p["wv"])
-    if "bq" in p:
-        q = q + p["bq"][None, None]
-        k = k + p["bk"][None, None]
-        v = v + p["bv"][None, None]
-    q = shard_constraint(q, ("act_batch", "act_seq", "act_heads", None), mesh)
-    k = shard_constraint(k, ("act_batch", "act_seq", "act_kv_heads", None), mesh)
-    v = shard_constraint(v, ("act_batch", "act_seq", "act_kv_heads", None), mesh)
-
-    if cfg.pos == "rope" and not cross:
-        src_pos = positions
-        q = apply_rope(q, positions, fraction=cfg.rope_fraction, theta=cfg.rope_theta)
-        k = apply_rope(k, src_pos, fraction=cfg.rope_fraction, theta=cfg.rope_theta)
+    with jax.named_scope("qkv"):
+        q = jnp.einsum("bse,ehd->bshd", x, p["wq"])
+        k = jnp.einsum("bte,ekd->btkd", src, p["wk"])
+        v = jnp.einsum("bte,ekd->btkd", src, p["wv"])
+        if "bq" in p:
+            q = q + p["bq"][None, None]
+            k = k + p["bk"][None, None]
+            v = v + p["bv"][None, None]
+        q = shard_constraint(q, ("act_batch", "act_seq", "act_heads", None),
+                             mesh)
+        k = shard_constraint(k, ("act_batch", "act_seq", "act_kv_heads",
+                                 None), mesh)
+        v = shard_constraint(v, ("act_batch", "act_seq", "act_kv_heads",
+                                 None), mesh)
+        if cfg.pos == "rope" and not cross:
+            q = apply_rope(q, positions, fraction=cfg.rope_fraction,
+                           theta=cfg.rope_theta)
+            k = apply_rope(k, positions, fraction=cfg.rope_fraction,
+                           theta=cfg.rope_theta)
 
     new_cache = None
     if mode == "decode":
@@ -277,88 +286,98 @@ def attention(p: dict, x: jax.Array, cfg, mesh, *, positions: jax.Array,
             n_pages, psize = cache["k"].shape[0], cache["k"].shape[1]
             max_pages = pages.shape[1]
             Kh, dh = k.shape[2], k.shape[3]
-            if s == 1:
-                logical_page = idx // psize
-                ok = logical_page < max_pages
-                dest = jnp.take_along_axis(
-                    pages, jnp.minimum(logical_page, max_pages - 1)[:, None],
-                    axis=1)[:, 0]                               # (slots,)
-                # out-of-range writes (a slot already at its page-run
-                # capacity) route to the reserved junk page 0 — NOT wrapped
-                # into the slot's last page, which under the prefix cache
-                # may be shared with a live request (same ok-guard as the
-                # chunk path below)
-                fpos = jnp.where(ok, dest * psize + idx % psize, idx % psize)
-                k_all = cache["k"].reshape(n_pages * psize, Kh, dh).at[fpos] \
-                    .set(k[:, 0]).reshape(n_pages, psize, Kh, dh)
-                v_all = cache["v"].reshape(n_pages * psize, Kh, dh).at[fpos] \
-                    .set(v[:, 0]).reshape(n_pages, psize, Kh, dh)
-            else:
-                # VERIFY burst: each row writes s speculative positions
-                # idx..idx+s-1.  Per-position page lookup keeps the same
-                # junk-page-0 ok-guard, so a burst past a slot's page-run
-                # capacity can never scribble into a (possibly
-                # prefix-shared) live page.
-                pos = idx[:, None] + jnp.arange(s)[None, :]     # (slots, s)
-                logical_page = pos // psize
-                ok = logical_page < max_pages
-                dest = jnp.take_along_axis(
-                    pages, jnp.minimum(logical_page, max_pages - 1), axis=1)
-                fpos = jnp.where(ok, dest * psize + pos % psize, pos % psize)
-                k_all = cache["k"].reshape(n_pages * psize, Kh, dh).at[fpos] \
-                    .set(k).reshape(n_pages, psize, Kh, dh)
-                v_all = cache["v"].reshape(n_pages * psize, Kh, dh).at[fpos] \
-                    .set(v).reshape(n_pages, psize, Kh, dh)
-            if cache.get("use_kernel") and s == 1:
-                # fused Pallas path (single-token decode only; verify
-                # bursts take the gather path): the page table is walked
-                # inside the kernel, so the materialized
-                # (slots, max_pages*psize, K, dh) gather never hits HBM
-                from repro.kernels.ops import paged_attention
-                out = paged_attention(q[:, 0], k_all, v_all, pages,
-                                      (idx + s).astype(jnp.int32))[:, None]
-            else:
-                kg = jnp.take(k_all, pages, axis=0).reshape(
-                    q.shape[0], max_pages * psize, Kh, dh)
-                vg = jnp.take(v_all, pages, axis=0).reshape(
-                    q.shape[0], max_pages * psize, Kh, dh)
-                out = dot_attention(q, kg, vg, causal=True, q_offset=idx,
-                                    kv_len=idx + s)
-        elif jnp.ndim(idx) == 1:
-            # SLOT-WISE decode (continuous batching): every row is a pool
-            # slot at its own length.  The new kv lands at each row's own
-            # position (one-hot select — a per-row scatter that XLA fuses),
-            # and the mask is per-row causal-with-length.  Window is not
-            # applied: pool slots are already bounded by max_len.
-            if s == 1:
-                hit = (jnp.arange(t)[None, :] == idx[:, None])[..., None, None]
-                k_all = jnp.where(hit, k, cache["k"])
-                v_all = jnp.where(hit, v, cache["v"])
-            else:
-                # VERIFY burst: scatter s speculative positions per row;
-                # positions past max_len drop (the host caps acceptance at
-                # the slot's backed capacity, so dropped writes are never
-                # attended)
-                rows = jnp.arange(q.shape[0])[:, None]          # (slots, 1)
-                pos = idx[:, None] + jnp.arange(s)[None, :]     # (slots, s)
-                k_all = cache["k"].at[rows, pos].set(k, mode="drop")
-                v_all = cache["v"].at[rows, pos].set(v, mode="drop")
-            out = dot_attention(q, k_all, v_all, causal=True, q_offset=idx,
-                                kv_len=idx + s)
-        elif window is not None and t <= window:
-            # RING BUFFER: cache holds only the last `t` positions.  Keys
+            with jax.named_scope("kv_write"):
+                if s == 1:
+                    logical_page = idx // psize
+                    ok = logical_page < max_pages
+                    dest = jnp.take_along_axis(
+                        pages,
+                        jnp.minimum(logical_page, max_pages - 1)[:, None],
+                        axis=1)[:, 0]                           # (slots,)
+                    # out-of-range writes (a slot already at its page-run
+                    # capacity) route to the reserved junk page 0 — NOT
+                    # wrapped into the slot's last page, which under the
+                    # prefix cache may be shared with a live request (same
+                    # ok-guard as the chunk path below)
+                    fpos = jnp.where(ok, dest * psize + idx % psize,
+                                     idx % psize)
+                    new_k, new_v = k[:, 0], v[:, 0]
+                else:
+                    # VERIFY burst: each row writes s speculative positions
+                    # idx..idx+s-1.  Per-position page lookup keeps the
+                    # same junk-page-0 ok-guard, so a burst past a slot's
+                    # page-run capacity can never scribble into a
+                    # (possibly prefix-shared) live page.
+                    pos = idx[:, None] + jnp.arange(s)[None, :]  # (slots, s)
+                    logical_page = pos // psize
+                    ok = logical_page < max_pages
+                    dest = jnp.take_along_axis(
+                        pages, jnp.minimum(logical_page, max_pages - 1),
+                        axis=1)
+                    fpos = jnp.where(ok, dest * psize + pos % psize,
+                                     pos % psize)
+                    new_k, new_v = k, v
+                k_all = cache["k"].reshape(n_pages * psize, Kh, dh) \
+                    .at[fpos].set(new_k).reshape(n_pages, psize, Kh, dh)
+                v_all = cache["v"].reshape(n_pages * psize, Kh, dh) \
+                    .at[fpos].set(new_v).reshape(n_pages, psize, Kh, dh)
+            with jax.named_scope("attn"):
+                if cache.get("use_kernel") and s == 1:
+                    # fused Pallas path (single-token decode only; verify
+                    # bursts take the gather path): the page table is
+                    # walked inside the kernel, so the materialized
+                    # (slots, max_pages*psize, K, dh) gather never hits HBM
+                    from repro.kernels.ops import paged_attention
+                    out = paged_attention(
+                        q[:, 0], k_all, v_all, pages,
+                        (idx + s).astype(jnp.int32))[:, None]
+                else:
+                    kg = jnp.take(k_all, pages, axis=0).reshape(
+                        q.shape[0], max_pages * psize, Kh, dh)
+                    vg = jnp.take(v_all, pages, axis=0).reshape(
+                        q.shape[0], max_pages * psize, Kh, dh)
+                    out = dot_attention(q, kg, vg, causal=True,
+                                        q_offset=idx, kv_len=idx + s)
+        else:
+            # RING BUFFER (a scalar index and a window no longer than the
+            # cache): the cache holds only the last `t` positions.  Keys
             # carry absolute RoPE phases from write time, so order in the
             # buffer is irrelevant; everything valid is attendable.
-            write = jnp.mod(idx, t)
-            k_all = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, write, axis=1)
-            v_all = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, write, axis=1)
-            out = dot_attention(q, k_all, v_all, causal=False,
-                                kv_len=jnp.minimum(idx + s, t))
-        else:
-            k_all = jax.lax.dynamic_update_slice_in_dim(cache["k"], k, idx, axis=1)
-            v_all = jax.lax.dynamic_update_slice_in_dim(cache["v"], v, idx, axis=1)
-            out = dot_attention(q, k_all, v_all, causal=True, q_offset=idx,
-                                kv_len=idx + s)
+            ring = jnp.ndim(idx) == 0 and window is not None and t <= window
+            with jax.named_scope("kv_write"):
+                if jnp.ndim(idx) == 1 and s == 1:
+                    # SLOT-WISE decode (continuous batching): every row is
+                    # a pool slot at its own length.  The new kv lands at
+                    # each row's own position (one-hot select — a per-row
+                    # scatter that XLA fuses), and the mask is per-row
+                    # causal-with-length.  Window is not applied: pool
+                    # slots are already bounded by max_len.
+                    hit = (jnp.arange(t)[None, :] == idx[:, None]
+                           )[..., None, None]
+                    k_all = jnp.where(hit, k, cache["k"])
+                    v_all = jnp.where(hit, v, cache["v"])
+                elif jnp.ndim(idx) == 1:
+                    # slot-wise VERIFY burst: scatter s speculative
+                    # positions per row; positions past max_len drop (the
+                    # host caps acceptance at the slot's backed capacity,
+                    # so dropped writes are never attended)
+                    rows = jnp.arange(q.shape[0])[:, None]      # (slots, 1)
+                    pos = idx[:, None] + jnp.arange(s)[None, :]  # (slots, s)
+                    k_all = cache["k"].at[rows, pos].set(k, mode="drop")
+                    v_all = cache["v"].at[rows, pos].set(v, mode="drop")
+                else:
+                    write = jnp.mod(idx, t) if ring else idx
+                    k_all = jax.lax.dynamic_update_slice_in_dim(
+                        cache["k"], k, write, axis=1)
+                    v_all = jax.lax.dynamic_update_slice_in_dim(
+                        cache["v"], v, write, axis=1)
+            with jax.named_scope("attn"):
+                if ring:
+                    out = dot_attention(q, k_all, v_all, causal=False,
+                                        kv_len=jnp.minimum(idx + s, t))
+                else:
+                    out = dot_attention(q, k_all, v_all, causal=True,
+                                        q_offset=idx, kv_len=idx + s)
         new_cache = {"k": k_all, "v": v_all, "index": idx + s}
     elif mode == "chunk":
         # CHUNKED PREFILL written straight into the serving pool: x is one
@@ -384,46 +403,57 @@ def attention(p: dict, x: jax.Array, cfg, mesh, *, positions: jax.Array,
         bound = cache["kv_bound"]
         pos = off + jnp.arange(s)                   # (s,) global positions
         Kh, dh = k.shape[2], k.shape[3]
-        if "pages_row" in cache:
-            pages_row = cache["pages_row"]          # (max_pages,) int32
-            n_pages, psize = cache["k"].shape[0], cache["k"].shape[1]
-            max_pages = pages_row.shape[0]
-            logical = pos // psize
-            ok = logical < max_pages
-            dest = jnp.take(pages_row, jnp.minimum(logical, max_pages - 1))
-            fpos = jnp.where(ok, dest * psize + pos % psize, pos % psize)
-            k_all = cache["k"].reshape(n_pages * psize, Kh, dh) \
-                .at[fpos].set(k[0]).reshape(n_pages, psize, Kh, dh)
-            v_all = cache["v"].reshape(n_pages * psize, Kh, dh) \
-                .at[fpos].set(v[0]).reshape(n_pages, psize, Kh, dh)
-            B = min(-(-bound // psize), max_pages)
-            kg = jnp.take(k_all, pages_row[:B], axis=0).reshape(
-                1, B * psize, Kh, dh)
-            vg = jnp.take(v_all, pages_row[:B], axis=0).reshape(
-                1, B * psize, Kh, dh)
-        else:
-            k_all = cache["k"].at[slot, pos].set(k[0], mode="drop")
-            v_all = cache["v"].at[slot, pos].set(v[0], mode="drop")
-            L = min(bound, k_all.shape[1])
-            kg = jax.lax.dynamic_slice(
-                k_all, (slot, 0, 0, 0), (1, L, Kh, dh))
-            vg = jax.lax.dynamic_slice(
-                v_all, (slot, 0, 0, 0), (1, L, Kh, dh))
-        out = dot_attention(q, kg, vg, causal=True, q_offset=off,
-                            kv_len=off + s)
+        paged = "pages_row" in cache
+        with jax.named_scope("kv_write"):
+            if paged:
+                pages_row = cache["pages_row"]      # (max_pages,) int32
+                n_pages, psize = cache["k"].shape[0], cache["k"].shape[1]
+                max_pages = pages_row.shape[0]
+                logical = pos // psize
+                ok = logical < max_pages
+                dest = jnp.take(pages_row,
+                                jnp.minimum(logical, max_pages - 1))
+                fpos = jnp.where(ok, dest * psize + pos % psize,
+                                 pos % psize)
+                k_all = cache["k"].reshape(n_pages * psize, Kh, dh) \
+                    .at[fpos].set(k[0]).reshape(n_pages, psize, Kh, dh)
+                v_all = cache["v"].reshape(n_pages * psize, Kh, dh) \
+                    .at[fpos].set(v[0]).reshape(n_pages, psize, Kh, dh)
+            else:
+                k_all = cache["k"].at[slot, pos].set(k[0], mode="drop")
+                v_all = cache["v"].at[slot, pos].set(v[0], mode="drop")
+        with jax.named_scope("attn"):
+            if paged:
+                B = min(-(-bound // psize), max_pages)
+                kg = jnp.take(k_all, pages_row[:B], axis=0).reshape(
+                    1, B * psize, Kh, dh)
+                vg = jnp.take(v_all, pages_row[:B], axis=0).reshape(
+                    1, B * psize, Kh, dh)
+            else:
+                L = min(bound, k_all.shape[1])
+                kg = jax.lax.dynamic_slice(
+                    k_all, (slot, 0, 0, 0), (1, L, Kh, dh))
+                vg = jax.lax.dynamic_slice(
+                    v_all, (slot, 0, 0, 0), (1, L, Kh, dh))
+            out = dot_attention(q, kg, vg, causal=True, q_offset=off,
+                                kv_len=off + s)
         new_cache = {"k": k_all, "v": v_all}
     else:
         causal = (not cross) and cfg.causal
-        if window is not None and s > window and causal:
-            out = _windowed_attention(q, k, v, window)
-        else:
-            out = dot_attention(q, k, v, causal=causal)
+        with jax.named_scope("attn"):
+            if window is not None and s > window and causal:
+                out = _windowed_attention(q, k, v, window)
+            else:
+                out = dot_attention(q, k, v, causal=causal)
         if mode == "prefill" and not cross:
             new_cache = {"k": k, "v": v, "index": jnp.asarray(s, jnp.int32)}
 
-    out = shard_constraint(out, ("act_batch", "act_seq", "act_heads", None), mesh)
-    y = jnp.einsum("bshd,hde->bse", out, p["wo"])
-    return shard_constraint(y, ("act_batch", "act_seq", "act_embed"), mesh), new_cache
+    with jax.named_scope("out_proj"):
+        out = shard_constraint(out, ("act_batch", "act_seq", "act_heads",
+                                     None), mesh)
+        y = jnp.einsum("bshd,hde->bse", out, p["wo"])
+        y = shard_constraint(y, ("act_batch", "act_seq", "act_embed"), mesh)
+    return y, new_cache
 
 
 def _windowed_attention(q, k, v, window: int) -> jax.Array:

@@ -127,7 +127,8 @@ class DenseLM(BaseLM):
             cache=cache, window=window)
         x = x + attn_out
         h = L.apply_norm(p["ln2"], x, cfg.norm)
-        x = x + self.mlp_apply(p["mlp"], h, mesh)
+        with jax.named_scope("mlp"):
+            x = x + self.mlp_apply(p["mlp"], h, mesh)
         return x, new_cache
 
     def mlp_apply(self, p, h, mesh):
@@ -215,20 +216,25 @@ class DenseLM(BaseLM):
         return x, new_cache
 
     # ---- entry points ----
+    # the device trace names the three parts of every step by
+    # jax.named_scope: "embed", "layers" (the scan) and "logits"
     def embed_inputs(self, params, batch, mesh, positions):
-        return L.embed(params["embed"], batch["tokens"], self.cfg, mesh,
-                       positions=positions)
+        with jax.named_scope("embed"):
+            return L.embed(params["embed"], batch["tokens"], self.cfg, mesh,
+                           positions=positions)
 
     def logits_from(self, params, x, mesh):
-        x = L.apply_norm(params["ln_f"], x, self.cfg.norm)
-        return L.unembed(params["embed"], x, self.cfg, mesh)
+        with jax.named_scope("logits"):
+            x = L.apply_norm(params["ln_f"], x, self.cfg.norm)
+            return L.unembed(params["embed"], x, self.cfg, mesh)
 
     def loss(self, params, batch, mesh):
         cfg = self.cfg
         b, s = batch["tokens"].shape
         positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
         x = self.embed_inputs(params, batch, mesh, positions)
-        x, _ = self.backbone(params, x, positions, mesh, "full")
+        with jax.named_scope("layers"):
+            x, _ = self.backbone(params, x, positions, mesh, "full")
         logits = self.logits_from(params, x, mesh)
         loss = L.softmax_xent(logits, batch["labels"],
                               batch.get("loss_mask"))
@@ -238,7 +244,8 @@ class DenseLM(BaseLM):
         b, s = batch["tokens"].shape
         positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
         x = self.embed_inputs(params, batch, mesh, positions)
-        x, cache = self.backbone(params, x, positions, mesh, "prefill")
+        with jax.named_scope("layers"):
+            x, cache = self.backbone(params, x, positions, mesh, "prefill")
         # optional batch["last"]: the true final-token position when the
         # prompt is right-padded to a bucketed length (serving re-uses one
         # compiled prefill per bucket; causality keeps rows <= last exact)
@@ -275,8 +282,9 @@ class DenseLM(BaseLM):
                        "kv_bound": int(kv_bound)}
         if pages_row is not None:
             chunk_cache["pages_row"] = pages_row
-        x, new_kv = self.backbone(params, x, positions, mesh, "chunk",
-                                  cache=chunk_cache)
+        with jax.named_scope("layers"):
+            x, new_kv = self.backbone(params, x, positions, mesh, "chunk",
+                                      cache=chunk_cache)
         x_last = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
         logits = self.logits_from(params, x_last, mesh)
         index = cache["index"].at[slot].set(offset + n_valid)
@@ -290,9 +298,12 @@ class DenseLM(BaseLM):
         else:
             positions = idx + jnp.broadcast_to(
                 jnp.arange(s, dtype=jnp.int32), (b, s))
-        x = L.embed(params["embed"], tokens, self.cfg, mesh, positions=positions)
-        x, new_cache = self.backbone(params, x, positions, mesh, "decode",
-                                     cache=cache)
+        with jax.named_scope("embed"):
+            x = L.embed(params["embed"], tokens, self.cfg, mesh,
+                        positions=positions)
+        with jax.named_scope("layers"):
+            x, new_cache = self.backbone(params, x, positions, mesh,
+                                         "decode", cache=cache)
         logits = self.logits_from(params, x, mesh)
         return logits, new_cache
 

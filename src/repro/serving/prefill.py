@@ -52,6 +52,8 @@ from collections import deque
 import jax.numpy as jnp
 import numpy as np
 
+from repro.serving.telemetry import span
+
 
 def bucket_len(n: int) -> int:
     """Power-of-two jit bucket for an `n`-token chunk."""
@@ -167,20 +169,23 @@ class PrefillManager:
     # -- chunk execution -----------------------------------------------------
     def _run_chunk(self, job: PrefillJob):
         """Ingest one chunk of `job`; returns the chunk's last-position
-        logits when it was the final chunk, else None."""
+        logits when it was the final chunk, else None.  Host prep, the
+        page-table upload and the dispatch run under ``serve.chunk``."""
         c = min(self.chunk_tokens or job.remaining, job.remaining)
         bucket = bucket_len(c)
-        toks = np.zeros((1, bucket), np.int32)
-        toks[0, :c] = job.prompt[job.done:job.done + c]
         # static KV read-back bound: the chunk attends its own bucketed
         # prefix, not the pool's max_len (bound buckets x chunk buckets
         # is the whole chunk jit cache)
         bound = min(bucket_len(job.done + c), self.pool.kv_bound_cap)
-        extras = self.pool.chunk_extras(job.slot)
-        logits, new_cache = self.chunk_step(
-            self.pool.cache, jnp.asarray(toks), jnp.int32(job.slot),
-            jnp.int32(job.done), jnp.int32(c), bound, *extras)
-        self.pool.adopt(new_cache)
+        with span("chunk", rid=job.st.rid, slot=job.slot, tokens=c,
+                  bucket=bucket, bound=bound):
+            toks = np.zeros((1, bucket), np.int32)
+            toks[0, :c] = job.prompt[job.done:job.done + c]
+            extras = self.pool.chunk_extras(job.slot)
+            logits, new_cache = self.chunk_step(
+                self.pool.cache, jnp.asarray(toks), jnp.int32(job.slot),
+                jnp.int32(job.done), jnp.int32(c), bound, *extras)
+            self.pool.adopt(new_cache)
         if self.tracer is not None:
             # each chunk is one vclock unit; tick()/drain() advance the
             # clock right after this returns, so the span is (t, t+1)

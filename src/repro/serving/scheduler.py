@@ -84,6 +84,7 @@ from repro.serving.pool import PoolExhausted
 from repro.serving.prefill import PrefillManager
 from repro.serving.sampling import K_CAP, effective_top_k
 from repro.serving.spec import NGramDrafter
+from repro.serving.telemetry import span
 
 
 def percentile_steps(values, q: float) -> float:
@@ -526,26 +527,29 @@ class Scheduler:
     # -- sampling ----------------------------------------------------------
     def _sample_rows(self, logits_last, entries):
         """One sampler call over rows; entries[i] styles row i (None rows
-        sample greedily with a dead key)."""
-        if self.sampler is None or self.all_greedy:
-            return np.asarray(jnp.argmax(logits_last, axis=-1))
-        n = logits_last.shape[0]
-        temps = np.zeros((n,), np.float32)
-        topks = np.zeros((n,), np.int32)
-        topps = np.ones((n,), np.float32)
-        rids = np.zeros((n,), np.int32)
-        steps = np.zeros((n,), np.int32)
-        for i, en in enumerate(entries):
-            if en is None:
-                continue
-            temps[i] = en.req.temperature
-            topks[i] = en.req.top_k
-            topps[i] = getattr(en.req, "top_p", 1.0)
-            rids[i] = en.req.rid
-            steps[i] = len(en.st.tokens)
-        return np.asarray(self.sampler(
-            logits_last, jnp.asarray(temps), jnp.asarray(topks),
-            jnp.asarray(topps), jnp.asarray(rids), jnp.asarray(steps)))
+        sample greedily with a dead key).  The ``serve.pick`` span covers
+        the device-to-host wait for the picks."""
+        one = entries[0] if len(entries) == 1 else None
+        with span("pick", **({"rid": one.req.rid} if one else {})):
+            if self.sampler is None or self.all_greedy:
+                return np.asarray(jnp.argmax(logits_last, axis=-1))
+            n = logits_last.shape[0]
+            temps = np.zeros((n,), np.float32)
+            topks = np.zeros((n,), np.int32)
+            topps = np.ones((n,), np.float32)
+            rids = np.zeros((n,), np.int32)
+            steps = np.zeros((n,), np.int32)
+            for i, en in enumerate(entries):
+                if en is None:
+                    continue
+                temps[i] = en.req.temperature
+                topks[i] = en.req.top_k
+                topps[i] = getattr(en.req, "top_p", 1.0)
+                rids[i] = en.req.rid
+                steps[i] = len(en.st.tokens)
+            return np.asarray(self.sampler(
+                logits_last, jnp.asarray(temps), jnp.asarray(topks),
+                jnp.asarray(topps), jnp.asarray(rids), jnp.asarray(steps)))
 
     def _sample_rows_multi(self, logits, width):
         """Sample ALL `width` speculated positions of every slot in one
@@ -604,8 +608,12 @@ class Scheduler:
 
     def admit_from_queue(self) -> None:
         """Admit from the local queue head while the pool has room."""
-        while self.queue and self.can_admit(self.queue[0]):
-            self._admit(self.queue.popleft())
+        with span("admit") as sp:
+            admitted = 0
+            while self.queue and self.can_admit(self.queue[0]):
+                self._admit(self.queue.popleft())
+                admitted += 1
+            sp.set_metadata(admitted=admitted)
 
     def _admit(self, entry: _Entry) -> None:
         now = self.clock()
@@ -775,48 +783,61 @@ class Scheduler:
         or — under a router (``evict_on_starvation``) — hand the evicted
         entry back for re-routing to a replica that can hold it.  Returns
         the evicted entries (empty in the single-engine path).
+
+        The tick runs under the ``serve.step`` profiler span (``vstep``:
+        the virtual clock at its start), its phases under ``serve.prefill``,
+        ``serve.page``, ``serve.decode`` (or ``serve.verify``),
+        ``serve.pick`` and ``serve.finish`` (``telemetry.span``).
         """
+        with span("step", vstep=self.vclock.t, active=len(self.active)):
+            return self._step(evict_on_starvation)
+
+    def _step(self, evict_on_starvation: bool) -> list:
         chunked = 0
         if self._mgr is not None and self._mgr.has_jobs:
-            self._peak = max(self._peak, self.in_flight)
-            finished, chunked = self._mgr.tick(self.vclock)
-            for job, logits in finished:
-                self._finish_prefill(job, logits)
+            with span("prefill"):
+                self._peak = max(self._peak, self.in_flight)
+                finished, chunked = self._mgr.tick(self.vclock)
+                for job, logits in finished:
+                    self._finish_prefill(job, logits)
         if not self.active:
             return []
         evicted = []
-        while True:
-            starved = self.pool.prepare_decode(sorted(self.active))
-            if not starved:
-                break
-            if self._mgr is not None and self._mgr.has_jobs:
-                self._requeue_job(self._mgr.evict_newest())
-                continue
-            if len(self.active) == 1:
-                (slot,) = self.active
-                if not evict_on_starvation:
-                    raise PoolExhausted(
-                        f"page starvation mid-decode: request "
-                        f"{self.active[slot].req.rid} holds every page and "
-                        f"still needs another — the page pool is too small "
-                        f"for it")
-                evicted.append(self._evict(slot))
-                self._preemptions += 1
-                return evicted               # nothing left to decode
-            victim = max(self.active,
-                         key=lambda sl: (self.active[sl].admit_step,
-                                         self.active[sl].req.rid))
-            self._preempt(victim)
+        with span("page"):
+            while True:
+                starved = self.pool.prepare_decode(sorted(self.active))
+                if not starved:
+                    break
+                if self._mgr is not None and self._mgr.has_jobs:
+                    self._requeue_job(self._mgr.evict_newest())
+                    continue
+                if len(self.active) == 1:
+                    (slot,) = self.active
+                    if not evict_on_starvation:
+                        raise PoolExhausted(
+                            f"page starvation mid-decode: request "
+                            f"{self.active[slot].req.rid} holds every page "
+                            f"and still needs another — the page pool is "
+                            f"too small for it")
+                    evicted.append(self._evict(slot))
+                    self._preemptions += 1
+                    return evicted           # nothing left to decode
+                victim = max(self.active,
+                             key=lambda sl: (self.active[sl].admit_step,
+                                             self.active[sl].req.rid))
+                self._preempt(victim)
         self._peak = max(self._peak, self.in_flight)
         self._peak_resident = max(self._peak_resident,
                                   int(self.pool.lengths.sum()))
         if self.spec_k and self.verify_fn is not None:
-            self._spec_step(chunked)
+            with span("verify"):
+                self._spec_step(chunked)
             return evicted
-        logits, new_cache = self.decode_fn(
-            self.pool.cache, jnp.asarray(self._last_tokens),
-            jnp.asarray(self._active_mask), *self.pool.decode_extras())
-        self.pool.update(new_cache, tuple(self.active))
+        with span("decode"):
+            logits, new_cache = self.decode_fn(
+                self.pool.cache, jnp.asarray(self._last_tokens),
+                jnp.asarray(self._active_mask), *self.pool.decode_extras())
+            self.pool.update(new_cache, tuple(self.active))
         self.vclock.advance(1)
         self._steps += 1
         self._busy += len(self.active)
@@ -825,24 +846,26 @@ class Scheduler:
         S = self.pool.num_slots
         rows = [self.active.get(i) for i in range(S)]
         toks = self._sample_rows(logits[:, -1], rows)
-        now = self.clock()
-        vnow = self.vclock.t
-        for slot, en in list(self.active.items()):
-            st = en.st
-            tok = int(toks[slot])
-            st.tokens.append(tok)
-            self._last_tokens[slot, 0] = tok
-            if len(st.tokens) >= st.max_new_tokens or tok == self.eos_id:
-                st.t_done = now
-                st.v_done = vnow
-                self.done.append(st)
-                del self.active[slot]
-                self._active_mask[slot] = 0
-                self._last_tokens[slot, 0] = 0
-                self.pool.free(slot)
-                if self.tracer is not None:
-                    self.tracer.end("decode", st.rid, vnow,
-                                    tokens=len(st.tokens))
+        with span("finish"):
+            now = self.clock()
+            vnow = self.vclock.t
+            for slot, en in list(self.active.items()):
+                st = en.st
+                tok = int(toks[slot])
+                st.tokens.append(tok)
+                self._last_tokens[slot, 0] = tok
+                if len(st.tokens) >= st.max_new_tokens or \
+                        tok == self.eos_id:
+                    st.t_done = now
+                    st.v_done = vnow
+                    self.done.append(st)
+                    del self.active[slot]
+                    self._active_mask[slot] = 0
+                    self._last_tokens[slot, 0] = 0
+                    self.pool.free(slot)
+                    if self.tracer is not None:
+                        self.tracer.end("decode", st.rid, vnow,
+                                        tokens=len(st.tokens))
         return evicted
 
     # -- speculative decode -------------------------------------------------

@@ -21,8 +21,7 @@ Three pieces:
   trace state ever enters jitted code, so telemetry-on streams are
   bit-identical to telemetry-off by construction.
 
-* ``MetricsRegistry`` — counters / gauges / histograms behind a declared
-  schema (``SERVE_SCHEMA`` / ``ROUTER_SCHEMA``).  ``ServeStats
+* ``MetricsRegistry`` — counters and gauges behind a declared schema (``SERVE_SCHEMA`` / ``ROUTER_SCHEMA``).  ``ServeStats
   .to_metrics()`` and ``RouterStats.to_metrics()`` are *views over this
   registry*: they set exactly the schema's keys and ``snapshot()``
   refuses extras or omissions, so the exported key set can never drift
@@ -36,6 +35,12 @@ Three pieces:
   spans with vstep timestamps, instant events for the ring).  Load a
   ``--trace-out`` file at https://ui.perfetto.dev to read one request's
   queued -> prefill -> decode life as a timeline.
+
+Wall and device time are not measured here: ``span`` names the host
+phases of a scheduler tick for ``jax.profiler``, which stamps them on the
+same clock as the device's ops (``launch/serve.py --profile-dir``).
+``serve.step`` carries the tick's ``vstep``, which ties the profiler's
+timeline to the ``Tracer``'s.
 """
 
 from __future__ import annotations
@@ -46,6 +51,8 @@ import math
 import re
 from collections import deque
 
+import jax
+
 # ---------------------------------------------------------------------------
 # Metric schema: the single source every flat metrics export goes through
 
@@ -55,11 +62,11 @@ class MetricSpec:
     """One declared metric: exact key, or a template containing ``{i}``
     (expanded per replica by the router view)."""
     key: str
-    kind: str                     # "counter" | "gauge" | "histogram"
+    kind: str                     # "counter" | "gauge"
     help: str
 
     def __post_init__(self):
-        if self.kind not in ("counter", "gauge", "histogram"):
+        if self.kind not in ("counter", "gauge"):
             raise ValueError(f"metric kind {self.kind!r}")
 
 
@@ -130,55 +137,23 @@ ROUTER_SCHEMA = _prefixed("router_", _COMMON) + (
 )
 
 
-@dataclasses.dataclass
-class Histogram:
-    """Fixed-bucket histogram (Prometheus-style cumulative on export)."""
-    bounds: tuple                  # ascending upper bounds; +inf implicit
-    counts: list = None
-    total: int = 0
-    sum: float = 0.0
-
-    def __post_init__(self):
-        if list(self.bounds) != sorted(self.bounds):
-            raise ValueError(f"histogram bounds not ascending {self.bounds}")
-        if self.counts is None:
-            self.counts = [0] * (len(self.bounds) + 1)
-
-    def observe(self, value: float) -> None:
-        for i, b in enumerate(self.bounds):
-            if value <= b:
-                self.counts[i] += 1
-                break
-        else:
-            self.counts[-1] += 1
-        self.total += 1
-        self.sum += float(value)
-
-
 class MetricsRegistry:
-    """Schema-validated counters/gauges/histograms behind one flat
-    namespace.
+    """Schema-validated counters and gauges behind one flat namespace.
 
-    Two modes of use, one instrument set:
-
-    * **view building** — construct from a declared schema
-      (``SERVE_SCHEMA`` / ``ROUTER_SCHEMA``), ``set`` every key, then
-      ``snapshot()``; a key outside the schema, or a declared exact key
-      never set, raises — the drift ``to_metrics()`` used to allow.
-    * **live accumulation** — ``declare`` metrics on the fly (the
-      ``Tracer`` does this for its span/event counters and duration
-      histogram), ``inc`` / ``observe`` as events happen.
+    Construct from a declared schema (``SERVE_SCHEMA`` /
+    ``ROUTER_SCHEMA``), ``set`` every key (``inc`` a counter), then
+    ``snapshot()``; a key outside the schema, or a declared exact key
+    never set, raises — the drift ``to_metrics()`` used to allow.
     """
 
     def __init__(self, schema=()):
         self._specs: dict[str, MetricSpec] = {}
         self._templates: list[MetricSpec] = []
         self._values: dict[str, float] = {}
-        self._hists: dict[str, Histogram] = {}
         for spec in schema:
             self.declare(spec)
 
-    def declare(self, spec: MetricSpec, buckets=None) -> MetricSpec:
+    def declare(self, spec: MetricSpec) -> MetricSpec:
         if "{i}" in spec.key:
             self._templates.append(spec)
             return spec
@@ -188,8 +163,6 @@ class MetricsRegistry:
             raise ValueError(f"metric key {spec.key!r} is not a valid "
                              f"Prometheus metric name")
         self._specs[spec.key] = spec
-        if spec.kind == "histogram":
-            self._hists[spec.key] = Histogram(tuple(buckets or (1, 10, 100)))
         return spec
 
     def spec_for(self, key: str) -> MetricSpec:
@@ -205,9 +178,7 @@ class MetricsRegistry:
 
     def set(self, key: str, value) -> None:
         """Record a snapshot value for a declared (or template) key."""
-        spec = self.spec_for(key)
-        if spec.kind == "histogram":
-            raise ValueError(f"{key!r} is a histogram — use observe()")
+        self.spec_for(key)
         self._values[key] = value
 
     def inc(self, key: str, n: float = 1) -> None:
@@ -215,32 +186,19 @@ class MetricsRegistry:
             raise ValueError(f"{key!r} is not a counter")
         self._values[key] = self._values.get(key, 0) + n
 
-    def observe(self, key: str, value: float) -> None:
-        if self.spec_for(key).kind != "histogram":
-            raise ValueError(f"{key!r} is not a histogram")
-        self._hists[key].observe(value)
-
     def snapshot(self, require_complete: bool = True) -> dict:
         """Flat ``{key: number}`` dict in schema declaration order
         (template instances in set order).  ``require_complete`` makes an
-        unset exact scalar key an error — a view that forgot a schema key
-        must fail loudly, not export a truncated scrape.  Histograms
-        flatten to ``{key}_count`` / ``{key}_sum`` / ``{key}_le_{b}``."""
+        unset exact key an error — a view that forgot a schema key must
+        fail loudly, not export a truncated scrape."""
         if require_complete:
-            missing = [k for k, s in self._specs.items()
-                       if s.kind != "histogram" and k not in self._values]
+            missing = [k for k in self._specs if k not in self._values]
             if missing:
                 raise ValueError(
                     f"metrics view did not set declared keys: {missing}")
         out = {}
-        for key, spec in self._specs.items():
-            if spec.kind == "histogram":
-                h = self._hists[key]
-                out[f"{key}_count"] = h.total
-                out[f"{key}_sum"] = h.sum
-                for b, c in zip(h.bounds, h.counts):
-                    out[f"{key}_le_{b}"] = c
-            elif key in self._values:
+        for key in self._specs:
+            if key in self._values:
                 out[key] = self._values[key]
         for key in self._values:
             if key not in self._specs:
@@ -285,6 +243,23 @@ def prometheus_text(metrics: dict, schema) -> str:
             lines.append(f"# TYPE {key} {spec.kind}")
         lines.append(f"{key} {_prom_value(value)}")
     return "\n".join(lines) + "\n"
+
+
+# ---------------------------------------------------------------------------
+# Profiler spans: the host phases of a scheduler tick on the device's clock
+
+
+def span(name: str, **args):
+    """A ``jax.profiler`` span named ``serve.<name>`` with ``args`` as its
+    stats.  With no profiler session open it records nothing (about a
+    microsecond of host time), so sites need no guard; under a session the
+    profiler stamps it on the clock of the device's own ops.  Spans are
+    ``serve.step`` (one ``Scheduler.step``; ``vstep``, ``active``),
+    ``serve.prefill``, ``serve.chunk`` (``rid``, ``slot``, ``tokens``,
+    ``bucket``, ``bound``), ``serve.page``, ``serve.decode``, ``serve.pick``
+    (``rid`` when one request), ``serve.finish``, ``serve.admit``
+    (``admitted``) and ``serve.verify``."""
+    return jax.profiler.TraceAnnotation("serve." + name, **args)
 
 
 # ---------------------------------------------------------------------------
@@ -430,31 +405,6 @@ class Tracer:
 
     def spans_of(self, phase: str) -> list:
         return [s for s in self.spans if s.phase == phase]
-
-    # -- derived metrics ------------------------------------------------------
-    def metrics(self) -> MetricsRegistry:
-        """A live registry over the trace itself: span counts per phase,
-        ring totals/drops, and a histogram of span durations (vsteps) —
-        the histogram leg of the registry, fed from real trace data."""
-        reg = MetricsRegistry()
-        reg.declare(_c("trace_spans_total", "spans recorded"))
-        reg.declare(_c("trace_events_total", "ring events recorded"))
-        reg.declare(_c("trace_events_dropped",
-                       "ring events lost to the capacity bound"))
-        reg.declare(MetricSpec("trace_span_vsteps", "histogram",
-                               "span durations, virtual steps"),
-                    buckets=(1, 2, 4, 8, 16, 32, 64, 128))
-        reg.inc("trace_spans_total", len(self.spans))
-        reg.inc("trace_events_total", self.total_events)
-        reg.inc("trace_events_dropped", self.dropped_events)
-        for phase in PHASES:
-            key = f"trace_{phase}_spans"
-            reg.declare(_c(key, f"{phase} spans recorded"))
-            reg.inc(key, len(self.spans_of(phase)))
-        for span in self.spans:
-            if span.v_end >= 0:
-                reg.observe("trace_span_vsteps", span.steps)
-        return reg
 
 
 # ---------------------------------------------------------------------------

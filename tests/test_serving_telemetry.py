@@ -80,21 +80,15 @@ def test_registry_template_keys_expand_per_replica():
 def test_registry_kind_discipline():
     reg = MetricsRegistry()
     reg.declare(MetricSpec("hits_total", "counter", ""))
-    reg.declare(MetricSpec("lat_steps", "histogram", ""), buckets=(1, 4))
+    reg.declare(MetricSpec("depth_now", "gauge", ""))
     reg.inc("hits_total")
     reg.inc("hits_total", 2)
     with pytest.raises(ValueError):
-        reg.observe("hits_total", 1)
+        reg.inc("depth_now")
     with pytest.raises(ValueError):
-        reg.set("lat_steps", 1)
-    for v in (1, 2, 3, 99):
-        reg.observe("lat_steps", v)
-    snap = reg.snapshot()
-    assert snap["hits_total"] == 3
-    assert snap["lat_steps_count"] == 4
-    assert snap["lat_steps_sum"] == 105.0
-    assert snap["lat_steps_le_1"] == 1      # per-bucket (non-cumulative)
-    assert snap["lat_steps_le_4"] == 2
+        MetricSpec("lat_steps", "histogram", "")
+    reg.set("depth_now", 4)
+    assert reg.snapshot() == {"hits_total": 3, "depth_now": 4}
 
 
 def test_prometheus_text_format():
@@ -206,19 +200,6 @@ def test_tracer_rebegin_closes_old_and_ring_bounds():
     assert [e.vstep for e in tr.events_of("preempt")] == [6, 7, 8, 9]
     with pytest.raises(ValueError):
         Tracer(ring_capacity=0)
-
-
-def test_tracer_metrics_registry_view():
-    tr = Tracer()
-    tr.span("prefill_chunk", 1, 0, 1)
-    tr.span("decode", 1, 1, 9)
-    tr.instant("reroute", 3, replica=1, rid=1)
-    snap = tr.metrics().snapshot(require_complete=False)
-    assert snap["trace_spans_total"] == 2
-    assert snap["trace_events_total"] == 1
-    assert snap["trace_prefill_chunk_spans"] == 1
-    assert snap["trace_span_vsteps_count"] == 2
-    assert snap["trace_span_vsteps_sum"] == 9.0
 
 
 # ---------------------------------------------------------------------------
@@ -417,3 +398,28 @@ def test_serve_main_telemetry_outputs(tmp_path, replicas, prefix):
     trace = json.loads(paths["trace_out"].read_text())
     assert {ev["name"] for ev in trace["traceEvents"]
             if ev["ph"] == "X"} >= {"queued", "decode"}
+
+
+def test_serve_main_profile_dir(tmp_path):
+    """--profile-dir: the drain's scheduler spans land in a profiler
+    trace, and serve.step carries the vstep that --trace-out keys on."""
+    import glob
+
+    from jax.profiler import ProfileData
+    _launch(tmp_path, "prof", profile_dir=str(tmp_path / "prof"))
+    (path,) = glob.glob(str(tmp_path / "prof" / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    events = [ev for plane in ProfileData.from_file(path).planes
+              for line in plane.lines for ev in line.events]
+    names = {ev.name for ev in events}
+    assert {"serve.step", "serve.admit", "serve.page", "serve.decode",
+            "serve.pick", "serve.finish"} <= names
+    vsteps = [dict(ev.stats)["vstep"] for ev in events
+              if ev.name == "serve.step"]
+    assert vsteps[0] == 0 and vsteps == sorted(set(vsteps))
+    # one clock: the last request's decode span in the vstep file ends
+    # with the last tick's decode step, one vstep after its serve.step
+    trace = json.loads((tmp_path / "prof_trace_out.out").read_text())
+    ends = [ev["ts"] + ev["dur"] for ev in trace["traceEvents"]
+            if ev.get("name") == "decode"]
+    assert max(ends) == vsteps[-1] + 1
