@@ -11,7 +11,8 @@ rule statically sums the VMEM-resident bytes its block shapes imply:
 * each ``pltpu.VMEM((dims...), dtype)`` scratch shape contributes
   ``prod(dims) * sizeof(dtype)`` once (scratch is not double-buffered).
 
-Dimensions resolve through, in order: constant-propagated local
+A ``None`` block dimension is squeezed (one element).  Other
+dimensions resolve through, in order: constant-propagated local
 assignments (``bx = min(block_x, n)`` resolves because ``min`` of the
 resolvable subset is a sound upper bound), the function's own integer
 keyword defaults (``block_q: int = 128``), and the declared bounds table
@@ -74,6 +75,8 @@ class _Unresolved(Exception):
 def _eval_dim(node: ast.AST, env: dict) -> int:
     if isinstance(node, ast.Constant) and isinstance(node.value, int):
         return node.value
+    if isinstance(node, ast.Constant) and node.value is None:
+        return 1                   # a squeezed block dim: one element
     if isinstance(node, ast.Name):
         if node.id in env:
             return env[node.id]

@@ -37,14 +37,18 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 128,
 
 
 @jax.jit
-def paged_attention(q, k_pages, v_pages, page_table, kv_len):
+def paged_attention(q, k_pages, v_pages, page_table, kv_len, layer=None):
     """Fused paged decode attention (see kernels/paged_attention.py).
 
-    q: (slots, H, dh); k_pages/v_pages: (num_pages, page_size, K, dh);
+    q: (slots, H, dh); k_pages/v_pages: every layer's pool
+    (layers, num_pages, page_size, rows, lanes) read at int32 ``layer``,
+    or one layer's (num_pages, page_size, rows, lanes) with no ``layer``,
+    a token's K heads as (K, dh) or packed to 128-lane rows
+    (``serving/pool.page_rows``);
     page_table: (slots, max_pages) int32; kv_len: (slots,) int32.
     """
     return paged_attention_pallas(q, k_pages, v_pages, page_table, kv_len,
-                                  interpret=interpret_mode())
+                                  layer, interpret=interpret_mode())
 
 
 @partial(jax.jit, static_argnames=("eps", "block_rows"))

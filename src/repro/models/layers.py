@@ -210,6 +210,38 @@ def dot_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 # Attention block (projections + rope + cache handling)
 
 
+def _layer_of(cache: jax.Array, layer) -> jax.Array:
+    """One layer's cache: ``cache`` itself where ``layer`` is None, else
+    layer ``layer`` of a (layers, ...) stack (a dynamic index XLA fuses
+    into the op that reads it)."""
+    if layer is None:
+        return cache
+    return jax.lax.dynamic_index_in_dim(cache, layer, keepdims=False)
+
+
+def _in_layer(ids: jax.Array, layer, n: int) -> jax.Array:
+    """Page or token-row ids of one layer (``n`` of them per layer) as ids
+    into the whole stack with its leading dims merged; unchanged for a
+    one-layer cache."""
+    return ids if layer is None else layer * n + ids
+
+
+def _set_rows(pool: jax.Array, rows: jax.Array, new: jax.Array) -> jax.Array:
+    """``new`` (..., K, dh) written at token rows ``rows`` of a page pool
+    (its dims before a token's ``(rows, lanes)`` merged, the heads packed
+    as the pool stores them: ``serving/pool.page_rows``) — a scatter in
+    place on the donated (or scan-carried) buffer."""
+    row = pool.shape[-2:]
+    new = new.reshape(new.shape[:-2] + row)
+    return pool.reshape((-1,) + row).at[rows].set(new).reshape(pool.shape)
+
+
+def _take_pages(pool: jax.Array, ids: jax.Array) -> jax.Array:
+    """The pages ``ids`` of a page pool whose dims before (page_size,
+    rows, lanes) are merged, read in place (no slice of a layer first)."""
+    return jnp.take(pool.reshape((-1,) + pool.shape[-3:]), ids, axis=0)
+
+
 def attention_defs(cfg) -> dict:
     dh = cfg.head_dim
     d = {
@@ -269,21 +301,29 @@ def attention(p: dict, x: jax.Array, cfg, mesh, *, positions: jax.Array,
     if mode == "decode":
         assert cache is not None and not cross
         idx = cache["index"]  # int32 tokens seen so far: scalar, or (b,)
-        t = cache["k"].shape[1]
+        # the layer scan passes the WHOLE cache (a leading layers dim) and
+        # this layer's id: every write lands in place on that buffer and
+        # every read indexes its layer, so no layer's cache is sliced out
+        # and written back.  Without "layer" the cache is one layer's.
+        layer = cache.get("layer")
+        lead = () if layer is None else (layer,)
+        t = cache["k"].shape[-3]
         if "pages" in cache:
             # PAGED slot-wise decode (continuous batching over a paged KV
             # pool): this layer's cache is a page pool (num_pages,
-            # page_size, K, dh) and `pages` is the (slots, max_pages)
-            # int32 page table.  The new kv is scattered to each row's own
+            # page_size, rows, lanes) — a token's (K, dh) as
+            # serving/pool.page_rows packs it — and `pages` is the
+            # (slots, max_pages) int32 page table.  The new kv is
+            # scattered to each row's own
             # page/offset; K/V are then read back *through the page table*
             # (one gather per row) so attention sees the same
             # (slots, max_pages*page_size, K, dh) layout the contiguous
             # path uses — identical masks, identical softmax, identical
             # tokens.  Rows with a zeroed page-table entry (freed /
-            # never-allocated slots) write into the reserved junk page 0,
-            # which no live table references.
+            # never-allocated slots) write into the reserved junk page 0
+            # (of this layer), which no live table references.
             pages = cache["pages"]
-            n_pages, psize = cache["k"].shape[0], cache["k"].shape[1]
+            n_pages, psize = cache["k"].shape[-4:-2]
             max_pages = pages.shape[1]
             Kh, dh = k.shape[2], k.shape[3]
             with jax.named_scope("kv_write"):
@@ -317,10 +357,9 @@ def attention(p: dict, x: jax.Array, cfg, mesh, *, positions: jax.Array,
                     fpos = jnp.where(ok, dest * psize + pos % psize,
                                      pos % psize)
                     new_k, new_v = k, v
-                k_all = cache["k"].reshape(n_pages * psize, Kh, dh) \
-                    .at[fpos].set(new_k).reshape(n_pages, psize, Kh, dh)
-                v_all = cache["v"].reshape(n_pages * psize, Kh, dh) \
-                    .at[fpos].set(new_v).reshape(n_pages, psize, Kh, dh)
+                fpos = _in_layer(fpos, layer, n_pages * psize)
+                k_all = _set_rows(cache["k"], fpos, new_k)
+                v_all = _set_rows(cache["v"], fpos, new_v)
             with jax.named_scope("attn"):
                 if cache.get("use_kernel") and s == 1:
                     # fused Pallas path (single-token decode only; verify
@@ -330,11 +369,12 @@ def attention(p: dict, x: jax.Array, cfg, mesh, *, positions: jax.Array,
                     from repro.kernels.ops import paged_attention
                     out = paged_attention(
                         q[:, 0], k_all, v_all, pages,
-                        (idx + s).astype(jnp.int32))[:, None]
+                        (idx + s).astype(jnp.int32), layer)[:, None]
                 else:
-                    kg = jnp.take(k_all, pages, axis=0).reshape(
+                    ids = _in_layer(pages, layer, n_pages)
+                    kg = _take_pages(k_all, ids).reshape(
                         q.shape[0], max_pages * psize, Kh, dh)
-                    vg = jnp.take(v_all, pages, axis=0).reshape(
+                    vg = _take_pages(v_all, ids).reshape(
                         q.shape[0], max_pages * psize, Kh, dh)
                     out = dot_attention(q, kg, vg, causal=True,
                                         q_offset=idx, kv_len=idx + s)
@@ -345,57 +385,59 @@ def attention(p: dict, x: jax.Array, cfg, mesh, *, positions: jax.Array,
             # buffer is irrelevant; everything valid is attendable.
             ring = jnp.ndim(idx) == 0 and window is not None and t <= window
             with jax.named_scope("kv_write"):
-                if jnp.ndim(idx) == 1 and s == 1:
+                if jnp.ndim(idx) == 1:
                     # SLOT-WISE decode (continuous batching): every row is
                     # a pool slot at its own length.  The new kv lands at
-                    # each row's own position (one-hot select — a per-row
-                    # scatter that XLA fuses), and the mask is per-row
+                    # each row's own position(s), and the mask is per-row
                     # causal-with-length.  Window is not applied: pool
-                    # slots are already bounded by max_len.
-                    hit = (jnp.arange(t)[None, :] == idx[:, None]
-                           )[..., None, None]
-                    k_all = jnp.where(hit, k, cache["k"])
-                    v_all = jnp.where(hit, v, cache["v"])
-                elif jnp.ndim(idx) == 1:
-                    # slot-wise VERIFY burst: scatter s speculative
-                    # positions per row; positions past max_len drop (the
-                    # host caps acceptance at the slot's backed capacity,
-                    # so dropped writes are never attended)
+                    # slots are already bounded by max_len.  A VERIFY
+                    # burst (s > 1) writes s speculative positions per
+                    # row; positions past max_len drop (the host caps
+                    # acceptance at the slot's backed capacity, so dropped
+                    # writes are never attended)
                     rows = jnp.arange(q.shape[0])[:, None]      # (slots, 1)
                     pos = idx[:, None] + jnp.arange(s)[None, :]  # (slots, s)
-                    k_all = cache["k"].at[rows, pos].set(k, mode="drop")
-                    v_all = cache["v"].at[rows, pos].set(v, mode="drop")
+                    k_all = cache["k"].at[lead + (rows, pos)].set(
+                        k, mode="drop")
+                    v_all = cache["v"].at[lead + (rows, pos)].set(
+                        v, mode="drop")
                 else:
                     write = jnp.mod(idx, t) if ring else idx
-                    k_all = jax.lax.dynamic_update_slice_in_dim(
-                        cache["k"], k, write, axis=1)
-                    v_all = jax.lax.dynamic_update_slice_in_dim(
-                        cache["v"], v, write, axis=1)
+                    at = lead + (0, write, 0, 0)
+                    one = (1,) * len(lead)
+                    k_all = jax.lax.dynamic_update_slice(
+                        cache["k"], k.reshape(one + k.shape), at)
+                    v_all = jax.lax.dynamic_update_slice(
+                        cache["v"], v.reshape(one + v.shape), at)
             with jax.named_scope("attn"):
+                k_l, v_l = _layer_of(k_all, layer), _layer_of(v_all, layer)
                 if ring:
-                    out = dot_attention(q, k_all, v_all, causal=False,
+                    out = dot_attention(q, k_l, v_l, causal=False,
                                         kv_len=jnp.minimum(idx + s, t))
                 else:
-                    out = dot_attention(q, k_all, v_all, causal=True,
+                    out = dot_attention(q, k_l, v_l, causal=True,
                                         q_offset=idx, kv_len=idx + s)
         new_cache = {"k": k_all, "v": v_all, "index": idx + s}
     elif mode == "chunk":
         # CHUNKED PREFILL written straight into the serving pool: x is one
         # bucketed chunk (batch 1, s tokens at global positions
-        # [offset, offset+s)) of a single request's prompt, and this
-        # layer's cache is the pool's own storage — contiguous
-        # (num_slots, max_len, K, dh) or paged (num_pages, page_size, K,
-        # dh) plus the slot's (max_pages,) page-table row.  The chunk's
-        # K/V scatter to their final resting positions (no intermediate
-        # contiguous (1, s) cache to re-scatter later), then the slot's
-        # whole KV is read back so the chunk attends causally over every
-        # prior chunk through the same indirection decode uses.  Bucket
-        # padding rows (query j >= the true chunk length) write junk only
-        # at positions later chunks / decode overwrite before any mask
-        # admits them; out-of-range rows drop (contiguous) or land in the
-        # reserved junk page 0 (paged).
+        # [offset, offset+s)) of a single request's prompt, and the cache
+        # is the pool's own storage — contiguous (num_slots, max_len, K,
+        # dh) or paged (num_pages, page_size, rows, lanes) plus the slot's
+        # (max_pages,) page-table row; with "layer", every layer's
+        # storage, written and read at that layer in place (as decode).
+        # The chunk's K/V scatter to their final resting positions (no
+        # intermediate contiguous (1, s) cache to re-scatter later), then
+        # the slot's whole KV is read back so the chunk attends causally
+        # over every prior chunk through the same indirection decode uses.
+        # Bucket padding rows (query j >= the true chunk length) write
+        # junk only at positions later chunks / decode overwrite before
+        # any mask admits them; out-of-range rows drop (contiguous) or
+        # land in the reserved junk page 0 (paged).
         assert cache is not None and not cross
         slot, off = cache["slot"], cache["offset"]
+        layer = cache.get("layer")
+        lead = () if layer is None else (layer,)
         # kv_bound (a STATIC python int >= offset + s) caps the read-back:
         # a 4-token prompt in a max_len=128 pool attends 4-16 positions,
         # not 128.  Bounds are bucketed to powers of two host-side so the
@@ -407,7 +449,7 @@ def attention(p: dict, x: jax.Array, cfg, mesh, *, positions: jax.Array,
         with jax.named_scope("kv_write"):
             if paged:
                 pages_row = cache["pages_row"]      # (max_pages,) int32
-                n_pages, psize = cache["k"].shape[0], cache["k"].shape[1]
+                n_pages, psize = cache["k"].shape[-4:-2]
                 max_pages = pages_row.shape[0]
                 logical = pos // psize
                 ok = logical < max_pages
@@ -415,26 +457,28 @@ def attention(p: dict, x: jax.Array, cfg, mesh, *, positions: jax.Array,
                                 jnp.minimum(logical, max_pages - 1))
                 fpos = jnp.where(ok, dest * psize + pos % psize,
                                  pos % psize)
-                k_all = cache["k"].reshape(n_pages * psize, Kh, dh) \
-                    .at[fpos].set(k[0]).reshape(n_pages, psize, Kh, dh)
-                v_all = cache["v"].reshape(n_pages * psize, Kh, dh) \
-                    .at[fpos].set(v[0]).reshape(n_pages, psize, Kh, dh)
+                fpos = _in_layer(fpos, layer, n_pages * psize)
+                k_all = _set_rows(cache["k"], fpos, k[0])
+                v_all = _set_rows(cache["v"], fpos, v[0])
             else:
-                k_all = cache["k"].at[slot, pos].set(k[0], mode="drop")
-                v_all = cache["v"].at[slot, pos].set(v[0], mode="drop")
+                k_all = cache["k"].at[lead + (slot, pos)].set(
+                    k[0], mode="drop")
+                v_all = cache["v"].at[lead + (slot, pos)].set(
+                    v[0], mode="drop")
         with jax.named_scope("attn"):
             if paged:
                 B = min(-(-bound // psize), max_pages)
-                kg = jnp.take(k_all, pages_row[:B], axis=0).reshape(
-                    1, B * psize, Kh, dh)
-                vg = jnp.take(v_all, pages_row[:B], axis=0).reshape(
-                    1, B * psize, Kh, dh)
+                ids = _in_layer(pages_row[:B], layer, n_pages)
+                kg = _take_pages(k_all, ids).reshape(1, B * psize, Kh, dh)
+                vg = _take_pages(v_all, ids).reshape(1, B * psize, Kh, dh)
             else:
-                L = min(bound, k_all.shape[1])
-                kg = jax.lax.dynamic_slice(
-                    k_all, (slot, 0, 0, 0), (1, L, Kh, dh))
-                vg = jax.lax.dynamic_slice(
-                    v_all, (slot, 0, 0, 0), (1, L, Kh, dh))
+                L = min(bound, k_all.shape[-3])
+                at = lead + (slot, 0, 0, 0)
+                size = (1,) * len(lead) + (1, L, Kh, dh)
+                kg = jax.lax.dynamic_slice(k_all, at, size).reshape(
+                    1, L, Kh, dh)
+                vg = jax.lax.dynamic_slice(v_all, at, size).reshape(
+                    1, L, Kh, dh)
             out = dot_attention(q, kg, vg, causal=True, q_offset=off,
                                 kv_len=off + s)
         new_cache = {"k": k_all, "v": v_all}

@@ -144,6 +144,9 @@ class MoELM(DenseLM):
         y, aux = moe_mlp(p["mlp"], h, cfg, mesh)
         return x + y, (new_cache, aux)
 
+    def block_cache(self, out):
+        return out[0]          # (new_cache, aux): decode drops the aux
+
     # backbone: thread aux through the scan carry
     def backbone(self, params, x, positions, mesh, mode, cache=None):
         blocks = params["blocks"]
@@ -164,48 +167,8 @@ class MoELM(DenseLM):
             self._last_aux = aux_sum / self.cfg.num_layers
             return x, None
 
-        if mode == "decode":
-            pages = cache.get("pages")
-
-            def body_d(carry, xs):
-                bp, ck, cv, ci = xs[:4]
-                layer_cache = {"k": ck, "v": cv, "index": ci}
-                if pages is not None:
-                    layer_cache["pages"] = xs[4]
-                y, (nc, _) = self.block_apply(bp, carry, mesh, positions,
-                                              "decode", layer_cache)
-                return y, (nc["k"], nc["v"])
-
-            index = cache["index"]   # scalar, or per-slot vector (serving)
-            L = self.cfg.num_layers
-            xs = (blocks, cache["k"], cache["v"],
-                  jnp.broadcast_to(index, (L,) + jnp.shape(index)))
-            if pages is not None:
-                xs = xs + (jnp.broadcast_to(pages, (L,) + pages.shape),)
-            x, (nk, nv) = jax.lax.scan(body_d, x, xs)
-            new_cache = {"k": nk, "v": nv, "index": index + x.shape[1]}
-            if pages is not None:
-                new_cache["pages"] = pages
-            return x, new_cache
-
-        if mode == "chunk":
-            slot, offset = cache["slot"], cache["offset"]
-            bound = cache["kv_bound"]              # static python int
-            pages_row = cache.get("pages_row")
-
-            def body_c(carry, xs):
-                bp, ck, cv = xs
-                layer_cache = {"k": ck, "v": cv, "slot": slot,
-                               "offset": offset, "kv_bound": bound}
-                if pages_row is not None:
-                    layer_cache["pages_row"] = pages_row
-                y, (nc, _) = self.block_apply(bp, carry, mesh, positions,
-                                              "chunk", layer_cache)
-                return y, (nc["k"], nc["v"])
-
-            x, (nk, nv) = jax.lax.scan(body_c, x,
-                                       (blocks, cache["k"], cache["v"]))
-            return x, {"k": nk, "v": nv}
+        if mode in ("decode", "chunk"):
+            return super().backbone(params, x, positions, mesh, mode, cache)
 
         def body_p(carry, bp):
             y, (nc, _) = self.block_apply(bp, carry, mesh, positions, "prefill", None)

@@ -11,6 +11,8 @@ All families implement ``BaseLM``:
 
 Layers are stacked with ``lax.scan`` (compile time on deep models) and
 wrapped in ``jax.checkpoint`` per the deployment plan's remat policy.
+Decode and chunked prefill carry the whole (layers, ...) KV cache
+through the scan and update it in place (``scan_cache``).
 """
 
 from __future__ import annotations
@@ -134,6 +136,31 @@ class DenseLM(BaseLM):
     def mlp_apply(self, p, h, mesh):
         return L.mlp(p, h, self.cfg, mesh)
 
+    def block_cache(self, out):
+        """The new cache from ``block_apply``'s second output."""
+        return out
+
+    def scan_cache(self, blocks, x, positions, mesh, mode, k, v, rest):
+        """Decode or chunk over scanned layers with the WHOLE KV cache,
+        (layers, ...) K and V, in the scan carry: each layer gets both
+        stacks and its layer id, writes its new K/V in place and reads
+        its own layer where it lies.  Carried (not scanned as xs -> ys),
+        the cache is updated in place — no layer's slice is copied out
+        and back, and the donated buffer aliases the step's output.
+        ``rest`` is the layer-invariant rest of the cache.  Returns
+        (x, new K, new V)."""
+        def body(carry, xs):
+            y, k, v = carry
+            bp, layer = xs
+            c = dict(rest, k=k, v=v, layer=layer)
+            y, out = self.block_apply(bp, y, mesh, positions, mode, c)
+            nc = self.block_cache(out)
+            return (y, nc["k"], nc["v"]), None
+
+        layers = jnp.arange(self.cfg.num_layers, dtype=jnp.int32)
+        (x, k, v), _ = jax.lax.scan(body, (x, k, v), (blocks, layers))
+        return x, k, v
+
     # ---- backbone over scanned layers ----
     def backbone(self, params, x, positions, mesh, mode, cache=None):
         blocks = params["blocks"]
@@ -148,62 +175,22 @@ class DenseLM(BaseLM):
             x, _ = jax.lax.scan(body, x, blocks)
             return x, None
 
-        # prefill / decode / chunk: per-layer cache travels as scan xs -> ys
-        index = cache.get("index") if cache is not None else None
-
-        if mode == "decode":
-            pages = cache.get("pages")
-            # STATIC python flag (never part of the jit pytree): selects the
-            # fused Pallas paged-decode kernel inside the traced body
-            use_kernel = bool(cache.get("use_kernel", False))
-
-            def body_d(carry, xs):
-                bp, ck, cv, ci = xs[:4]
-                layer_cache = {"k": ck, "v": cv, "index": ci}
-                if pages is not None:
-                    layer_cache["pages"] = xs[4]
-                    if use_kernel:
-                        layer_cache["use_kernel"] = True
-                y, nc = self.block_apply(bp, carry, mesh, positions, "decode",
-                                         layer_cache)
-                return y, (nc["k"], nc["v"])
-
-            # index is a scalar (static decode) or a per-slot vector
-            # (continuous batching); the paged layout adds the shared
-            # (slots, max_pages) page table.  Either way each scanned
-            # layer sees its own copy.
-            L = self.cfg.num_layers
-            xs = (blocks, cache["k"], cache["v"],
-                  jnp.broadcast_to(index, (L,) + jnp.shape(index)))
-            if pages is not None:
-                xs = xs + (jnp.broadcast_to(pages, (L,) + pages.shape),)
-            x, (nk, nv) = jax.lax.scan(body_d, x, xs)
-            new_cache = {"k": nk, "v": nv, "index": index + x.shape[1]}
-            if pages is not None:
-                new_cache["pages"] = pages
+        if mode in ("decode", "chunk"):
+            # the rest of the cache is layer-invariant and closes over the
+            # scan body: decode's index (a scalar, or a per-slot vector
+            # under continuous batching) and the paged layout's (slots,
+            # max_pages) page table; the chunk's slot, offset and page
+            # table row; the STATIC kv_bound and use_kernel (the fused
+            # Pallas paged-decode kernel), never part of the jit pytree
+            rest = {n: c for n, c in cache.items() if n not in ("k", "v")}
+            x, k, v = self.scan_cache(blocks, x, positions, mesh, mode,
+                                      cache["k"], cache["v"], rest)
+            if mode == "chunk":
+                return x, {"k": k, "v": v}
+            new_cache = {"k": k, "v": v, "index": cache["index"] + x.shape[1]}
+            if "pages" in cache:
+                new_cache["pages"] = cache["pages"]
             return x, new_cache
-
-        if mode == "chunk":
-            # chunked prefill into a serving pool: each scanned layer sees
-            # its own (pool-shaped) K/V slice; slot / offset / the page
-            # table row are layer-invariant and close over the body
-            slot, offset = cache["slot"], cache["offset"]
-            bound = cache["kv_bound"]              # static python int
-            pages_row = cache.get("pages_row")
-
-            def body_c(carry, xs):
-                bp, ck, cv = xs
-                layer_cache = {"k": ck, "v": cv, "slot": slot,
-                               "offset": offset, "kv_bound": bound}
-                if pages_row is not None:
-                    layer_cache["pages_row"] = pages_row
-                y, nc = self.block_apply(bp, carry, mesh, positions,
-                                         "chunk", layer_cache)
-                return y, (nc["k"], nc["v"])
-
-            x, (nk, nv) = jax.lax.scan(body_c, x,
-                                       (blocks, cache["k"], cache["v"]))
-            return x, {"k": nk, "v": nv}
 
         # prefill
         def body_p(carry, bp):

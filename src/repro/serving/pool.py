@@ -14,12 +14,15 @@ lifetime, whatever its actual length — simple, but the pool's capacity is
 ``PagedKVCachePool`` breaks that reservation: KV storage is a pool of
 fixed-size pages plus a per-slot page-table indirection,
 
-    k, v      : (layers, num_pages, page_size, kv_heads, head_dim)
+    k, v      : (layers, num_pages, page_size, rows, lanes)
     index     : (num_slots,) int32 — tokens written per slot
     page_table: (num_slots, max_pages) int32 — host-side, shipped to the
                 decode step each iteration as a plain argument
 
-so a request only ever holds ``ceil(len / page_size)`` pages and the
+where one token's ``(kv_heads, head_dim)`` is stored as ``(rows, lanes)``
+(``page_rows``: the heads themselves, or several heads to a 128-lane row
+where head_dim is smaller), so a request only ever holds
+``ceil(len / page_size)`` pages and the
 tuner's HBM budget buys admitted *tokens* instead of admitted worst
 cases.  Page 0 is a reserved junk page: inactive slots (zeroed
 page-table rows) scatter their dead writes there and nothing ever reads
@@ -112,6 +115,27 @@ def _scatter_insert(cache, slot, pk, pv):
     return {"k": k, "v": v, "index": index}
 
 
+LANES = 128     # a TPU vector register's lanes: the minor dim of a tile
+
+
+def page_rows(num_kv_heads: int, head_dim: int) -> tuple:
+    """How a page pool stores one token's K (or V): ``(rows, lanes)``.
+
+    ``(num_kv_heads, head_dim)`` itself where head_dim fills the lanes;
+    where it is under 128 and divides it, ``128 // head_dim`` heads side
+    by side in each 128-lane row (head ``h`` in row ``h // r``, lanes
+    ``(h % r) * head_dim`` on) — the same bytes in the same order.  A
+    TPU lays out a (kv_heads, 64) pool with the page axis minor-most
+    (no lane padding), which the paged kernel cannot read a page of: every
+    step would relayout the whole pool.  A 128-lane row is laid out
+    row-major, dense, and read a page at a time in place.
+    """
+    width = num_kv_heads * head_dim
+    if 0 < head_dim < LANES and LANES % head_dim == 0 and width % LANES == 0:
+        return width // LANES, LANES
+    return num_kv_heads, head_dim
+
+
 @partial(jax.jit, donate_argnums=(0,))
 def _scatter_insert_paged(cache, slot, pages_row, pk, pv):
     """Write a batch-1 prefill cache (L, 1, s, K, dh) through `pages_row`.
@@ -119,15 +143,17 @@ def _scatter_insert_paged(cache, slot, pages_row, pk, pv):
     Token position j lands in page ``pages_row[j // page_size]`` at offset
     ``j % page_size`` — the same indirection the decode step reads back.
     """
-    L, _, s, K, dh = pk.shape
-    P, psize = cache["k"].shape[1], cache["k"].shape[2]
+    L, _, s = pk.shape[:3]
+    shape = cache["k"].shape
+    P, psize, row = shape[1], shape[2], shape[3:]
     pos = jnp.arange(s)
     fpos = pages_row[pos // psize] * psize + pos % psize  # (s,)
-    k = cache["k"].reshape(L, P * psize, K, dh).at[:, fpos].set(pk[:, 0])
-    v = cache["v"].reshape(L, P * psize, K, dh).at[:, fpos].set(pv[:, 0])
+    k = cache["k"].reshape((L, P * psize) + row).at[:, fpos].set(
+        pk[:, 0].reshape((L, s) + row))
+    v = cache["v"].reshape((L, P * psize) + row).at[:, fpos].set(
+        pv[:, 0].reshape((L, s) + row))
     index = cache["index"].at[slot].set(s)
-    return {"k": k.reshape(L, P, psize, K, dh),
-            "v": v.reshape(L, P, psize, K, dh), "index": index}
+    return {"k": k.reshape(shape), "v": v.reshape(shape), "index": index}
 
 
 class KVCachePool:
@@ -291,8 +317,8 @@ class PagedKVCachePool:
         if self.num_pages < 2:
             raise ValueError(f"num_pages {self.num_pages} < 2 "
                              f"(page 0 is reserved)")
-        kv_shape = (cfg.num_layers, self.num_pages, page_size,
-                    cfg.num_kv_heads, cfg.head_dim)
+        kv_shape = (cfg.num_layers, self.num_pages, page_size) + \
+            page_rows(cfg.num_kv_heads, cfg.head_dim)
         self.cache = {"k": jnp.zeros(kv_shape, cfg.activation_dtype),
                       "v": jnp.zeros(kv_shape, cfg.activation_dtype),
                       "index": jnp.zeros((num_slots,), jnp.int32)}

@@ -161,10 +161,12 @@ def build_prefill_chunk_step_paged(model, mesh=None):
     """Chunked prefill straight into a *paged* serving KV pool.
 
     Same contract as ``build_prefill_chunk_step``, but K/V are the page
-    pool ``(layers, num_pages, page_size, kv_heads, head_dim)`` and
+    pool ``(layers, num_pages, page_size, rows, lanes)`` (a token's K or V
+    heads as ``serving/pool.page_rows`` stores them) and
     ``pages_row`` is the slot's ``(max_pages,)`` page-table row: chunk
     token at global position j lands in page ``pages_row[j // page_size]``
-    at offset ``j % page_size`` — its final resting place, one write.
+    at offset ``j % page_size`` — its final resting place, one write,
+    in place in the pool the layer scan carries (as the decode step).
     Pages must be reserved by the pool before the call; rows past the
     reserved region (bucket padding) fall into the junk page 0.
     """
@@ -204,18 +206,22 @@ def build_decode_step_slots_paged(model, mesh=None, use_kernel: bool = False):
     """Slot-wise decode over a *paged* KV pool (PagedKVCachePool).
 
     Same contract as ``build_decode_step_slots``, but the cache's K/V are
-    a page pool ``(layers, num_pages, page_size, kv_heads, head_dim)`` and
+    a page pool ``(layers, num_pages, page_size, rows, lanes)`` and
     the per-slot ``(num_slots, max_pages)`` int32 page table arrives as an
     extra argument each step (the pool keeps it on the host so page
     alloc/free never touches the device).  The model reads and writes K/V
     through the table; a slot whose table row is zeroed (freed) scatters
-    its dead write into the reserved junk page 0.  Jittable; the engine
-    donates the cache argument only — the page table is tiny and
-    re-uploaded per step.
+    its dead write into the reserved junk page 0 (of each layer).
+    Jittable; the engine donates the cache argument only — the page table
+    is tiny and re-uploaded per step.  The layer scan carries the whole
+    pool: each layer writes its new K/V into it in place and reads its
+    own layer where it lies, so with the cache donated no part of the pool
+    is copied.
 
     use_kernel=True swaps the gather-then-attend read for the fused
     Pallas paged-attention kernel (kernels/paged_attention.py): the page
-    table is walked inside the kernel, so the materialized
+    table is walked inside the kernel, which DMAs the layer's pages out
+    of the whole pool, so the materialized
     (slots, max_pages*page_size, K, dh) read never hits HBM.  The flag is
     STATIC — it is closed over and inserted into the cache dict inside
     the traced function, never at the jit boundary, so cache pytree

@@ -23,6 +23,7 @@ from _hypothesis_compat import given, settings, strategies as st  # noqa: E402
 from repro.kernels import ref  # noqa: E402
 from repro.kernels.ops import paged_attention  # noqa: E402
 from repro.models.layers import dot_attention  # noqa: E402
+from repro.serving.pool import page_rows  # noqa: E402
 
 
 def make_case(seed, lens, page_size, max_pages, K, G, dh, dtype,
@@ -146,3 +147,57 @@ def test_paged_kernel_rejects_bad_gqa():
     with pytest.raises(AssertionError):
         paged_attention(q, kp, kp, jnp.zeros((2, 2), jnp.int32),
                         jnp.zeros((2,), jnp.int32))
+
+
+def _packed(pool):
+    """The pool as the serving pool stores it: each token's (K, dh) as
+    ``serving/pool.page_rows`` packs it (same bytes, same order)."""
+    return pool.reshape(pool.shape[:-2] + page_rows(*pool.shape[-2:]))
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("psize,mp,K,G,dh,lens,dtype", [CASES[0], CASES[3]])
+def test_paged_kernel_reads_one_layer_of_a_stacked_pool(layer, psize, mp, K,
+                                                        G, dh, lens, dtype):
+    """The decode step hands the kernel every layer's pool (layers,
+    num_pages, page_size, rows, lanes) and a layer id: the output equals
+    the oracle on that layer's pool alone, and the other layers' pages,
+    NaN throughout, never reach it."""
+    q, kp, vp, table, kv_len = make_case(7, lens, psize, mp, K, G, dh,
+                                         dtype, poison=1e4)
+    nan = jnp.full_like(kp, jnp.nan)
+    k5 = jnp.stack([kp if i == layer else nan for i in range(3)])
+    v5 = jnp.stack([vp if i == layer else nan for i in range(3)])
+    out = np.asarray(paged_attention(q, _packed(k5), _packed(v5), table,
+                                     kv_len, jnp.int32(layer)), np.float32)
+    assert np.isfinite(out).all()
+    want = np.asarray(
+        ref.paged_attention_ref(q, kp, vp, table, kv_len), np.float32)
+    np.testing.assert_allclose(out, want, rtol=_tol(dtype), atol=_tol(dtype))
+    # and bitwise what the one-layer call gives
+    np.testing.assert_array_equal(
+        out, np.asarray(paged_attention(q, _packed(kp), _packed(vp), table,
+                                        kv_len), np.float32))
+
+
+@pytest.mark.parametrize("K,G,dh", [(2, 4, 64), (4, 2, 32), (8, 1, 32),
+                                    (8, 2, 16)],
+                         ids=["r2", "r4", "r4x2rows", "r8"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_kernel_packed_rows(K, G, dh, dtype):
+    """Heads packed several to a 128-lane row (head_dim under 128, as the
+    serving pool stores them) give the oracle's output on the unpacked
+    pool, and the unpacked kernel's to rounding — freed slots and a
+    poisoned junk page included."""
+    assert page_rows(K, dh)[1] == 128
+    lens = [40, 7, 0, 33]
+    q, kp, vp, table, kv_len = make_case(5, lens, 8, 6, K, G, dh, dtype,
+                                         poison=1e4)
+    out = np.asarray(paged_attention(q, _packed(kp), _packed(vp), table,
+                                     kv_len), np.float32)
+    want = np.asarray(
+        ref.paged_attention_ref(q, kp, vp, table, kv_len), np.float32)
+    np.testing.assert_allclose(out, want, rtol=_tol(dtype), atol=_tol(dtype))
+    flat = np.asarray(paged_attention(q, kp, vp, table, kv_len), np.float32)
+    np.testing.assert_allclose(out, flat, rtol=1e-5, atol=1e-5)
+    assert np.all(out[2] == 0.0)

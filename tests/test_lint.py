@@ -321,6 +321,18 @@ def test_vmem_dynamic_dim_is_an_error():
     assert errs and "dynamic block dimension" in errs[0].message
 
 
+def test_vmem_squeezed_dim_counts_one_element():
+    """A ``None`` block dim (Pallas squeezes it) is one element: the
+    block (None, 128, d) costs what (128, d) costs, and is no error."""
+    def info(bx):
+        out = lint_source(_kernel_src(bx, "d"), "src/repro/kernels/x.py",
+                          _VMEM_CFG, ["vmem-budget"])
+        assert [f for f in out if f.severity == "error"] == []
+        return [f.message for f in out if f.severity == "info"]
+    assert info("None, 128") == info(128)
+    assert "2x256 KiB blocks" in info("None, 128")[0]
+
+
 def test_vmem_scratch_and_bounds_resolution():
     src = (
         "from jax.experimental import pallas as pl\n"

@@ -328,6 +328,19 @@ def test_kernel_survives_preemption_and_junk_rows():
     assert b.preemptions == a.preemptions
 
 
+def test_kernel_on_token_identical_to_gather_moe_family():
+    """The MoE backbone hands its decode layers the same carried pool:
+    kernel-on and gather streams match there too."""
+    kw = dict(arch="granite-moe-3b-a800m-smoke", num_slots=3, max_len=48,
+              seed=0, kv_layout="paged", page_size=8,
+              log=lambda *a, **k: None)
+    eg = ServeEngine(kv_kernel="gather", **kw)
+    ek = ServeEngine(kv_kernel="pallas", **kw)
+    reqs = zipf_trace(6, eg.cfg.vocab_size, max_prompt=16, max_new=10,
+                      seed=1)
+    assert _tokens(eg.run(reqs)) == _tokens(ek.run(reqs))
+
+
 def test_contiguous_engine_rejects_pallas_kv_kernel():
     with pytest.raises(ValueError, match="kv_kernel"):
         ServeEngine(arch=ARCH, num_slots=2, max_len=32, seed=0,
@@ -445,3 +458,90 @@ def test_tuner_sizes_paged_pool_and_reports_delta():
     worst = 4096 * (32768 // 16) + 1
     assert plan_big.serve_num_pages < worst
     assert "serve_capacity_delta" in plan_big.napkin
+
+
+# ---------------------------------------------------------------------------
+# The layer scan writes the carried pool in place: one (layer, page, offset)
+# per new token, nothing else
+
+
+def _random_pool(model, num_slots=3, max_len=32, page_size=8):
+    pool = PagedKVCachePool(model, num_slots=num_slots, max_len=max_len,
+                            page_size=page_size)
+    shape = pool.cache["k"].shape
+    kk, kv = jax.random.split(jax.random.PRNGKey(5))
+    pool.cache = dict(
+        pool.cache,
+        k=jax.random.normal(kk, shape, jnp.float32).astype(jnp.bfloat16),
+        v=jax.random.normal(kv, shape, jnp.float32).astype(jnp.bfloat16))
+    return pool
+
+
+def _changed_only_at(before, after, written):
+    """Every (layer, page, offset) outside `written` (a set of (page,
+    offset), for every layer) is bit-identical; each written one moved."""
+    keep = np.ones(before.shape[:3], bool)
+    for page, off in written:
+        keep[:, page, off] = False
+        assert not np.array_equal(before[:, page, off], after[:, page, off])
+    np.testing.assert_array_equal(before[keep], after[keep])
+
+
+@pytest.mark.parametrize("use_kernel", [False, True],
+                         ids=["gather", "kernel"])
+def test_paged_decode_step_writes_only_the_new_tokens(use_kernel):
+    """One decode step over a pool full of data: each active slot's new
+    K/V lands at (every layer, its page, its offset) — the first token of
+    a fresh page included — an inactive slot's dead write goes to the
+    junk page 0, and every other position is bit-identical to before."""
+    from repro.training.steps import build_decode_step_slots_paged
+    model = _model()
+    params = init_params(model.param_table(), jax.random.PRNGKey(0))
+    pool = _random_pool(model)
+    lengths = {pool.alloc(): 12, pool.alloc(): 8}    # 8: a fresh page
+    idle = pool.alloc()
+    for slot, n in lengths.items():
+        pool.reserve_prefix(slot, n + 1)
+        pool.set_length(slot, n)
+    index = jnp.asarray([lengths.get(s, 0) for s in range(3)], jnp.int32)
+    cache = dict(pool.cache, index=index)
+    step = jax.jit(build_decode_step_slots_paged(model,
+                                                 use_kernel=use_kernel))
+    active = jnp.asarray([s != idle for s in range(3)], jnp.int32)
+    _, new = step(params, cache, jnp.ones((3, 1), jnp.int32), active,
+                  jnp.asarray(pool.page_table))
+    written = {(int(pool.page_table[s, n // 8]), n % 8)
+               for s, n in lengths.items()}
+    for name in ("k", "v"):
+        before = np.asarray(cache[name], np.float32)
+        after = np.asarray(new[name], np.float32)
+        after_live = after.copy()
+        after_live[:, 0] = before[:, 0]           # the junk page may change
+        _changed_only_at(before, after_live, written)
+    np.testing.assert_array_equal(np.asarray(new["index"]),
+                                  np.asarray(index) + np.asarray(active))
+
+
+def test_paged_chunk_step_writes_only_the_chunk():
+    """One chunk step (16 bucketed tokens, 12 valid, at offset 8 of a slot
+    holding 20): the chunk's K/V land at its positions' (page, offset) in
+    every layer and nothing else in the pool moves."""
+    from repro.training.steps import build_prefill_chunk_step_paged
+    model = _model()
+    params = init_params(model.param_table(), jax.random.PRNGKey(0))
+    pool = _random_pool(model)
+    pool.alloc()
+    slot = pool.alloc()
+    pool.reserve_prefix(slot, 20)
+    cache = dict(pool.cache)
+    step = jax.jit(build_prefill_chunk_step_paged(model),
+                   static_argnums=(6,))
+    toks = jnp.arange(1, 17, dtype=jnp.int32)[None]
+    _, new = step(params, cache, toks, jnp.int32(slot), jnp.int32(8),
+                  jnp.int32(12), 32, *pool.chunk_extras(slot))
+    row = pool.page_table[slot]
+    written = {(int(row[j // 8]), j % 8) for j in range(8, 24)}
+    for name in ("k", "v"):
+        _changed_only_at(np.asarray(cache[name], np.float32),
+                         np.asarray(new[name], np.float32), written)
+    assert int(new["index"][slot]) == 20

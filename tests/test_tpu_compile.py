@@ -12,6 +12,8 @@ only one process at a time may load the TPU library, and every test
 worker imports this file.
 """
 
+import math
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -83,3 +85,80 @@ def test_sedov_step_compiles_on_a_64_cube(one_chip):
         lambda st, dt: sedov_step_pallas(st, dt, interpret=False),
         state, S(()))
     assert "tpu_custom_call" in text
+
+
+# ---------------------------------------------------------------------------
+# The engine's paged steps: the KV pool stays in place
+
+
+def _paged_step(one_chip, monkeypatch, step):
+    """Compile one of the engine's paged steps as the engine jits it (the
+    pool donated), at stablelm-2-1.6b widths cut to 4 layers, over a
+    65-page pool: head_dim 64, so the pool packs two heads to a 128-lane
+    row (``serving/pool.page_rows``).  Returns (compiled, pool shape)."""
+    from repro.configs import get_config
+    from repro.models.params import init_params
+    from repro.models.transformer import model_for
+    from repro.serving.pool import page_rows
+    from repro.training import steps
+
+    # the model's kernel wrapper asks the attached backend (the CPU here)
+    # whether to interpret; this compile is for the described chip
+    monkeypatch.setattr("repro.kernels.ops.interpret_mode", lambda: False)
+    cfg = get_config("stablelm-1.6b").replace(num_layers=4, qkv_bias=True)
+    model = model_for(cfg, remat="none")
+    slots, page_size, num_pages = 4, 16, 65
+    max_pages = (num_pages - 1) // slots
+    pool = (cfg.num_layers, num_pages, page_size) + \
+        page_rows(cfg.num_kv_heads, cfg.head_dim)
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: S(a.shape, a.dtype),
+        jax.eval_shape(lambda: init_params(model.param_table(),
+                                           jax.random.PRNGKey(0))))
+    cache = {"k": S(pool, jnp.bfloat16), "v": S(pool, jnp.bfloat16),
+             "index": S((slots,), jnp.int32)}
+    i32 = jnp.int32
+    if step == "decode":
+        fn = steps.build_decode_step_slots_paged(model, use_kernel=True)
+        args = (params, cache, S((slots, 1), i32), S((slots,), i32),
+                S((slots, max_pages), i32))
+        jitted = jax.jit(fn, donate_argnums=(1,))
+    elif step == "verify":
+        fn = steps.build_verify_step_slots_paged(model)
+        args = (params, cache, S((slots, 4), i32), S((slots,), i32),
+                S((slots, max_pages), i32))
+        jitted = jax.jit(fn, donate_argnums=(1,))
+    else:
+        # a one-token chunk too: XLA is freest to pick its own pool
+        # layout for a one-row scatter
+        fn = steps.build_prefill_chunk_step_paged(model)
+        tokens = 1 if step == "chunk1" else 128
+        args = (params, cache, S((1, tokens), i32), S((), i32), S((), i32),
+                S((), i32), max_pages * page_size, S((max_pages,), i32))
+        jitted = jax.jit(fn, donate_argnums=(1,), static_argnums=(6,))
+    return jitted.lower(*args).compile(), pool
+
+
+@pytest.mark.parametrize("step", ["decode", "chunk", "chunk1", "verify"])
+def test_paged_step_keeps_the_pool_in_place(one_chip, monkeypatch, step):
+    """The layer scan carries the whole pool: the compiled step aliases the
+    donated K and V to its outputs, holds no copy shaped like the pool or
+    like one layer's slice of it, and needs less scratch than one layer's
+    K pool."""
+    compiled, pool = _paged_step(one_chip, monkeypatch, step)
+    text = compiled.as_text()
+    if step == "decode":
+        assert "tpu_custom_call" in text
+    shapes = ["bf16[" + ",".join(map(str, dims)) + "]"
+              for dims in (pool, pool[1:])]
+    copies = [line.strip() for line in text.splitlines()
+              if " copy(" in line and any(s in line for s in shapes)]
+    assert copies == []
+    mem = compiled.memory_analysis()
+    pool_bytes = math.prod(pool) * 2
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // pool[0]
