@@ -1,0 +1,3 @@
+"""idle_share.longgen: see ``bench/readers.py``."""
+
+from bench.readers import idle_share as read  # noqa: F401

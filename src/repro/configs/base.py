@@ -17,6 +17,17 @@ import jax.numpy as jnp
 
 
 @dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rope scaling (DeepSeek-V2's ``rope_scaling`` of type yarn)."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: str                      # dense|moe|ssm_xlstm|hybrid_mamba|encdec|vlm
@@ -32,6 +43,7 @@ class ModelConfig:
     pos: str = "rope"                # rope|learned|sinusoidal|none
     rope_fraction: float = 1.0
     rope_theta: float = 10000.0
+    rope_yarn: Yarn | None = None
     qkv_bias: bool = False
     tie_embeddings: bool = False
     causal: bool = True
@@ -43,6 +55,19 @@ class ModelConfig:
     experts_per_token: int = 0
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
+    moe_d_ff: int = 0                # routed and shared expert width (0: d_ff)
+    shared_experts: int = 0          # always-on experts of width moe_d_ff
+    first_dense_layers: int = 0      # leading layers with a dense d_ff MLP
+    norm_topk: bool = True           # renormalise the top-k gates
+    # the share of the routed experts held here: experts_held (0: all) from
+    # expert_offset; the router still scores all num_experts
+    experts_held: int = 0
+    expert_offset: int = 0
+    # --- latent attention (MLA; kv_lora_rank 0 = ordinary attention) ---
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # --- encoder-decoder (whisper) ---
     num_encoder_layers: int = 0
     # --- VLM (llava) ---

@@ -100,13 +100,21 @@ def param_count_estimate(cfg: ModelConfig) -> int:
     return param_count(model_for(cfg).param_table())
 
 
-def kv_bytes_per_token(cfg: ModelConfig) -> int:
+def kv_bytes_per_token(cfg: ModelConfig, stored: bool = False) -> int:
     """HBM bytes one KV-cache token costs (k+v, all layers) — the unit the
     serve-mode budget is denominated in.  Single source of truth for the
-    tuner, the serving benchmark, and the budget-target tests."""
+    tuner, the serving benchmark, and the budget-target tests.  Latent
+    attention caches one row of ``kv_lora_rank + qk_rope_head_dim`` per
+    layer; ``stored`` counts it as the paged pool stores it, padded to
+    128-lane tiles (``models/mla.latent_lanes``)."""
     import jax.numpy as jnp
-    per = 2 * cfg.num_layers * cfg.num_kv_heads * cfg.head_dim * \
-        jnp.dtype(cfg.activation_dtype).itemsize
+    item = jnp.dtype(cfg.activation_dtype).itemsize
+    if cfg.kv_lora_rank:
+        from repro.models.mla import latent_lanes
+        width = latent_lanes(cfg) if stored else \
+            cfg.kv_lora_rank + cfg.qk_rope_head_dim
+        return cfg.num_layers * width * item
+    per = 2 * cfg.num_layers * cfg.num_kv_heads * cfg.head_dim * item
     if cfg.family == "encdec":
         per *= 2  # self- and cross-attention caches
     return per
@@ -121,13 +129,18 @@ def prefix_cache_quota(num_pages: int) -> int:
 
 
 def active_param_count(cfg: ModelConfig) -> int:
-    """Parameters touched per token (MoE: only top-k experts active)."""
+    """Parameters touched per token (MoE: only top-k experts active).
+    The weights counted are those held here (``param_count_estimate``);
+    where a share of the experts is held, serving computes each held
+    expert for every token, so all of them are active."""
     total = param_count_estimate(cfg)
-    if cfg.family != "moe":
+    if cfg.family != "moe" or cfg.experts_held:
         return total
     gated = 3 if cfg.activation in ("silu", "geglu") else 2
-    per_expert = gated * cfg.d_model * cfg.d_ff
-    inactive = (cfg.num_experts - cfg.experts_per_token) * per_expert * cfg.num_layers
+    per_expert = gated * cfg.d_model * (cfg.moe_d_ff or cfg.d_ff)
+    moe_layers = cfg.num_layers - cfg.first_dense_layers
+    inactive = (cfg.num_experts - cfg.experts_per_token) * per_expert \
+        * moe_layers
     return total - inactive
 
 
@@ -241,7 +254,7 @@ def tune(cfg: ModelConfig, shape: ShapeConfig, target: TargetSpec,
         plan.remat_policy = "none"
         # decode/prefill memory: params + kv cache
         if cfg.family in ("dense", "moe", "vlm", "encdec"):
-            kv_per_token = kv_bytes_per_token(cfg)
+            kv_per_token = kv_bytes_per_token(cfg, stored=True)
             kv = kv_per_token * shape.global_batch * shape.seq_len
             plan.napkin["kv_cache_per_chip"] = f"{kv/chips/1e9:.3f} GB"
             # --- serve-mode KV pool sizing ---------------------------------

@@ -17,6 +17,7 @@ from functools import partial
 import jax
 
 from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels.mla_attention import mla_decode_attention_pallas
 from repro.kernels.paged_attention import paged_attention_pallas
 from repro.kernels.rmsnorm import rmsnorm_pallas
 from repro.kernels.sedov_stencil import cfl_dt, sedov_step_pallas
@@ -62,3 +63,17 @@ def sedov_step_kernel(state: dict, cfg, block_x: int = 16) -> dict:
     dt = cfl_dt(state)
     return sedov_step_pallas(state, dt, block_x=block_x,
                              interpret=interpret_mode())
+
+
+@partial(jax.jit, static_argnames=("scale", "value_lanes"))
+def mla_decode_attention(q, pool, page_table, kv_len, layer, *, scale: float,
+                         value_lanes: int):
+    """Paged latent-attention decode (see kernels/mla_attention.py).
+
+    q: (slots, H, lanes) absorbed queries; pool: (layers, num_pages,
+    page_size, lanes) read at int32 ``layer``; page_table: (slots,
+    max_pages) int32; kv_len: (slots,) int32 (0: nothing to attend).
+    Returns (slots, H, value_lanes)."""
+    return mla_decode_attention_pallas(
+        q, pool, page_table, kv_len, layer, scale=scale,
+        value_lanes=value_lanes, interpret=interpret_mode())

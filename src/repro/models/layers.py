@@ -102,15 +102,21 @@ def apply_rope(x: jax.Array, positions: jax.Array, *, fraction: float = 1.0,
     x_rot, x_pass = x[..., :rot], x[..., rot:]
     freqs = jnp.exp(-jnp.arange(0, rot, 2, dtype=jnp.float32)
                     * (math.log(theta) / rot))
+    out = rotate_pairs(x_rot, positions, freqs)
+    return jnp.concatenate([out, x_pass], axis=-1) if rot < head_dim else out
+
+
+def rotate_pairs(x: jax.Array, positions: jax.Array,
+                 freqs: jax.Array) -> jax.Array:
+    """Rotate adjacent pairs (2i, 2i+1) of x: (b, s, heads, 2 * len(freqs))
+    by ``positions * freqs[i]``; positions: (b, s)."""
     angles = positions[..., None].astype(jnp.float32) * freqs  # (b, s, rot/2)
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
-    x1, x2 = x_rot[..., ::2], x_rot[..., 1::2]
+    x1, x2 = x[..., ::2], x[..., 1::2]
     o1 = x1 * cos - x2 * sin
     o2 = x2 * cos + x1 * sin
-    out = jnp.stack([o1, o2], axis=-1).reshape(x_rot.shape)
-    return jnp.concatenate([out.astype(x.dtype), x_pass], axis=-1) if rot < head_dim \
-        else out.astype(x.dtype)
+    return jnp.stack([o1, o2], axis=-1).reshape(x.shape).astype(x.dtype)
 
 
 def sinusoidal_positions(seq_len: int, d_model: int) -> jax.Array:
@@ -146,10 +152,12 @@ def _attn_one_chunk(q, k, v, mask, scale):
 def dot_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                   causal: bool, q_offset: jax.Array | int = 0,
                   kv_len: jax.Array | None = None,
-                  q_chunk: int = _Q_CHUNK) -> jax.Array:
+                  q_chunk: int = _Q_CHUNK,
+                  scale: float | None = None) -> jax.Array:
     """Grouped-query attention.
 
-    q: (b, s, H, dh); k/v: (b, t, K, dh) with H % K == 0.
+    q: (b, s, H, dh); k: (b, t, K, dh), v: (b, t, K, dv) with H % K == 0;
+    returns (b, s, H, dv).  ``scale`` defaults to dh ** -0.5.
     causal: query i attends keys j <= i + q_offset.
     kv_len: optional valid-length of the kv sequence (decode with a
         pre-allocated cache).
@@ -159,9 +167,10 @@ def dot_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     buffer is (b, H, q_chunk, t) instead of (b, H, s, t).
     """
     b, s, H, dh = q.shape
-    t, K = k.shape[1], k.shape[2]
+    t, K, dv = k.shape[1], k.shape[2], v.shape[-1]
     G = H // K
-    scale = 1.0 / math.sqrt(dh)
+    if scale is None:
+        scale = 1.0 / math.sqrt(dh)
     qg = q.reshape(b, s, K, G, dh).transpose(0, 2, 3, 1, 4)  # b,K,G,s,dh
 
     kv_pos = jnp.arange(t)
@@ -201,9 +210,9 @@ def dot_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             q_pos = i * q_chunk + jnp.arange(q_chunk)
             return _attn_one_chunk(qi, k, v, mask_for(q_pos), scale)
 
-        out = jax.lax.map(one, (jnp.arange(n), qc))          # n,b,K,G,qc,dh
-        out = out.transpose(1, 2, 3, 0, 4, 5).reshape(b, K, G, s, dh)
-    return out.transpose(0, 3, 1, 2, 4).reshape(b, s, H, dh)
+        out = jax.lax.map(one, (jnp.arange(n), qc))          # n,b,K,G,qc,dv
+        out = out.transpose(1, 2, 3, 0, 4, 5).reshape(b, K, G, s, dv)
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, s, H, dv)
 
 
 # ---------------------------------------------------------------------------
@@ -234,6 +243,19 @@ def _set_rows(pool: jax.Array, rows: jax.Array, new: jax.Array) -> jax.Array:
     row = pool.shape[-2:]
     new = new.reshape(new.shape[:-2] + row)
     return pool.reshape((-1,) + row).at[rows].set(new).reshape(pool.shape)
+
+
+def paged_rows(pages: jax.Array, pos: jax.Array, psize: int) -> jax.Array:
+    """Flat token rows (page * psize + offset, within one layer) of
+    positions ``pos`` through page-table rows ``pages`` (``(..., max_pages)``,
+    ``pos`` ``(..., n)``); a position past the table lands in the reserved
+    junk page 0, never wrapped into a page a live request may share."""
+    max_pages = pages.shape[-1]
+    logical = pos // psize
+    dest = jnp.take_along_axis(pages, jnp.minimum(logical, max_pages - 1),
+                               axis=-1)
+    return jnp.where(logical < max_pages, dest * psize + pos % psize,
+                     pos % psize)
 
 
 def _take_pages(pool: jax.Array, ids: jax.Array) -> jax.Array:
@@ -327,39 +349,16 @@ def attention(p: dict, x: jax.Array, cfg, mesh, *, positions: jax.Array,
             max_pages = pages.shape[1]
             Kh, dh = k.shape[2], k.shape[3]
             with jax.named_scope("kv_write"):
-                if s == 1:
-                    logical_page = idx // psize
-                    ok = logical_page < max_pages
-                    dest = jnp.take_along_axis(
-                        pages,
-                        jnp.minimum(logical_page, max_pages - 1)[:, None],
-                        axis=1)[:, 0]                           # (slots,)
-                    # out-of-range writes (a slot already at its page-run
-                    # capacity) route to the reserved junk page 0 — NOT
-                    # wrapped into the slot's last page, which under the
-                    # prefix cache may be shared with a live request (same
-                    # ok-guard as the chunk path below)
-                    fpos = jnp.where(ok, dest * psize + idx % psize,
-                                     idx % psize)
-                    new_k, new_v = k[:, 0], v[:, 0]
-                else:
-                    # VERIFY burst: each row writes s speculative positions
-                    # idx..idx+s-1.  Per-position page lookup keeps the
-                    # same junk-page-0 ok-guard, so a burst past a slot's
-                    # page-run capacity can never scribble into a
-                    # (possibly prefix-shared) live page.
-                    pos = idx[:, None] + jnp.arange(s)[None, :]  # (slots, s)
-                    logical_page = pos // psize
-                    ok = logical_page < max_pages
-                    dest = jnp.take_along_axis(
-                        pages, jnp.minimum(logical_page, max_pages - 1),
-                        axis=1)
-                    fpos = jnp.where(ok, dest * psize + pos % psize,
-                                     pos % psize)
-                    new_k, new_v = k, v
-                fpos = _in_layer(fpos, layer, n_pages * psize)
-                k_all = _set_rows(cache["k"], fpos, new_k)
-                v_all = _set_rows(cache["v"], fpos, new_v)
+                # each row writes its positions idx..idx+s-1 (s > 1: a
+                # VERIFY burst); a position past the slot's page run (a
+                # slot at capacity) goes to the reserved junk page 0, never
+                # wrapped into the slot's last page, which under the prefix
+                # cache may be shared with a live request
+                pos = idx[:, None] + jnp.arange(s)[None, :]  # (slots, s)
+                fpos = _in_layer(paged_rows(pages, pos, psize), layer,
+                                 n_pages * psize)
+                k_all = _set_rows(cache["k"], fpos, k)
+                v_all = _set_rows(cache["v"], fpos, v)
             with jax.named_scope("attn"):
                 if cache.get("use_kernel") and s == 1:
                     # fused Pallas path (single-token decode only; verify
@@ -451,13 +450,8 @@ def attention(p: dict, x: jax.Array, cfg, mesh, *, positions: jax.Array,
                 pages_row = cache["pages_row"]      # (max_pages,) int32
                 n_pages, psize = cache["k"].shape[-4:-2]
                 max_pages = pages_row.shape[0]
-                logical = pos // psize
-                ok = logical < max_pages
-                dest = jnp.take(pages_row,
-                                jnp.minimum(logical, max_pages - 1))
-                fpos = jnp.where(ok, dest * psize + pos % psize,
-                                 pos % psize)
-                fpos = _in_layer(fpos, layer, n_pages * psize)
+                fpos = _in_layer(paged_rows(pages_row, pos, psize), layer,
+                                 n_pages * psize)
                 k_all = _set_rows(cache["k"], fpos, k[0])
                 v_all = _set_rows(cache["v"], fpos, v[0])
             else:

@@ -12,7 +12,9 @@ All families implement ``BaseLM``:
 Layers are stacked with ``lax.scan`` (compile time on deep models) and
 wrapped in ``jax.checkpoint`` per the deployment plan's remat policy.
 Decode and chunked prefill carry the whole (layers, ...) KV cache
-through the scan and update it in place (``scan_cache``).
+through the scan and update it in place (``scan_cache``).  A config with
+``kv_lora_rank`` attends by latent attention (``models/mla.py``), whose
+cache is one latent row per token (``latent``) instead of K and V.
 """
 
 from __future__ import annotations
@@ -25,6 +27,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.models import layers as L
+from repro.models import mla
 from repro.models.params import ParamDef, _map_table
 from repro.sharding.rules import shard_constraint
 
@@ -38,6 +41,11 @@ def remat_wrap(fn, policy: str):
     if policy == "full":
         return jax.checkpoint(fn)
     raise ValueError(policy)
+
+
+# the entries of a serving cache that hold one stack per layer: the layer
+# scan carries them and each layer writes its part in place
+CARRIED = ("k", "v", "latent", "route_counts")
 
 
 def stack_defs(defs: dict, n: int) -> dict:
@@ -92,12 +100,14 @@ class DenseLM(BaseLM):
     """Llama/Mistral/Nemotron/StableLM-style decoder; also the VLM backbone."""
 
     # ---- tables ----
-    def block_defs(self) -> dict:
+    def block_defs(self, mlp: dict | None = None) -> dict:
         cfg = self.cfg
+        attn = mla.mla_defs(cfg) if cfg.kv_lora_rank else \
+            L.attention_defs(cfg)
         d = {"ln1": L.norm_defs(cfg.d_model, cfg.norm),
-             "attn": L.attention_defs(cfg),
+             "attn": attn,
              "ln2": L.norm_defs(cfg.d_model, cfg.norm),
-             "mlp": self.mlp_defs()}
+             "mlp": self.mlp_defs() if mlp is None else mlp}
         return d
 
     def mlp_defs(self) -> dict:
@@ -120,13 +130,20 @@ class DenseLM(BaseLM):
                 "index": ParamDef((), (), jnp.int32, "zeros")}
 
     # ---- block ----
+    def attend(self, p, h, mesh, positions, mode, cache):
+        """The block's attention: (out, new cache)."""
+        cfg = self.cfg
+        if cfg.kv_lora_rank:
+            return mla.attention(p, h, cfg, mesh, positions=positions,
+                                 mode=mode, cache=cache)
+        return L.attention(p, h, cfg, mesh, positions=positions, mode=mode,
+                           cache=cache, window=cfg.window or None)
+
     def block_apply(self, p, x, mesh, positions, mode, cache):
         cfg = self.cfg
         h = L.apply_norm(p["ln1"], x, cfg.norm)
-        window = cfg.window or None
-        attn_out, new_cache = L.attention(
-            p["attn"], h, cfg, mesh, positions=positions, mode=mode,
-            cache=cache, window=window)
+        attn_out, new_cache = self.attend(p["attn"], h, mesh, positions,
+                                          mode, cache)
         x = x + attn_out
         h = L.apply_norm(p["ln2"], x, cfg.norm)
         with jax.named_scope("mlp"):
@@ -136,30 +153,34 @@ class DenseLM(BaseLM):
     def mlp_apply(self, p, h, mesh):
         return L.mlp(p, h, self.cfg, mesh)
 
-    def block_cache(self, out):
-        """The new cache from ``block_apply``'s second output."""
-        return out
+    def layer_stacks(self, params) -> list:
+        """(stacked block params, id of its first layer, block function)
+        for each run of like layers, in order."""
+        return [(params["blocks"], 0, self.block_apply)]
 
-    def scan_cache(self, blocks, x, positions, mesh, mode, k, v, rest):
-        """Decode or chunk over scanned layers with the WHOLE KV cache,
-        (layers, ...) K and V, in the scan carry: each layer gets both
-        stacks and its layer id, writes its new K/V in place and reads
-        its own layer where it lies.  Carried (not scanned as xs -> ys),
-        the cache is updated in place — no layer's slice is copied out
-        and back, and the donated buffer aliases the step's output.
-        ``rest`` is the layer-invariant rest of the cache.  Returns
-        (x, new K, new V)."""
-        def body(carry, xs):
-            y, k, v = carry
-            bp, layer = xs
-            c = dict(rest, k=k, v=v, layer=layer)
-            y, out = self.block_apply(bp, y, mesh, positions, mode, c)
-            nc = self.block_cache(out)
-            return (y, nc["k"], nc["v"]), None
+    def scan_cache(self, params, x, positions, mesh, mode, stores, rest):
+        """Decode or chunk over scanned layers with the WHOLE cache in the
+        scan carry: ``stores`` are its (layers, ...) stacks (``CARRIED``:
+        K and V, or the latent rows, and any counters), and each layer
+        gets them all and its layer id, writes its part in place and
+        reads its own layer where it lies.  Carried (not scanned as
+        xs -> ys), the cache is updated in place — no layer's slice is
+        copied out and back, and the donated buffer aliases the step's
+        output.  ``rest`` is the layer-invariant rest of the cache.
+        Returns (x, new stores)."""
+        for blocks, first, apply in self.layer_stacks(params):
+            def body(carry, xs, apply=apply):
+                y, st = carry
+                bp, layer = xs
+                y, nc = apply(bp, y, mesh, positions, mode,
+                              dict(rest, **st, layer=layer))
+                return (y, {n: nc.get(n, st[n]) for n in st}), None
 
-        layers = jnp.arange(self.cfg.num_layers, dtype=jnp.int32)
-        (x, k, v), _ = jax.lax.scan(body, (x, k, v), (blocks, layers))
-        return x, k, v
+            n = jax.tree.leaves(blocks)[0].shape[0]
+            layers = first + jnp.arange(n, dtype=jnp.int32)
+            (x, stores), _ = jax.lax.scan(body, (x, stores),
+                                          (blocks, layers))
+        return x, stores
 
     # ---- backbone over scanned layers ----
     def backbone(self, params, x, positions, mesh, mode, cache=None):
@@ -179,27 +200,33 @@ class DenseLM(BaseLM):
             # the rest of the cache is layer-invariant and closes over the
             # scan body: decode's index (a scalar, or a per-slot vector
             # under continuous batching) and the paged layout's (slots,
-            # max_pages) page table; the chunk's slot, offset and page
-            # table row; the STATIC kv_bound and use_kernel (the fused
-            # Pallas paged-decode kernel), never part of the jit pytree
-            rest = {n: c for n, c in cache.items() if n not in ("k", "v")}
-            x, k, v = self.scan_cache(blocks, x, positions, mesh, mode,
-                                      cache["k"], cache["v"], rest)
+            # max_pages) page table; the chunk's slot, offset, valid
+            # length and page table row; the STATIC kv_bound and
+            # use_kernel (the fused Pallas paged-decode kernel), never
+            # part of the jit pytree
+            stores = {n: c for n, c in cache.items() if n in CARRIED}
+            rest = {n: c for n, c in cache.items() if n not in CARRIED}
+            x, stores = self.scan_cache(params, x, positions, mesh, mode,
+                                        stores, rest)
             if mode == "chunk":
-                return x, {"k": k, "v": v}
-            new_cache = {"k": k, "v": v, "index": cache["index"] + x.shape[1]}
+                return x, stores
+            new_cache = dict(stores, index=cache["index"] + x.shape[1])
             if "pages" in cache:
                 new_cache["pages"] = cache["pages"]
             return x, new_cache
 
-        # prefill
-        def body_p(carry, bp):
-            y, nc = self.block_apply(bp, carry, mesh, positions, "prefill", None)
-            return y, (nc["k"], nc["v"]) if nc is not None else None
+        # prefill: each layer's fresh cache, stacked
+        caches = []
+        for blocks, first, apply in self.layer_stacks(params):
+            def body_p(carry, bp, apply=apply):
+                y, nc = apply(bp, carry, mesh, positions, "prefill", None)
+                return y, {n: c for n, c in nc.items() if n in CARRIED}
 
-        x, kvs = jax.lax.scan(body_p, x, blocks)
-        new_cache = {"k": kvs[0], "v": kvs[1],
-                     "index": jnp.asarray(x.shape[1], jnp.int32)}
+            x, nc = jax.lax.scan(body_p, x, blocks)
+            caches.append(nc)
+        new_cache = caches[0] if len(caches) == 1 else \
+            jax.tree.map(lambda *c: jnp.concatenate(c), *caches)
+        new_cache["index"] = jnp.asarray(x.shape[1], jnp.int32)
         return x, new_cache
 
     # ---- entry points ----
@@ -264,18 +291,18 @@ class DenseLM(BaseLM):
         positions = offset + jnp.broadcast_to(
             jnp.arange(c, dtype=jnp.int32), (b, c))
         x = self.embed_inputs(params, {"tokens": tokens}, mesh, positions)
-        chunk_cache = {"k": cache["k"], "v": cache["v"],
-                       "slot": slot, "offset": offset,
-                       "kv_bound": int(kv_bound)}
+        chunk_cache = {n: c for n, c in cache.items() if n in CARRIED}
+        chunk_cache.update(slot=slot, offset=offset, n_valid=n_valid,
+                           kv_bound=int(kv_bound))
         if pages_row is not None:
             chunk_cache["pages_row"] = pages_row
         with jax.named_scope("layers"):
-            x, new_kv = self.backbone(params, x, positions, mesh, "chunk",
+            x, stores = self.backbone(params, x, positions, mesh, "chunk",
                                       cache=chunk_cache)
         x_last = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
         logits = self.logits_from(params, x_last, mesh)
         index = cache["index"].at[slot].set(offset + n_valid)
-        return logits, {"k": new_kv["k"], "v": new_kv["v"], "index": index}
+        return logits, dict(stores, index=index)
 
     def decode_step(self, params, cache, tokens, mesh):
         b, s = tokens.shape

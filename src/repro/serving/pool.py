@@ -21,8 +21,13 @@ fixed-size pages plus a per-slot page-table indirection,
 
 where one token's ``(kv_heads, head_dim)`` is stored as ``(rows, lanes)``
 (``page_rows``: the heads themselves, or several heads to a 128-lane row
-where head_dim is smaller), so a request only ever holds
-``ceil(len / page_size)`` pages and the
+where head_dim is smaller).  A latent-attention model (``kv_lora_rank``)
+stores one array instead of K and V, ``latent: (layers, num_pages,
+page_size, lanes)``, a token's latent row padded to 128-lane tiles
+(``models/mla.latent_lanes``); an MoE model's pool also carries
+``route_counts`` (num_experts,) int32, the tokens its steps have routed
+to each expert, added up on the device (``page_stores``).  So a request
+only ever holds ``ceil(len / page_size)`` pages and the
 tuner's HBM budget buys admitted *tokens* instead of admitted worst
 cases.  Page 0 is a reserved junk page: inactive slots (zeroed
 page-table rows) scatter their dead writes there and nothing ever reads
@@ -93,7 +98,7 @@ class _FreeList:
         return bool(self._mask[idx])
 
 
-def _check_servable(cfg):
+def _check_servable(cfg, layout="paged"):
     if cfg.family not in ("dense", "moe"):
         raise NotImplementedError(
             f"KV pools serve attention-cache families (dense/moe), "
@@ -103,6 +108,9 @@ def _check_servable(cfg):
             "slot-wise decode does not apply sliding-window attention "
             "yet; a windowed config served here would silently attend "
             "the full history")
+    if cfg.kv_lora_rank and layout != "paged":
+        raise NotImplementedError(
+            "latent attention is served from the paged pool only")
 
 
 @partial(jax.jit, donate_argnums=(0,))
@@ -136,24 +144,43 @@ def page_rows(num_kv_heads: int, head_dim: int) -> tuple:
     return num_kv_heads, head_dim
 
 
+def page_stores(cfg, num_pages: int, page_size: int) -> dict:
+    """The arrays a paged pool holds for ``cfg`` besides its index: K and
+    V, or the latent rows, one stack per layer, and an MoE model's
+    routing counters."""
+    lead = (cfg.num_layers, num_pages, page_size)
+    if cfg.kv_lora_rank:
+        from repro.models.mla import latent_lanes
+        out = {"latent": jnp.zeros(lead + (latent_lanes(cfg),),
+                                   cfg.activation_dtype)}
+    else:
+        shape = lead + page_rows(cfg.num_kv_heads, cfg.head_dim)
+        out = {"k": jnp.zeros(shape, cfg.activation_dtype),
+               "v": jnp.zeros(shape, cfg.activation_dtype)}
+    if cfg.family == "moe":
+        out["route_counts"] = jnp.zeros((cfg.num_experts,), jnp.int32)
+    return out
+
+
 @partial(jax.jit, donate_argnums=(0,))
-def _scatter_insert_paged(cache, slot, pages_row, pk, pv):
-    """Write a batch-1 prefill cache (L, 1, s, K, dh) through `pages_row`.
+def _scatter_insert_paged(cache, slot, pages_row, prefill):
+    """Write a batch-1 prefill cache — each store (L, 1, s, ...) — through
+    `pages_row`.
 
     Token position j lands in page ``pages_row[j // page_size]`` at offset
     ``j % page_size`` — the same indirection the decode step reads back.
     """
-    L, _, s = pk.shape[:3]
-    shape = cache["k"].shape
-    P, psize, row = shape[1], shape[2], shape[3:]
-    pos = jnp.arange(s)
-    fpos = pages_row[pos // psize] * psize + pos % psize  # (s,)
-    k = cache["k"].reshape((L, P * psize) + row).at[:, fpos].set(
-        pk[:, 0].reshape((L, s) + row))
-    v = cache["v"].reshape((L, P * psize) + row).at[:, fpos].set(
-        pv[:, 0].reshape((L, s) + row))
-    index = cache["index"].at[slot].set(s)
-    return {"k": k.reshape(shape), "v": v.reshape(shape), "index": index}
+    out = dict(cache)
+    for name, new in prefill.items():
+        L, _, s = new.shape[:3]
+        shape = cache[name].shape
+        P, psize, row = shape[1], shape[2], shape[3:]
+        pos = jnp.arange(s)
+        fpos = pages_row[pos // psize] * psize + pos % psize  # (s,)
+        out[name] = cache[name].reshape((L, P * psize) + row).at[
+            :, fpos].set(new[:, 0].reshape((L, s) + row)).reshape(shape)
+    out["index"] = cache["index"].at[slot].set(s)
+    return out
 
 
 class KVCachePool:
@@ -163,7 +190,7 @@ class KVCachePool:
 
     def __init__(self, model, num_slots: int, max_len: int):
         cfg = model.cfg
-        _check_servable(cfg)
+        _check_servable(cfg, self.layout)
         if num_slots < 1 or max_len < 1:
             raise ValueError((num_slots, max_len))
         self.cfg = cfg
@@ -317,11 +344,8 @@ class PagedKVCachePool:
         if self.num_pages < 2:
             raise ValueError(f"num_pages {self.num_pages} < 2 "
                              f"(page 0 is reserved)")
-        kv_shape = (cfg.num_layers, self.num_pages, page_size) + \
-            page_rows(cfg.num_kv_heads, cfg.head_dim)
-        self.cache = {"k": jnp.zeros(kv_shape, cfg.activation_dtype),
-                      "v": jnp.zeros(kv_shape, cfg.activation_dtype),
-                      "index": jnp.zeros((num_slots,), jnp.int32)}
+        self.cache = dict(page_stores(cfg, self.num_pages, page_size),
+                          index=jnp.zeros((num_slots,), jnp.int32))
         self.page_table = np.zeros((num_slots, self.max_pages), np.int32)
         self._pages_held = np.zeros((num_slots,), np.int64)
         self._free = _FreeList(num_slots)
@@ -495,14 +519,14 @@ class PagedKVCachePool:
         engine now writes through ``reserve_prefix`` + the chunked
         prefill step, which scatters each chunk's KV to its final
         page/offset directly."""
-        pk, pv = prefill_cache["k"], prefill_cache["v"]
-        s = pk.shape[2]
+        stores = {n: c for n, c in prefill_cache.items() if n != "index"}
+        s = next(iter(stores.values())).shape[2]
         if s > self.max_len:
             raise ValueError(f"prefill length {s} > pool max_len {self.max_len}")
         self.reserve_prefix(slot, s)
         self.cache = _scatter_insert_paged(
             self.cache, jnp.int32(slot),
-            jnp.asarray(self.page_table[slot]), pk, pv)
+            jnp.asarray(self.page_table[slot]), stores)
         self.lengths[slot] = s
 
     def reserve_prefix(self, slot: int, n_tokens: int) -> None:

@@ -252,6 +252,11 @@ class ServeStats:
     # every admitted request that asked for a top-k filter — surfaces what
     # the sampler actually applied instead of silently clamping
     effective_top_k: dict = dataclasses.field(default_factory=dict)
+    # MoE routing, read from the pool's on-device counters once per
+    # stats() call (never per tick): tokens routed to each held expert
+    # {expert id: tokens} and the share of all top-k picks they took
+    expert_tokens: dict = dataclasses.field(default_factory=dict)
+    held_pick_share: float = float("nan")
 
     @property
     def tokens_per_s(self) -> float:
@@ -293,6 +298,9 @@ class ServeStats:
         reg.set("serve_spec_verify_steps", self.spec_verify_steps)
         reg.set("serve_spec_drafted_tokens", self.spec_drafted_tokens)
         reg.set("serve_spec_accepted_tokens", self.spec_accepted_tokens)
+        reg.set("serve_held_pick_share", self.held_pick_share)
+        for e, n in self.expert_tokens.items():
+            reg.set(f"serve_expert{e}_tokens", n)
         return reg.snapshot()
 
     @property
@@ -974,6 +982,7 @@ class Scheduler:
             if r.meets_slo(self.slo_ttft_steps, self.slo_e2e_steps))
         mgr = self._mgr
         pc = getattr(self.pool, "prefix_cache", None)
+        expert_tokens, held_share = self._routing()
         return ServeStats(
             results=done, wall_s=wall, decode_steps=self._steps,
             generated_tokens=sum(len(r.tokens) for r in done),
@@ -1001,7 +1010,25 @@ class Scheduler:
             spec_drafted_tokens=self._spec_drafted,
             spec_accepted_tokens=self._spec_accepted,
             total_vsteps=self.vclock.t - self._v0,
-            effective_top_k=dict(self._eff_topk))
+            effective_top_k=dict(self._eff_topk),
+            expert_tokens=expert_tokens, held_pick_share=held_share)
+
+    def _routing(self) -> tuple:
+        """({held expert: tokens routed to it}, share of all picks on
+        held experts) from the pool's ``route_counts``, summed over
+        layers and over every step the pool has run; ({}, NaN) without
+        them."""
+        counts = (self.pool.cache or {}).get("route_counts")
+        if counts is None:
+            return {}, float("nan")
+        counts = np.asarray(counts)
+        cfg = self.pool.cfg
+        first = cfg.expert_offset
+        held = range(first, first + (cfg.experts_held or cfg.num_experts))
+        total = int(counts.sum())
+        share = sum(int(counts[e]) for e in held) / total if total \
+            else float("nan")
+        return {e: int(counts[e]) for e in held}, share
 
     # -- main loop ---------------------------------------------------------
     def run(self, requests) -> ServeStats:

@@ -117,6 +117,9 @@ SERVE_SCHEMA = _prefixed("serve_", _COMMON) + (
     _c("serve_spec_verify_steps", "speculative slot-verify scoring events"),
     _c("serve_spec_drafted_tokens", "draft tokens proposed"),
     _c("serve_spec_accepted_tokens", "draft tokens accepted"),
+    _g("serve_held_pick_share",
+       "share of the router's top-k picks on held experts (NaN: no MoE)"),
+    _c("serve_expert{i}_tokens", "tokens routed to held expert i"),
 )
 
 #: Flat key schema behind ``RouterStats.to_metrics()`` — the table in

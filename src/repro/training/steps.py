@@ -206,7 +206,8 @@ def build_decode_step_slots_paged(model, mesh=None, use_kernel: bool = False):
     """Slot-wise decode over a *paged* KV pool (PagedKVCachePool).
 
     Same contract as ``build_decode_step_slots``, but the cache's K/V are
-    a page pool ``(layers, num_pages, page_size, rows, lanes)`` and
+    a page pool ``(layers, num_pages, page_size, rows, lanes)`` (or one
+    latent pool, ``serving/pool.page_stores``) and
     the per-slot ``(num_slots, max_pages)`` int32 page table arrives as an
     extra argument each step (the pool keeps it on the host so page
     alloc/free never touches the device).  The model reads and writes K/V
@@ -240,9 +241,15 @@ def build_decode_step_slots_paged(model, mesh=None, use_kernel: bool = False):
             dcache["use_kernel"] = True
         logits, new_cache = model.decode_step(params, dcache, tokens, mesh)
         new_index = jnp.where(keep, new_cache["index"], cache["index"])
-        return logits, {"k": new_cache["k"], "v": new_cache["v"],
-                        "index": new_index}
+        return logits, _pool_cache(new_cache, new_index)
     return decode_step
+
+
+def _pool_cache(new_cache, index):
+    """The pool's cache tree from a paged step's: its stores (K and V, or
+    the latent rows, and any counters) and ``index``."""
+    return dict({n: c for n, c in new_cache.items() if n != "pages"},
+                index=index)
 
 
 def build_verify_step_slots(model, mesh=None):
@@ -284,6 +291,5 @@ def build_verify_step_slots_paged(model, mesh=None):
         safe_pages = jnp.where(keep[:, None], pages, 0)
         dcache = dict(cache, pages=safe_pages)
         logits, new_cache = model.decode_step(params, dcache, tokens, mesh)
-        return logits, {"k": new_cache["k"], "v": new_cache["v"],
-                        "index": cache["index"]}
+        return logits, _pool_cache(new_cache, cache["index"])
     return verify_step

@@ -29,6 +29,13 @@ ASSIGNED = {
     "zamba2-7b": dict(num_layers=81, d_model=3584, num_heads=32,
                       num_kv_heads=32, d_ff=14336, vocab_size=32000,
                       ssm_state=64),
+    "deepseek-v2-lite": dict(num_layers=27, d_model=2048, num_heads=16,
+                             d_ff=10944, vocab_size=102400, num_experts=64,
+                             experts_per_token=6, moe_d_ff=1408,
+                             shared_experts=2, first_dense_layers=1,
+                             kv_lora_rank=512, qk_nope_head_dim=128,
+                             qk_rope_head_dim=64, v_head_dim=128,
+                             norm_topk=False),
 }
 
 
@@ -59,22 +66,22 @@ def test_shapes_match_assignment():
 
 def test_cell_accounting_40():
     all_cells = cells(include_skipped=True)
-    assert len(all_cells) == 40  # 10 archs x 4 shapes
+    assert len(all_cells) == 44  # 11 archs x 4 shapes
     skipped = [c for c in all_cells if c[2]]
-    # 8 full-attention archs skip long_500k; ssm/hybrid run it
-    assert len(skipped) == 8
+    # 9 full-attention archs skip long_500k; ssm/hybrid run it
+    assert len(skipped) == 9
     for arch, shape, _ in skipped:
         assert shape == "long_500k"
         assert not get_config(arch).sub_quadratic
     runnable = cells()
-    assert len(runnable) == 32
+    assert len(runnable) == 35
 
 
 @pytest.mark.parametrize("arch,target", [
     ("mistral-large-123b", 123e9), ("nemotron-4-340b", 340e9),
     ("dbrx-132b", 132e9), ("deepseek-7b", 7e9), ("stablelm-1.6b", 1.6e9),
     ("xlstm-1.3b", 1.3e9), ("zamba2-7b", 7e9), ("llava-next-34b", 34e9),
-    ("granite-moe-3b-a800m", 3.4e9),
+    ("granite-moe-3b-a800m", 3.4e9), ("deepseek-v2-lite", 15.7e9),
 ])
 def test_param_counts_near_nameplate(arch, target):
     n = param_count_estimate(get_config(arch))

@@ -181,6 +181,37 @@ def test_paged_matches_contiguous_moe_family():
     assert _tokens(ec.run(reqs)) == _tokens(ep.run(reqs))
 
 
+@pytest.mark.parametrize("arch", ["granite-moe-3b-a800m-smoke",
+                                  "dbrx-132b-smoke"])
+def test_moe_serving_matches_capacity_dispatch_where_none_drop(
+        arch, monkeypatch):
+    """Dropless serving gives the token stream the capacity dispatch
+    (``moe_mlp``, the training path) gives where its capacity drops no
+    token: the same layer, computed another way.  In float32, as the two
+    round a bfloat16 sum in different orders (a near tie then flips)."""
+    from repro.configs import base
+    from repro.models import moe
+
+    def capacity_dispatch(p, x, cfg, valid=None):
+        roomy = cfg.replace(
+            capacity_factor=cfg.num_experts / cfg.experts_per_token)
+        y, _ = moe.moe_mlp(p, x, roomy, None)
+        return y, jnp.zeros((cfg.num_experts,), jnp.int32)
+
+    f32 = smoke_config(arch).replace(name=arch + "-f32",
+                                     param_dtype=jnp.float32,
+                                     activation_dtype=jnp.float32)
+    monkeypatch.setitem(base.ARCHS, f32.name, {
+        "full": f32, "smoke": f32, "skip_shapes": (), "is_smoke": True})
+    kw = dict(arch=f32.name, num_slots=3, max_len=48, seed=0,
+              kv_layout="paged", page_size=8, log=lambda *a, **k: None)
+    reqs = zipf_trace(6, smoke_config(arch).vocab_size, max_prompt=16,
+                      max_new=10, seed=1)
+    dropless = _tokens(ServeEngine(**kw).run(reqs, prefill_chunk=4))
+    monkeypatch.setattr(moe, "held_experts_mlp", capacity_dispatch)
+    assert _tokens(ServeEngine(**kw).run(reqs, prefill_chunk=4)) == dropless
+
+
 @settings(max_examples=5, deadline=None)
 @given(page_size=st.sampled_from([8, 16, 32]),
        trace_seed=st.integers(min_value=0, max_value=30))

@@ -162,3 +162,85 @@ def test_paged_step_keeps_the_pool_in_place(one_chip, monkeypatch, step):
     pool_bytes = math.prod(pool) * 2
     assert mem.alias_size_in_bytes >= 2 * pool_bytes
     assert mem.temp_size_in_bytes < pool_bytes // pool[0]
+
+
+# ---------------------------------------------------------------------------
+# DeepSeek-V2-Lite: the latent-attention kernel and steps
+
+
+def test_mla_decode_attention_compiles_at_real_widths(one_chip):
+    """The cell's decode kernel: 64 slots x 256 pages of 16, 16 heads over
+    640-lane latent rows (512 of them values), 27 layers of pool."""
+    from repro.kernels.mla_attention import mla_decode_attention_pallas
+    slots, heads, lanes, pages, layers = 64, 16, 640, 256, 27
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = _compile_text(
+        lambda q, pool, pt, kl, ly: mla_decode_attention_pallas(
+            q, pool, pt, kl, ly, scale=0.1, value_lanes=512,
+            interpret=False),
+        S((slots, heads, lanes), jnp.bfloat16),
+        S((layers, slots * pages + 1, 16, lanes), jnp.bfloat16),
+        S((slots, pages), jnp.int32), S((slots,), jnp.int32),
+        S((), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("step", ["decode", "chunk", "chunk1"])
+def test_mla_step_keeps_the_latent_pool_in_place(one_chip, monkeypatch,
+                                                  step):
+    """DeepSeek-V2-Lite's decode and chunk steps at published widths, cut
+    to its dense layer and 2 MoE layers holding 8 of 64 experts, donated
+    as the engine jits them: the latent pool aliases the output, no copy
+    shaped like it or like one layer of it, scratch under one layer."""
+    from repro.configs import get_config
+    from repro.models.params import init_params
+    from repro.models.transformer import model_for
+    from repro.serving.pool import page_stores
+    from repro.training import steps
+
+    monkeypatch.setattr("repro.kernels.ops.interpret_mode", lambda: False)
+    cfg = get_config("deepseek-v2-lite").replace(num_layers=3,
+                                                 experts_held=8)
+    model = model_for(cfg, remat="none")
+    slots, page_size, num_pages = 8, 16, 1025
+    max_pages = (num_pages - 1) // slots
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: S(a.shape, a.dtype),
+        jax.eval_shape(lambda: init_params(model.param_table(),
+                                           jax.random.PRNGKey(0))))
+    stores = jax.eval_shape(lambda: page_stores(cfg, num_pages, page_size))
+    cache = {n: S(a.shape, a.dtype) for n, a in stores.items()}
+    cache["index"] = S((slots,), jnp.int32)
+    i32 = jnp.int32
+    if step == "decode":
+        fn = steps.build_decode_step_slots_paged(model, use_kernel=True)
+        args = (params, cache, S((slots, 1), i32), S((slots,), i32),
+                S((slots, max_pages), i32))
+        jitted = jax.jit(fn, donate_argnums=(1,))
+    else:
+        fn = steps.build_prefill_chunk_step_paged(model)
+        tokens = 1 if step == "chunk1" else 128
+        args = (params, cache, S((1, tokens), i32), S((), i32), S((), i32),
+                S((), i32), max_pages * page_size, S((max_pages,), i32))
+        jitted = jax.jit(fn, donate_argnums=(1,), static_argnums=(6,))
+    compiled = jitted.lower(*args).compile()
+    text = compiled.as_text()
+    if step == "decode":
+        assert "tpu_custom_call" in text
+    pool = stores["latent"].shape
+    shapes = ["bf16[" + ",".join(map(str, dims)) + "]"
+              for dims in (pool, pool[1:])]
+    copies = [line.strip() for line in text.splitlines()
+              if " copy(" in line and any(s in line for s in shapes)]
+    assert copies == []
+    mem = compiled.memory_analysis()
+    pool_bytes = math.prod(pool) * 2
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // pool[0]
