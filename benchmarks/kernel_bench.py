@@ -85,8 +85,8 @@ def run(report) -> None:
     # gather: the materialized (slots, max_pages*psize, K, dh) K+V tensor
     # is written once and read back by attention
     gather_bytes = 2 * 2 * slots * max_pages * psize * Kp * dhp * item
-    # fused: held pages streamed from the pool, once per phase, no write
-    fused_paged_bytes = 3 * 2 * sum(held) * psize * Kp * dhp * item
+    # fused: each held page's K and V copied from the pool once, no write
+    fused_paged_bytes = 2 * sum(held) * psize * Kp * dhp * item
     report("kernel_paged_decode_gather", t_gather * 1e6,
            f"bytes={gather_bytes}")
     report("kernel_paged_decode_fused", t_fused * 1e6,

@@ -34,9 +34,11 @@ traffic lives in):
    paged trace with ``kv_kernel='pallas'`` — the fused Pallas
    paged-attention kernel walking the page table in-kernel instead of
    materializing the (slots, max_pages*page_size, K, dh) gather each
-   tick.  Gated to be token-identical to the gather cell; wall time on
-   CPU is interpret-mode emulation (the bytes-moved win is quoted by
-   ``benchmarks/kernel_bench.py``'s ``kernel_paged_decode_*`` cells).
+   tick.  Gated to be token-identical to the gather path in float32 (in
+   bfloat16 the two agree to the dtype's rounding, so a near tie may
+   part their streams); wall time on CPU is interpret-mode emulation
+   (the bytes-moved win is quoted by ``benchmarks/kernel_bench.py``'s
+   ``kernel_paged_decode_*`` cells).
 7. **spec-off vs draft-then-verify decode** (this PR): a repetitive
    greedy trace (``repetitive_trace`` over the 4-token-vocab
    ``picolm-4-smoke``, whose streams settle into n-gram-predictable
@@ -125,10 +127,26 @@ def _register_tight_target(max_len: int = MAX_LEN) -> str:
     return name
 
 
+def _register_f32(arch: str) -> str:
+    """`arch` in float32 (parameters and activations, so the KV pool
+    too), registered under its own name; returns the name."""
+    import jax.numpy as jnp
+    from repro.configs.base import ARCHS, get_config
+
+    name = arch + "-f32"
+    if name not in ARCHS:
+        cfg = get_config(arch).replace(name=name, param_dtype=jnp.float32,
+                                       activation_dtype=jnp.float32)
+        ARCHS[name] = {"full": cfg, "smoke": cfg, "skip_shapes": (),
+                       "is_smoke": True}
+    return name
+
+
 def _engine(kv_layout: str, target: str = "local:cpu", slots: int = SLOTS,
-            max_len: int = MAX_LEN, kv_kernel: str = "auto"):
+            max_len: int = MAX_LEN, kv_kernel: str = "auto",
+            arch: str = ARCH):
     from repro.serving import ServeEngine
-    return ServeEngine(arch=ARCH, target=target, num_slots=slots,
+    return ServeEngine(arch=arch, target=target, num_slots=slots,
                        max_len=max_len, seed=0, kv_layout=kv_layout,
                        kv_kernel=kv_kernel, log=lambda *a, **k: None)
 
@@ -371,9 +389,9 @@ def run_smoke(out_path: str = "BENCH_serving.json",
             }
     # paged decode through the fused Pallas paged-attention kernel (page
     # table walked in-kernel, interpret mode on CPU): same trace, same
-    # tight budget — gated below to be token-identical to the gather
-    # paged_continuous cell, and recorded so the kernel path has a
-    # throughput baseline from day one
+    # tight budget — recorded so the kernel path has a throughput
+    # baseline; its float32 twin below is gated to be token-identical to
+    # the gather path
     e_kernel = _engine("paged", target=tight, kv_kernel="pallas")
     kreqs = _trace(n_requests, e_kernel, max_new=max_new)
     e_kernel.run(kreqs, policy="continuous", prefill_chunk=0)   # warm jits
@@ -394,6 +412,11 @@ def run_smoke(out_path: str = "BENCH_serving.json",
         "preemptions": kstats.preemptions,
         "mean_ttft_steps": round(kstats.mean_ttft_steps, 4),
     }
+    f32_arch = _register_f32(ARCH)
+    f32_stats = {k: _engine("paged", target=tight, kv_kernel=k,
+                            arch=f32_arch).run(kreqs, policy="continuous",
+                                               prefill_chunk=0)
+                 for k in ("gather", "pallas")}
     # draft-then-verify speculative decoding: the repetitive greedy trace
     # on the 4-token-vocab probe arch, same paged engine with spec off vs
     # spec_k=SPEC_K — gated below on bit-identical streams AND
@@ -585,7 +608,8 @@ def run_smoke(out_path: str = "BENCH_serving.json",
     print(f"paged {pc['tokens_per_s']} tok/s @ "
           f"{pc['hbm_bytes_per_admitted_token']} B/tok, peak "
           f"{pc['peak_active']} (fused kernel {pk['tokens_per_s']} tok/s, "
-          f"token-identical) | contiguous {cc['tokens_per_s']} tok/s @ "
+          f"token-identical in float32) | contiguous "
+          f"{cc['tokens_per_s']} tok/s @ "
           f"{cc['hbm_bytes_per_admitted_token']} B/tok, peak "
           f"{cc['peak_active']} | router x{FLEET} {rc['tokens_per_s']} "
           f"tok/s fleet, peak {rc['peak_in_flight']} "
@@ -624,11 +648,11 @@ def run_smoke(out_path: str = "BENCH_serving.json",
                 f"than blocking's {lb['mean_ttft_steps']} on the "
                 f"long-prompt trace")
         sp_tok = lambda stats: [r.tokens for r in stats.results]  # noqa: E731
-        if sp_tok(kstats) != sp_tok(paged_cont_stats):
+        if sp_tok(f32_stats["pallas"]) != sp_tok(f32_stats["gather"]):
             raise SystemExit(
-                "SMOKE FAIL: fused-kernel paged token streams differ from "
-                "the gather path on the same trace — the kernel must be "
-                "token-identical")
+                "SMOKE FAIL: float32 fused-kernel paged token streams "
+                "differ from the gather path on the same trace — in "
+                "float32 the kernel must be token-identical")
         if sp_tok(sp_hot) != sp_tok(sp_cold):
             raise SystemExit(
                 "SMOKE FAIL: prefix-cached token streams differ from the "

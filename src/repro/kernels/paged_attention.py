@@ -1,82 +1,52 @@
-"""Pallas TPU fused paged-attention decode kernel (page-table walk in-kernel).
+"""Pallas TPU fused paged-attention decode kernel: one pass over live pages.
 
-The paged serving decode path previously paid a full materialized gather
-every tick: K/V were read back *through* the page table into a
-(slots, max_pages*page_size, K, dh) tensor before attention — the same
-unfused-HBM-traffic failure mode the roofline quantified for prefill
-scores, now on the KV stream.  This kernel walks each slot's page-table
-row *inside* the kernel instead (the PagedAttention design, vLLM): the
-innermost grid dimension streams pages, each page's K/V block DMA'd
-straight from the (layers, num_pages, page_size, rows, lanes) pool via a
-scalar-prefetched page-table index map, with the softmax statistics
-carried across pages in VMEM scratch — the block/`pl.when` idiom of
-kernels/flash_attention.py with the kv grid dimension redirected through
-the page table.
+The paged serving decode reads each slot's K and V through its page table
+inside the kernel (the PagedAttention design, vLLM), so the gathered
+``(slots, max_pages*page_size, K, dh)`` tensor never hits HBM.
 
-The pool is every layer's, as the decode step's layer scan carries it:
-the layer id is a third scalar prefetch and the first coordinate of the
-K/V index map, so one layer's pages are read where they lie and the scan
-never slices (copies) a layer's pool out for the call.  A one-layer pool
-(num_pages, page_size, rows, lanes) is read as layer 0 of a one-layer
-stack.  A token's K (or V) is ``(rows, lanes)``: its kv heads ``(K,
-dh)``, or, where dh is under 128, ``128 // dh`` heads side by side in a
-128-lane row (``serving/pool.page_rows``) — the layout a TPU keeps such
-a pool in by default, dense, where a ``(K, 64)`` pool would be laid out
-page-minor and need a whole-pool relayout before every call.  A packed
-row's heads are told apart by lane (each head's dot product a masked
-lane sum); the passes and their rounding are those of one head to a row.
+The grid is one step per slot.  Each step walks the slot's live pages in
+blocks of ``ppb`` pages: every live page's K and V are copied from the
+pool in HBM (``memory_space=ANY``, read in place at the scalar-prefetched
+``layer``: no slice of the pool is materialised) to VMEM buffers by their
+own DMAs, double-buffered, so the next block's copies run while this
+block is computed, and a slot's last block starts the next slot's first.
+Each live page is copied once; a page past the slot's length, or behind a
+page-table entry of 0 (the reserved junk page: freed slots, rows past the
+held run), is never copied.  ``ppb`` follows from the page's size: about
+``2**19`` elements (1 MiB of bf16) of K per buffer, at most 16 pages and
+at most the table's width.  The buffers are zeroed once per call, so rows
+a block does not load hold finite stale values, which the mask drops.
 
-Parity contract: the serving engine promises token-identical streams with
-the kernel on or off, and the reference path (models/layers.dot_attention
-over the gathered KV) rounds its *normalized* probabilities to the
-activation dtype (bf16) before the PV contraction.  A single online
-pass cannot reproduce that per-element rounding (probabilities are only
-normalized at the very end), so the page walk runs in three phases over
-the same page stream — max, denominator, then PV with the same
-normalize-then-round sequence as the reference:
+One online-softmax pass carries ``(m, l, acc)`` in float32 across the
+blocks and normalises once at the end.  A token's K (or V) is ``(rows,
+lanes)``: its kv heads ``(K, dh)``, or ``lanes // dh`` heads side by side
+in a 128-lane row (``serving/pool.page_rows``), the layout a TPU keeps
+such a pool in.  A block's ``ppb * page_size`` tokens are a ``(N, lanes)``
+matrix of ``N = ppb * page_size * rows`` rows, and the queries are an
+``(M, lanes)`` matrix of ``M = G * (lanes // dh) * rows`` rows: query
+group g of the kv head in row r, lane slot j, in row ``(g, j, r)``, its
+dh lanes at slot j and zeros elsewhere.  So the scores of every head are
+one matrix product on the MXU, ``(M, N)``, in which row ``(g, j, r)``
+meets its own kv head at the columns of row r and the rest are masked;
+the weighted sum of V is a second product, ``(M, N) x (N, lanes)``, whose
+row ``(g, j, r)`` is kept at the lanes of slot j.
 
-    phase 0   m   = max_t s_t                    (exact; order-free)
-    phase 1   l   = sum_t exp(s_t - m)           (f32, page-sequential)
-    phase 2   acc = sum_t round_bf16(exp(s_t - m) / l) * v_t   (f32)
+Masking: a column is live where its token is below the slot's ``kv_len``
+and its page-table entry is not 0; ``p`` is zeroed under the mask
+explicitly (NEG_INF - NEG_INF = 0 would otherwise make exp() emit 1 per
+masked key), and a slot with no live page writes exact zeros.
 
-Scratch (m, l, acc) carries across the whole 3 * max_pages walk; pages a
-slot does not hold are skipped, so the pool is streamed at ~3x the
-slot's *held* bytes — still far below the gather's materialized
-worst-case (slots, max_pages*page_size, K, dh) read-plus-write on
-heavy-tailed traces (see benchmarks/kernel_bench.py).
+Numerics: the inputs stay in the pool's dtype and every sum is float32.
+The probabilities meet V unnormalised, rounded to V's dtype, as in
+``kernels/mla_attention.py``; the gather path (``models/layers.
+dot_attention``) rounds the normalised ones, so the two agree to the
+dtype's rounding, not bit for bit.  Validated in interpret mode against
+the gather oracle (``kernels/ref.paged_attention_ref``,
+tests/test_kernels_paged.py); compiled for a described v5e at
+deepseek-7b and stablelm-2-1.6b widths (tests/test_tpu_compile.py).
 
-Layout/masking contract (mirrors models/layers.py's paged decode arm):
-
-* the grid is (slots, 3 * max_pages); each step DMAs one whole page of
-  one layer, ``(page_size, rows, lanes)`` over ALL kv heads (block
-  ``(None, 1, page_size, rows, lanes)``: the layer dim squeezed).  The
-  TPU compiler requires a block's last two dims to be multiples of
-  (8, 128) or to equal the array's; a one-kv-head block
-  ``(1, page_size, 1, dh)`` puts a size-1 dim second from last and is
-  refused, while ``(rows, lanes)`` equals the pool's own trailing dims
-  at any width.  The heads are then processed
-  side by side on the vector unit: scores are a lane reduction of
-  ``k * q`` over dh, the PV product a sum over the page's tokens;
-* the query block holds one slot's heads as ``(G, rows, lanes)`` (G =
-  H // K query heads per kv head, transposed and packed outside the
-  kernel so each of the G groups is a slab lined up with the page's
-  heads);
-* token position ``ip * page_size + j`` is masked at each slot's own
-  ``kv_len`` (per-slot lengths — continuous batching);
-* page-table entries equal to 0 are the reserved junk page (freed /
-  never-grown rows; each layer has its own page 0): their blocks are
-  skipped entirely, so a freed
-  slot's output is exactly zero rather than an average of dead writes;
-* a fully-masked row cannot poison the accumulator: ``p`` is zeroed
-  under the mask explicitly (NEG_INF - NEG_INF = 0 would otherwise make
-  exp() emit 1 per masked key) and a slot with no live page never
-  divides by its zero denominator.
-
-Validated in interpret mode against the gather-then-attend oracle
-(kernels/ref.paged_attention_ref) over a page_size x pages-per-slot x
-GQA-ratio x per-slot-length sweep (tests/test_kernels_paged.py); compiled
-for a described v5e at deepseek-7b widths (tests/test_tpu_compile.py);
-checked compiled against the oracle on the chip by chip_smoke.py.
+The ``pallas_call`` is named ``paged_attention``; a profiler trace shows
+its ops under that name.
 """
 
 from __future__ import annotations
@@ -92,114 +62,144 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _paged_decode_kernel(pt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref,
-                         o_ref, acc_ref, m_ref, l_ref, *, scale: float,
-                         page_size: int, max_pages: int, groups: int,
-                         head_dim: int):
-    """One (slot, phase*page) grid step of the fused decode attention.
+def _split(x, d: int):
+    """(x // d, x % d) of a non-negative int32 array."""
+    if d & (d - 1) == 0:
+        return x >> (d.bit_length() - 1), x & (d - 1)
+    return jax.lax.div(x, d), jax.lax.rem(x, d)
 
-    ``pt_ref``/``len_ref``/``layer_ref`` are the scalar-prefetched
-    (slots, max_pages) page table, (slots,) kv lengths and (1,) layer —
-    prefetched so the k/v BlockSpec index maps can route each grid step's
-    DMA to ``(layer_ref[0], pt_ref[slot, page])`` before the body runs
-    (the body itself never reads the layer).  The innermost grid
-    dimension walks the page stream three times (max / denominator / PV
-    — see module docstring); VMEM scratch carries (m, l, acc) across the
-    whole walk (innermost is sequential on TPU).  A page row holds
-    ``r = lanes // head_dim`` kv heads side by side, head j of a row in
-    lanes ``[j * head_dim, (j + 1) * head_dim)`` (r = 1: a row is one
-    head).  Per query group g and row head j the state is ``m/l[g, j]``
-    of shape (rows, 1), and ``acc[g]`` is (rows, lanes); scores are
-    (page_size, rows, 1) — token-major, heads on sublanes, so every
-    reduction is either over lanes or over the leading token axis.
-    """
-    is_, it = pl.program_id(0), pl.program_id(1)
-    ip = it % max_pages
-    phase = it // max_pages
-    r = k_ref.shape[-1] // head_dim
 
-    @pl.when(it == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+def _paged_decode_kernel(len_ref, pt_ref, layer_ref, q_ref, k_hbm, v_hbm,
+                         o_ref, k_buf, v_buf, sem, state, *, scale: float,
+                         page_size: int, max_pages: int, ppb: int,
+                         groups: int, head_dim: int):
+    """One slot: its live pages, ``ppb`` at a time.
 
-    page = pt_ref[is_, ip]
-    kv_len = len_ref[is_]
+    ``len_ref``, ``pt_ref`` (the page table, flattened) and ``layer_ref``
+    are scalar-prefetched.  ``state`` (SMEM, int32) holds the buffer the
+    next block lands in (0 or 1) and whether this slot's first block was
+    started by the step before it."""
+    b = pl.program_id(0)
+    slots = pl.num_programs(0)
+    layer = layer_ref[0]
+    _, _, _, rows, lanes = k_buf.shape
+    bk = ppb * page_size
+    n_cols = bk * rows
 
-    # skip junk-page rows (page-table entry 0: freed slots, rows past the
-    # slot's held pages) and pages wholly beyond the slot's length — the
-    # whole block is masked, so there is nothing to accumulate
-    live = (page != 0) & (ip * page_size < kv_len)
+    def length(slot):
+        return jnp.minimum(len_ref[slot], max_pages * page_size)
 
-    def head_of_lane(shape):
-        return jax.lax.broadcasted_iota(jnp.int32, shape, 2) // head_dim
+    def page_of(slot, idx):
+        """The page-table entry of page ``idx`` of ``slot`` and whether it
+        is live (below the slot's length, not the junk page)."""
+        page = pt_ref[slot * max_pages + jnp.minimum(idx, max_pages - 1)]
+        n_pages = (length(slot) + page_size - 1) // page_size
+        return page, (idx < n_pages) & (page != 0)
 
-    def scores(g):
-        """[s_j for each row head j], mask — each (page_size, rows, 1)."""
-        q = q_ref[0, g].astype(jnp.float32)           # (rows, lanes)
-        k = k_ref[0].astype(jnp.float32)              # (page_size, rows, lanes)
-        qk = k * q[None]
-        if r == 1:
-            parts = [qk]
-        else:
-            head = head_of_lane(qk.shape)
-            parts = [jnp.where(head == j, qk, 0.0) for j in range(r)]
-        ss = [jnp.sum(x, axis=-1, keepdims=True) * scale for x in parts]
-        pos = ip * page_size + \
-            jax.lax.broadcasted_iota(jnp.int32, ss[0].shape, 0)
-        return ss, pos < kv_len
+    def copies(slot, blk, which):
+        out = []
+        for j in range(ppb):
+            page, live = page_of(slot, blk * ppb + j)
+            for kv, (src, dst) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+                out.append((live, pltpu.make_async_copy(
+                    src.at[layer, page], dst.at[which, j], sem.at[kv, which])))
+        return out
 
-    @pl.when(live & (phase == 0))
-    def _max_pass():
-        for g in range(groups):
-            ss, mask = scores(g)
-            for j, s in enumerate(ss):
-                s = jnp.where(mask, s, NEG_INF)
-                m_ref[g, j] = jnp.maximum(m_ref[g, j], jnp.max(s, axis=0))
+    def start(slot, blk, which):
+        for live, cp in copies(slot, blk, which):
+            pl.when(live)(cp.start)
 
-    @pl.when(live & (phase == 1))
-    def _sum_pass():
-        for g in range(groups):
-            ss, mask = scores(g)
-            for j, s in enumerate(ss):
-                # explicit zero under the mask: a row with no live key keeps
-                # m = NEG_INF, and exp(s - m) = exp(NEG_INF - NEG_INF) = 1
-                # for the masked entries (the flash-kernel poisoning bug,
-                # fixed there too)
-                p = jnp.where(mask, jnp.exp(s - m_ref[g, j][None]), 0.0)
-                l_ref[g, j] = l_ref[g, j] + jnp.sum(p, axis=0)
+    def wait(slot, blk, which):
+        for live, cp in copies(slot, blk, which):
+            pl.when(live)(cp.wait)
 
-    @pl.when(live & (phase == 2))
-    def _pv_pass():
-        v = v_ref[0]                                  # (page_size, rows, lanes)
-        for g in range(groups):
-            ss, mask = scores(g)
-            probs = None
-            for j, s in enumerate(ss):
-                p = jnp.where(mask, jnp.exp(s - m_ref[g, j][None]), 0.0)
-                # normalize THEN round to the value dtype — the reference
-                # path's probs.astype(v.dtype) before the PV contraction,
-                # reproduced per element so kernel-on streams are
-                # token-identical
-                p = (p / l_ref[g, j][None]).astype(v.dtype).astype(
-                    jnp.float32)
-                if r == 1:
-                    probs = p
-                else:
-                    # row head j's probabilities across its own lanes
-                    if probs is None:
-                        head = head_of_lane(v.shape)
-                        probs = jnp.zeros(v.shape, jnp.float32)
-                    probs = jnp.where(head == j, p, probs)
-            acc_ref[g] = acc_ref[g] + jnp.sum(
-                probs * v.astype(jnp.float32), axis=0)
+    @pl.when(b == 0)
+    def _first():
+        k_buf[...] = jnp.zeros_like(k_buf)
+        v_buf[...] = jnp.zeros_like(v_buf)
+        state[0] = 0
+        state[1] = 0
 
-    @pl.when(it == 3 * max_pages - 1)
-    def _finalize():
-        # acc is already normalized; a slot with no live page at all
-        # (freed / junk-only row) never entered the phases -> exact zero
-        o_ref[0] = acc_ref[...].astype(o_ref.dtype)
+    kv_len = length(b)
+    nb = (kv_len + bk - 1) // bk
+
+    @pl.when((nb > 0) & (state[1] == 0))
+    def _start_own():
+        start(b, 0, state[0])
+
+    q = q_ref[0]                                        # (M, lanes)
+    # query row (g, j, r) meets the kv head of its own row r
+    own = _split(jax.lax.broadcasted_iota(jnp.int32, (q.shape[0], n_cols), 0),
+                 rows)[1] == _split(jax.lax.broadcasted_iota(
+                     jnp.int32, (q.shape[0], n_cols), 1), rows)[1]
+    tok, _ = _split(jax.lax.broadcasted_iota(jnp.int32, (1, n_cols), 1), rows)
+    page_in_block, _ = _split(tok, page_size)
+
+    def attend(i, live_pages, carry):
+        m, l, acc = carry
+        which = state[0]
+        k = k_buf[which].reshape(n_cols, lanes)
+        v = v_buf[which].reshape(n_cols, lanes)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale   # (M, N)
+        col_live = (i * bk + tok < kv_len) & \
+            (((live_pages >> page_in_block) & 1) == 1)
+        mask = own & col_live
+        s = jnp.where(mask, s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m - m_new)
+        l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return m_new, l, alpha * acc + pv
+
+    def body(i, carry):
+        which = state[0]
+        wait(b, i, which)
+        nxt = 1 - which
+
+        @pl.when(i + 1 < nb)
+        def _next_block():
+            start(b, i + 1, nxt)
+
+        @pl.when((i + 1 == nb) & (b + 1 < slots))
+        def _next_slot():
+            start(jnp.minimum(b + 1, slots - 1), 0, nxt)
+
+        # bit j: page j of the block is live (copied this block)
+        live_pages = 0
+        for j in range(ppb):
+            live_pages = live_pages | jnp.where(
+                page_of(b, i * ppb + j)[1], 1 << j, 0)
+        carry = jax.lax.cond(live_pages != 0,
+                             functools.partial(attend, i, live_pages),
+                             lambda c: c, carry)
+        state[0] = nxt
+        return carry
+
+    heads = q.shape[0]
+    init = (jnp.full((heads, 1), NEG_INF, jnp.float32),
+            jnp.zeros((heads, 1), jnp.float32),
+            jnp.zeros((heads, lanes), jnp.float32))
+    _, l, acc = jax.lax.fori_loop(0, nb, body, init)
+    # a slot with no live row keeps l = 0 and acc = 0: its output is zero
+    o = acc / jnp.where(l > 0, l, 1.0)
+    slot_heads = lanes // head_dim
+    o = o.reshape(groups, slot_heads, rows, lanes)
+    if slot_heads == 1:
+        out = o[:, 0]
+    else:
+        lane_slot = jax.lax.broadcasted_iota(
+            jnp.int32, (groups, rows, lanes), 2) // head_dim
+        out = jnp.zeros((groups, rows, lanes), jnp.float32)
+        for j in range(slot_heads):
+            out = jnp.where(lane_slot == j, o[:, j], out)
+    o_ref[0] = out.astype(o_ref.dtype)
+    # the next slot's first block was started above when this one had any
+    state[1] = ((nb > 0) & (b + 1 < slots)).astype(jnp.int32)
 
 
 def paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
@@ -232,53 +232,54 @@ def paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
     slots, H, dh = q.shape
     _, _, page_size, rows, lanes = k_pages.shape
     assert lanes % dh == 0, (lanes, dh)
-    K = rows * (lanes // dh)
+    slot_heads = lanes // dh
+    K = rows * slot_heads
     assert H % K == 0, (H, K)
     G = H // K
     max_pages = page_table.shape[1]
     assert page_table.shape[0] == slots and kv_len.shape == (slots,), \
         (page_table.shape, kv_len.shape, slots)
-    scale = 1.0 / math.sqrt(dh)
-    # query head h = k * G + g  ->  (slots, G, K, dh): group-major, so
-    # q_ref[0, g] is a slab of the K heads laid out as the page's rows
-    qg = q.reshape(slots, K, G, dh).transpose(0, 2, 1, 3).reshape(
-        slots, G, rows, lanes)
-
-    def kv_map(is_, it, pt, kl, ly):
-        # the page walk: this slot's (it mod max_pages)-th page of layer
-        # ly, straight from the pool — revisited once per phase
-        return (ly[0], pt[is_, it % max_pages], 0, 0, 0)
-
-    def q_map(is_, it, pt, kl, ly):
-        return (is_, 0, 0, 0)
-
+    # about 2**19 elements of K per buffer (1 MiB of bf16), at most 16
+    # pages (a block's live pages are the bits of an int32)
+    ppb = max(1, min(max_pages, 16, 2**19 // (page_size * rows * lanes)))
+    heads = G * slot_heads * rows
+    # query head h = k * G + g of kv head k = r * slot_heads + j -> row
+    # (g, j, r) of the query matrix, its dh lanes at lane slot j
+    qg = q.reshape(slots, rows, slot_heads, G, dh).transpose(0, 3, 2, 1, 4)
+    eye = jnp.eye(slot_heads, dtype=q.dtype)
+    qm = (qg[:, :, :, :, None, :] * eye[None, None, :, None, :, None]
+          ).reshape(slots, heads, lanes)
+    kernel = functools.partial(
+        _paged_decode_kernel, scale=1.0 / math.sqrt(dh), page_size=page_size,
+        max_pages=max_pages, ppb=ppb, groups=G, head_dim=dh)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,        # page table + kv lengths + layer
-        grid=(slots, 3 * max_pages),
+        num_scalar_prefetch=3,        # kv lengths, page table, layer
+        grid=(slots,),
         in_specs=[
-            pl.BlockSpec((1, G, rows, lanes), q_map),
-            pl.BlockSpec((None, 1, page_size, rows, lanes), kv_map),
-            pl.BlockSpec((None, 1, page_size, rows, lanes), kv_map),
+            pl.BlockSpec((1, heads, lanes), lambda b, *_: (b, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, G, rows, lanes), q_map),
+        out_specs=pl.BlockSpec((1, G, rows, lanes),
+                               lambda b, *_: (b, 0, 0, 0)),
         scratch_shapes=[
-            # VMEM scratch carrying softmax state across the page walk
-            pltpu.VMEM((G, rows, lanes), jnp.float32),
-            pltpu.VMEM((G, lanes // dh, rows, 1), jnp.float32),
-            pltpu.VMEM((G, lanes // dh, rows, 1), jnp.float32),
+            pltpu.VMEM((2, ppb, page_size, rows, lanes), k_pages.dtype),
+            pltpu.VMEM((2, ppb, page_size, rows, lanes), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((2,), jnp.int32),
         ],
     )
-    kernel = functools.partial(_paged_decode_kernel, scale=scale,
-                               page_size=page_size, max_pages=max_pages,
-                               groups=G, head_dim=dh)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((slots, G, rows, lanes), q.dtype),
+        # slots run in order: each one's last block starts the next's first
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         # the op's name in a profiler trace, whatever wraps the call
         name="paged_attention",
-    )(page_table.astype(jnp.int32), kv_len.astype(jnp.int32),
-      jnp.reshape(layer, (1,)).astype(jnp.int32), qg, k_pages, v_pages)
+    )(kv_len.astype(jnp.int32), page_table.reshape(-1).astype(jnp.int32),
+      jnp.reshape(layer, (1,)).astype(jnp.int32), qm, k_pages, v_pages)
     return out.reshape(slots, G, K, dh).transpose(0, 2, 1, 3).reshape(
         slots, H, dh)

@@ -493,12 +493,12 @@ def main(argv=None):
                         "materialized (slots, max_pages*page_size, heads, "
                         "dim) tensor before attending; 'pallas' runs the "
                         "fused paged-attention kernel that walks the page "
-                        "table in-kernel (K/V stream page-by-page, online "
-                        "softmax in VMEM scratch) and never materializes "
+                        "table in-kernel (each live K/V page copied once, "
+                        "one online-softmax pass) and never materializes "
                         "the gather; 'auto' follows the tuner "
                         "(plan.serve_kv_kernel: pallas targets get the "
-                        "kernel).  Token streams are identical either "
-                        "way; requires --kv-layout paged")
+                        "kernel).  The two agree to the activation dtype's "
+                        "rounding; requires --kv-layout paged")
     p.add_argument("--replicas", type=int, default=1,
                    help="serve through a ReplicaRouter over N tuner-split "
                         "engines (1 = single engine)")

@@ -283,7 +283,8 @@ def build_verify_step_slots_paged(model, mesh=None):
     the same ok-guard, so a burst past a slot's page-run capacity can
     never scribble into a (possibly prefix-shared) live page.  The fused
     Pallas kernel is single-token-only, so verify always reads through
-    the gather path — token-identical to the kernel by the PR 6 sweep.
+    the gather path, which agrees with the kernel to the activation
+    dtype's rounding (tests/test_kernels_paged.py).
     ``index`` stays host-authoritative (see ``build_verify_step_slots``).
     """
     def verify_step(params, cache, tokens, active, pages):

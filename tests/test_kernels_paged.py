@@ -1,12 +1,16 @@
 """Fused paged-attention decode kernel: equivalence sweep vs the gather
 oracle and the contiguous slot-decode path, plus junk-page masking.
 
-The kernel's contract (kernels/paged_attention.py) is *token identity*
-with the gather-then-attend path, so the sweep crosses page size x
-pages-per-slot x GQA ratio x per-slot lengths — including freed slots
-whose page-table rows point at the reserved junk page 0 — and checks
-three-way agreement: paged-Pallas == gather oracle == contiguous
-slot-decode attention over the same KV.
+The kernel (kernels/paged_attention.py) is held to the gather-then-attend
+oracle within the dtype's tolerance (2e-2 in bf16, 2e-5 in float32): it
+rounds its unnormalised probabilities where the gather path rounds the
+normalised ones, so the two agree to rounding, not bit for bit.  The
+sweep crosses page size x pages-per-slot x GQA ratio x per-slot lengths
+— freed slots whose page-table rows point at the reserved junk page 0,
+lengths spanning several of the kernel's page blocks with a partial last
+page and block, pages past a slot's length that would poison any read —
+and checks three-way agreement: paged-Pallas == gather oracle ==
+contiguous slot-decode attention over the same KV.
 """
 
 import sys
@@ -64,6 +68,10 @@ CASES = [
     (4, 4, 1, 4, 32, [16, 3, 0, 9], jnp.float32),        # MQA + freed slot
     (16, 2, 4, 1, 16, [32, 31, 30, 5], jnp.bfloat16),    # MHA, bf16 pool
     (8, 8, 2, 4, 64, [64, 1, 40, 0, 23], jnp.bfloat16),
+    # 16-page blocks of 4 tokens: 150 tokens are blocks of 16, 16 and 6
+    # pages, the last page half full; a zero-length slot between live ones
+    (4, 40, 2, 2, 32, [150, 0, 97, 64], jnp.float32),
+    (4, 40, 4, 1, 16, [97, 150, 1, 0], jnp.bfloat16),
 ]
 
 
@@ -112,15 +120,18 @@ def test_paged_kernel_hypothesis_sweep(psize, mp, K, G, seed, lens):
     np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-5)
 
 
-def test_freed_slots_exact_zero_under_poisoned_junk():
+@pytest.mark.parametrize("psize,mp,lens", [(8, 4, [24, 13, 7]),
+                                           (4, 40, [150, 130, 97])],
+                         ids=["one-block", "blocks"])
+def test_freed_slots_exact_zero_under_poisoned_junk(psize, mp, lens):
     """A freed/preempted slot (zeroed page-table row, stale nonzero
     kv_len — exactly what the decode step's `safe_pages` produces for
     inactive rows) must output exactly 0: the junk page is skipped
-    in-kernel, never averaged in."""
-    lens = [24, 13, 7]
-    q, kp, vp, table, kv_len = make_case(3, lens, 8, 4, 2, 2, 32,
+    in-kernel, never averaged in — and the slot after it, whose first
+    block the freed slot's walk starts, is still read right."""
+    q, kp, vp, table, kv_len = make_case(3, lens, psize, mp, 2, 2, 32,
                                          jnp.float32, poison=1e6)
-    table = table.at[1].set(0)          # freed mid-flight; kv_len stays 13
+    table = table.at[1].set(0)          # freed mid-flight; kv_len stays
     out = np.asarray(paged_attention(q, kp, vp, table, kv_len))
     assert np.all(out[1] == 0.0), "freed slot read the junk page"
     # the other slots are untouched by the free
@@ -139,6 +150,32 @@ def test_junk_page_contents_never_leak_into_live_slots():
     out_clean = np.asarray(paged_attention(*clean[:3], clean[3], clean[4]))
     out_dirty = np.asarray(paged_attention(*dirty[:3], dirty[3], dirty[4]))
     np.testing.assert_array_equal(out_clean, out_dirty)
+
+
+@pytest.mark.parametrize("psize,mp,K,G,dh,lens", [
+    (8, 4, 2, 2, 32, [17, 9, 32]),
+    (4, 40, 2, 2, 32, [150, 3, 0, 65]),
+    (16, 24, 32, 1, 64, [300, 17]),             # stablelm's packed rows
+], ids=["one-block", "blocks", "packed"])
+def test_pages_past_the_length_are_never_read(psize, mp, K, G, dh, lens):
+    """Every page-table entry past a slot's length points at a page of
+    NaN: no such page is copied, so the output is finite, the oracle's on
+    the clean table, and bitwise what the clean table gives."""
+    q, kp, vp, clean, kv_len = make_case(13, lens, psize, mp, K, G, dh,
+                                         jnp.float32)
+    nan = kp.shape[0]
+    kp = jnp.concatenate([kp, jnp.full_like(kp[:1], jnp.nan)])
+    vp = jnp.concatenate([vp, jnp.full_like(vp[:1], jnp.nan)])
+    held = -(-np.asarray(kv_len) // psize)
+    past = np.arange(mp)[None, :] >= held[:, None]
+    poisoned = jnp.where(jnp.asarray(past), nan, clean)
+    kq, vq = _packed(kp), _packed(vp)
+    out = np.asarray(paged_attention(q, kq, vq, poisoned, kv_len))
+    assert np.isfinite(out).all()
+    want = np.asarray(ref.paged_attention_ref(q, kp, vp, clean, kv_len))
+    np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(
+        out, np.asarray(paged_attention(q, kq, vq, clean, kv_len)))
 
 
 def test_paged_kernel_rejects_bad_gqa():
@@ -198,6 +235,28 @@ def test_paged_kernel_packed_rows(K, G, dh, dtype):
     want = np.asarray(
         ref.paged_attention_ref(q, kp, vp, table, kv_len), np.float32)
     np.testing.assert_allclose(out, want, rtol=_tol(dtype), atol=_tol(dtype))
+    # the two layouts sum in different orders: equal to the output's
+    # rounding (float32: 1e-5; bfloat16: its epsilon)
+    tol = max(1e-5, float(jnp.finfo(dtype).eps))
     flat = np.asarray(paged_attention(q, kp, vp, table, kv_len), np.float32)
-    np.testing.assert_allclose(out, flat, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(out, flat, rtol=tol, atol=tol)
+    assert np.all(out[2] == 0.0)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_kernel_packed_rows_over_blocks(dtype):
+    """stablelm-2-1.6b's pool layout: 32 kv heads of 64 packed to 16 rows
+    of 128 lanes, pages of 16 tokens, so a block is 16 pages: a slot of
+    600 tokens spans three blocks (the last one partial, its last page
+    half full) beside a freed slot and short ones."""
+    K, dh = 32, 64
+    assert page_rows(K, dh) == (16, 128)
+    lens = [600, 33, 0, 257]
+    q, kp, vp, table, kv_len = make_case(21, lens, 16, 40, K, 1, dh, dtype,
+                                         poison=1e4)
+    out = np.asarray(paged_attention(q, _packed(kp), _packed(vp), table,
+                                     kv_len), np.float32)
+    want = np.asarray(
+        ref.paged_attention_ref(q, kp, vp, table, kv_len), np.float32)
+    np.testing.assert_allclose(out, want, rtol=_tol(dtype), atol=_tol(dtype))
     assert np.all(out[2] == 0.0)

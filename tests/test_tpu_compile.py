@@ -65,6 +65,29 @@ def test_paged_attention_compiles_at_real_widths(one_chip, K, G):
     assert "tpu_custom_call" in text
 
 
+def test_paged_attention_compiles_at_longctx_widths(one_chip):
+    """stablelm-2-1.6b's longctx cell: 6 slots x 256 pages of 16, 32 kv
+    heads of 64 packed to (16, 128) rows, the 24-layer pool read at a
+    traced layer, as the decode step's layer scan calls it."""
+    from repro.serving.pool import page_rows
+    slots, K, dh, page_size, max_pages, layers = 6, 32, 64, 16, 256, 24
+    rows, lanes = page_rows(K, dh)
+    assert (rows, lanes) == (16, 128)
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = S((layers, slots * max_pages + 1, page_size, rows, lanes),
+             jnp.bfloat16)
+    text = _compile_text(
+        lambda q, k, v, pt, kl, ly: paged_attention_pallas(
+            q, k, v, pt, kl, ly, interpret=False),
+        S((slots, K, dh), jnp.bfloat16), pool, pool,
+        S((slots, max_pages), jnp.int32), S((slots,), jnp.int32),
+        S((), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
 def test_rmsnorm_compiles_at_real_widths(one_chip):
     x = jax.ShapeDtypeStruct((512, 4096), jnp.bfloat16, sharding=one_chip)
     w = jax.ShapeDtypeStruct((4096,), jnp.bfloat16, sharding=one_chip)
