@@ -61,8 +61,6 @@ _OPENLOOP = {"arrival_gap", "arrival_seed", "arrivals",
 # exact key set.  Adding a bench cell means adding a row here — the
 # validator (and the CI step running it) fails otherwise.
 CELL_SCHEMA = {
-    "contiguous_static": _SINGLE,
-    "contiguous_continuous": _SINGLE,
     "paged_static": _SINGLE,
     "paged_continuous": _SINGLE,
     "paged_continuous_kernel": _SINGLE | {"kv_kernel"},

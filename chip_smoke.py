@@ -139,9 +139,8 @@ def serve_phase(target: str, monitor: CompileMonitor) -> None:
     num_pages = SLOTS * math.ceil(max_len / PAGE_SIZE) + 1
     t0 = time.perf_counter()
     engine = ServeEngine(arch=ARCH, target=target, num_slots=SLOTS,
-                         max_len=max_len, seed=SEED, kv_layout="paged",
-                         page_size=PAGE_SIZE, num_pages=num_pages,
-                         kv_kernel="auto", log=log)
+                         max_len=max_len, seed=SEED, page_size=PAGE_SIZE,
+                         num_pages=num_pages, kv_kernel="auto", log=log)
     jax.block_until_ready(engine.params)
     cfg = engine.cfg
     log(f"arch={ARCH} layers={cfg.num_layers} d_model={cfg.d_model} "

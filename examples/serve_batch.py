@@ -11,8 +11,7 @@ repetitive trace the accepted-tokens/verify-step figure printed below
 clears 1 and decode finishes in fewer steps:
 
     PYTHONPATH=src python examples/serve_batch.py \
-        --arch picolm-4-smoke --kv-layout paged \
-        --trace repetitive --decode 48 --spec-k 4
+        --arch picolm-4-smoke --trace repetitive --decode 48 --spec-k 4
 """
 
 import argparse
@@ -26,15 +25,14 @@ def main():
     p.add_argument("--batch", type=int, default=4)
     p.add_argument("--prefill", type=int, default=64)
     p.add_argument("--decode", type=int, default=16)
-    p.add_argument("--kv-layout", default="contiguous")
     p.add_argument("--trace", choices=TRACES, default="uniform")
     p.add_argument("--spec-k", default="0",
                    help="draft tokens per verify step (0=off, 'auto'=tuner)")
     a = p.parse_args()
     spec_k = None if a.spec_k == "auto" else int(a.spec_k)
     out = serve_main(arch=a.arch, batch=a.batch, prefill_len=a.prefill,
-                     decode_tokens=a.decode, kv_layout=a.kv_layout,
-                     trace=a.trace, spec_k=spec_k)
+                     decode_tokens=a.decode, trace=a.trace,
+                     spec_k=spec_k)
     msg = (f"\n{a.arch}: {out['decode_tok_per_s']:.1f} decode tok/s "
            f"(batch={a.batch}); first tokens of request 0: {out['sample']}")
     if out.get("spec_verify_steps"):

@@ -29,7 +29,6 @@ class DeploymentPlan:
     sequence_parallel: bool = False
     grad_compression: str = "none"        # none | ef_int8
     donate_state: bool = True
-    serve_slots: int = 0                  # KV-pool slots (serve mode; 0 = n/a)
     serve_max_len: int = 0                # per-slot KV capacity (serve mode)
     serve_page_size: int = 0              # paged KV: tokens per page
     serve_num_pages: int = 0              # paged KV: pool pages (incl. junk 0)
@@ -38,7 +37,7 @@ class DeploymentPlan:
     serve_prefix_cache_pages: int = 0     # paged KV: LRU pin cap for the
     #                                       shared-prefix cache (same pool)
     serve_kv_kernel: str = ""             # paged decode attn: gather | pallas
-    #                                       ("" = n/a / contiguous layout)
+    #                                       ("" = n/a)
     serve_spec_k: int = 0                 # speculative draft tokens per slot
     #                                       per verify step (0 = spec off)
     serve_slo_ttft_steps: int = 0         # TTFT deadline (virtual steps) the
@@ -71,10 +70,6 @@ class DeploymentPlan:
                  f"  kernels         : {self.kernels}",
                  f"  seq parallel    : {self.sequence_parallel}",
                  f"  grad compression: {self.grad_compression}"]
-        if self.serve_slots:
-            per = " per replica" if self.serve_replicas > 1 else ""
-            lines.append(f"  serve kv pool   : {self.serve_slots} slots "
-                         f"x {self.serve_max_len}{per}")
         if self.serve_num_pages:
             per = " per replica" if self.serve_replicas > 1 else ""
             lines.append(f"  serve kv pages  : {self.serve_num_pages} pages "

@@ -259,41 +259,24 @@ def tune(cfg: ModelConfig, shape: ShapeConfig, target: TargetSpec,
             plan.napkin["kv_cache_per_chip"] = f"{kv/chips/1e9:.3f} GB"
             # --- serve-mode KV pool sizing ---------------------------------
             # The continuous-batching engine asks for (slots x max_len);
-            # the requested batch is honoured only while params + pool fit
-            # the HBM budget, otherwise the pool is capped — the serving
-            # analogue of the training escalation ladder.  Both KV layouts
-            # are sized and recorded: the contiguous pool reserves
-            # worst-case (max_len) per admitted request, the paged pool
-            # turns the same budget into *pages* so capacity is measured
-            # in expected tokens instead of worst cases.
+            # the HBM budget left after the params buys a pool of *pages*,
+            # so capacity is measured in expected tokens instead of worst
+            # cases.  Pages beyond the requested batch's worst case are
+            # pointless, so the pool is capped there; capacity is then
+            # quoted against the *expected* request length (heavy-tailed
+            # traces use ~1/4 of max_len on average), not against max_len.
             #
             # With `shape.serve_replicas` > 1 the KV budget is split evenly
             # across N co-resident engines (params are shared weights, so
-            # only the pools divide), every slot/page count below is *per
+            # only the pools divide), every page count below is *per
             # replica*, and the napkin quotes the fleet-aggregate capacity
             # — the quantity the ReplicaRouter balances.
             replicas = max(int(getattr(shape, "serve_replicas", 1) or 1), 1)
             plan.serve_replicas = replicas
             budget = (0.85 * target.hbm_bytes - param_bytes / chips) / replicas
             replica_batch = max(math.ceil(shape.global_batch / replicas), 1)
-            per_slot = kv_per_token * shape.seq_len / chips
-            cap = max(int(budget // per_slot), 1) if per_slot > 0 else \
-                replica_batch
             plan.serve_max_len = shape.seq_len
-            plan.serve_slots = max(1, min(replica_batch, cap))
             per = " per replica" if replicas > 1 else ""
-            plan.napkin["serve_pool"] = (
-                f"{plan.serve_slots} slots x {shape.seq_len} "
-                f"({plan.serve_slots * per_slot / 1e9:.3f} GB/chip{per})")
-            if plan.serve_slots < replica_batch:
-                plan.notes.append(
-                    f"serve: requested {replica_batch} slots{per} exceed the "
-                    f"HBM budget -> pool capped at {plan.serve_slots}")
-            # paged layout: same budget buys a page pool.  Pages beyond the
-            # requested batch's worst case are pointless, so the pool is
-            # capped there; capacity is then quoted against the *expected*
-            # request length (heavy-tailed traces use ~1/4 of max_len on
-            # average), not against max_len.
             page_size = min(SERVE_PAGE_SIZE, shape.seq_len)
             page_bytes = kv_per_token * page_size / chips
             worst_pages = replica_batch * \
@@ -305,17 +288,11 @@ def tune(cfg: ModelConfig, shape: ShapeConfig, target: TargetSpec,
             expected_len = max(
                 int(shape.seq_len * SERVE_EXPECTED_LEN_FRACTION), 1)
             usable_tokens = (plan.serve_num_pages - 1) * page_size
-            paged_reqs = max(usable_tokens // expected_len, 1)
             plan.napkin["kv_pages"] = plan.serve_num_pages
             plan.napkin["page_size"] = page_size
             plan.napkin["serve_pool_paged"] = (
                 f"{plan.serve_num_pages} pages x {page_size} "
                 f"({plan.serve_num_pages * page_bytes / 1e9:.3f} GB/chip{per})")
-            delta = paged_reqs / max(plan.serve_slots, 1) - 1.0
-            plan.napkin["serve_capacity_delta"] = (
-                f"contiguous {plan.serve_slots} worst-case reqs vs paged "
-                f"~{paged_reqs} expected-len({expected_len}) reqs "
-                f"({delta:+.0%}){per}")
             # --- chunked-prefill grain + TTFT napkin -----------------------
             # Prompt ingestion interleaves with decode ticks; the chunk is
             # sized so one chunk's FLOPs fit inside one decode tick's
@@ -325,7 +302,7 @@ def tune(cfg: ModelConfig, shape: ShapeConfig, target: TargetSpec,
             # to a power of two so the chunk jit cache stays small.
             flops_tok = 2 * active_param_count(cfg)
             t_tick = max(param_bytes / chips / target.hbm_bw,
-                         plan.serve_slots * flops_tok / target.peak_flops)
+                         replica_batch * flops_tok / target.peak_flops)
             c_raw = t_tick * target.peak_flops / max(flops_tok, 1)
             chunk = 8
             while chunk * 2 <= min(c_raw, 128, shape.seq_len):
@@ -388,9 +365,9 @@ def tune(cfg: ModelConfig, shape: ShapeConfig, target: TargetSpec,
             plan.serve_kv_kernel = \
                 "pallas" if target.kernels == "pallas" else "gather"
             slot_cap = math.ceil(shape.seq_len / page_size) * page_size
-            gather_bytes = kv_per_token * plan.serve_slots * slot_cap / chips
+            gather_bytes = kv_per_token * replica_batch * slot_cap / chips
             fused_bytes_est = \
-                kv_per_token * plan.serve_slots * expected_len / chips
+                kv_per_token * replica_batch * expected_len / chips
             plan.napkin["serve_kv_kernel"] = (
                 f"{plan.serve_kv_kernel}: gather materializes "
                 f"{gather_bytes/1e9:.3f} GB/chip of K/V per decode tick "
@@ -429,9 +406,7 @@ def tune(cfg: ModelConfig, shape: ShapeConfig, target: TargetSpec,
             plan.napkin["serve_fleet_tokens"] = fleet_tokens
             plan.napkin["serve_fleet_capacity"] = (
                 f"{replicas} replica(s) x {usable_tokens} paged tokens = "
-                f"{fleet_tokens} tokens | {replicas} x {plan.serve_slots} "
-                f"contiguous slots = {replicas * plan.serve_slots} "
-                f"worst-case reqs")
+                f"{fleet_tokens} tokens")
 
     # --- long-context sequence parallelism ---
     if shape.kind != "train" and shape.seq_len >= 131072 and \

@@ -42,7 +42,6 @@ def run_command(command: str, job=None, workdir: Path | None = None,
             mode=kw.get("mode", "continuous"),
             requests=int(kw.get("requests", 0)),
             max_len=int(kw.get("max-len", 0)),
-            kv_layout=kw.get("kv-layout", "contiguous"),
             page_size=int(kw.get("page-size", 0)),
             temperature=float(kw.get("temperature", 0.0)),
             top_k=int(kw.get("top-k", 0)),

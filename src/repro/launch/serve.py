@@ -1,11 +1,9 @@
 """Serving driver (EASEY RUN command `serve ...`) — thin CLI over the
 continuous-batching ServeEngine (repro/serving/).
 
-Dense/MoE families go through the engine: a KV-cache pool sized by the
-tuner's serve-mode branch (``--kv-layout contiguous`` reserves
-slots x max_len worst cases; ``--kv-layout paged`` buys a page pool with
-the same budget and admits by actual tokens), slot-wise decode with
-per-request sampling (``--temperature`` / ``--top-k``), and a scheduler
+Dense/MoE families go through the engine: a KV page pool sized by the
+tuner's serve-mode branch (it admits by actual tokens), slot-wise decode
+with per-request sampling (``--temperature`` / ``--top-k``), and a scheduler
 that refills freed slots between steps.  Families without a
 slot-indexable attention cache (SSM, hybrid, enc-dec, VLM) keep the
 legacy fixed-batch path so `serve --arch xlstm-1.3b-smoke` still works.
@@ -93,8 +91,8 @@ def serve_main(arch: str = "deepseek-7b-smoke", batch: int = 4,
                prefill_len: int = 64, decode_tokens: int = 16,
                target: str | None = None, seed: int = 0,
                mode: str = "continuous", requests: int = 0,
-               max_len: int = 0, kv_layout: str = "contiguous",
-               page_size: int = 0, temperature: float = 0.0,
+               max_len: int = 0, page_size: int = 0,
+               temperature: float = 0.0,
                top_k: int = 0, top_p: float = 1.0, replicas: int = 1,
                route_policy: str = "least_loaded",
                prefill_chunk: int | None = None,
@@ -110,12 +108,12 @@ def serve_main(arch: str = "deepseek-7b-smoke", batch: int = 4,
     """Serve `requests` requests (default: one per slot) of `prefill_len`
     prompts, `decode_tokens` generations each.  Reports per-request latency
     and aggregate tokens/sec.  With ``replicas`` > 1 the requests flow
-    through a ``ReplicaRouter`` over N tuner-split engines (``kv_layout``
-    may be comma-separated to mix layouts; ``route_policy`` picks the
-    balancing rule).  ``prefill_chunk`` sets the prompt-ingestion grain
-    (None: the tuner's ``plan.serve_prefill_chunk``; 0: blocking
-    full-prompt prefill at admission).  ``prefix_cache`` (paged layout
-    only) reuses cached shared-prefix page runs by pointer copy, so
+    through a ``ReplicaRouter`` over N tuner-split engines
+    (``route_policy`` picks the balancing rule).  ``prefill_chunk`` sets
+    the prompt-ingestion grain (None: the tuner's
+    ``plan.serve_prefill_chunk``; 0: blocking full-prompt prefill at
+    admission).  ``prefix_cache`` reuses cached shared-prefix page runs
+    by pointer copy, so
     repeat prefixes skip their re-prefill entirely; pair it with
     ``trace='sharedprefix'`` (Zipf-clustered prompt heads) to see hits —
     the default uniform trace draws unrelated prompts.  ``kv_kernel``
@@ -192,7 +190,7 @@ def serve_main(arch: str = "deepseek-7b-smoke", batch: int = 4,
             arch=arch, batch=batch, prefill_len=prefill_len,
             decode_tokens=decode_tokens, target=target, seed=seed,
             mode=mode, requests=requests, pool_len=pool_len,
-            kv_layout=kv_layout, page_size=page_size,
+            page_size=page_size,
             temperature=temperature, top_k=top_k, top_p=top_p,
             replicas=replicas,
             route_policy=route_policy, prefill_chunk=prefill_chunk,
@@ -203,7 +201,7 @@ def serve_main(arch: str = "deepseek-7b-smoke", batch: int = 4,
             trace_out=trace_out, metrics_out=metrics_out,
             prom_out=prom_out, profile_dir=profile_dir, log=log)
     engine = ServeEngine(arch=arch, target=target, num_slots=batch,
-                         max_len=pool_len, seed=seed, kv_layout=kv_layout,
+                         max_len=pool_len, seed=seed,
                          page_size=page_size, prefill_chunk=prefill_chunk,
                          prefix_cache=prefix_cache, kv_kernel=kv_kernel,
                          spec_k=spec_k, repetitiveness=repetitiveness,
@@ -228,7 +226,6 @@ def serve_main(arch: str = "deepseek-7b-smoke", batch: int = 4,
     out = {
         "arch": arch, "batch": engine.num_slots, "prefill_len": prefill_len,
         "decode_tokens": decode_tokens, "mode": mode,
-        "kv_layout": kv_layout,
         "kv_kernel": engine.kv_kernel,
         "requests": len(stats.results),
         "decode_steps": stats.decode_steps,
@@ -264,7 +261,7 @@ def serve_main(arch: str = "deepseek-7b-smoke", batch: int = 4,
         "sample": stats.results[0].tokens[:8],
         "plan": engine.plan,
     }
-    log(f"[serve] {kv_layout}:{mode}: {out['decode_tok_per_s']:.1f} tok/s "
+    log(f"[serve] {mode}: {out['decode_tok_per_s']:.1f} tok/s "
         f"aggregate, occupancy {stats.occupancy:.0%}, "
         f"peak {stats.peak_active} in flight")
     _write_telemetry(out["metrics"], tracer, trace_out, metrics_out,
@@ -273,7 +270,7 @@ def serve_main(arch: str = "deepseek-7b-smoke", batch: int = 4,
 
 
 def _router_serve_main(arch, batch, prefill_len, decode_tokens, target,
-                       seed, mode, requests, pool_len, kv_layout, page_size,
+                       seed, mode, requests, pool_len, page_size,
                        temperature, top_k, top_p, replicas, route_policy,
                        prefill_chunk=None, prefix_cache=False,
                        kv_kernel="auto", spec_k=0, repetitiveness=0.0,
@@ -285,8 +282,8 @@ def _router_serve_main(arch, batch, prefill_len, decode_tokens, target,
     from repro.serving import AutoscalePolicy, ReplicaRouter, with_arrivals
     cfg = get_config(arch)
     router = ReplicaRouter.build(
-        arch=arch, target=target, replicas=replicas, kv_layout=kv_layout,
-        num_slots=batch, max_len=pool_len, seed=seed, policy=route_policy,
+        arch=arch, target=target, replicas=replicas, num_slots=batch,
+        max_len=pool_len, seed=seed, policy=route_policy,
         page_size=page_size, prefill_chunk=prefill_chunk,
         prefix_cache=prefix_cache, kv_kernel=kv_kernel,
         spec_k=spec_k, repetitiveness=repetitiveness, log=log)
@@ -318,7 +315,7 @@ def _router_serve_main(arch, batch, prefill_len, decode_tokens, target,
     out = {
         "arch": arch, "batch": batch, "prefill_len": prefill_len,
         "decode_tokens": decode_tokens, "mode": mode,
-        "kv_layout": kv_layout, "replicas": replicas,
+        "replicas": replicas,
         "route_policy": route_policy,
         "requests": len(stats.results),
         "reroutes": stats.reroutes,
@@ -349,7 +346,7 @@ def _router_serve_main(arch, batch, prefill_len, decode_tokens, target,
         "sample": stats.results[0].tokens[:8] if stats.results else [],
         "plan": router.engines[0].plan,
     }
-    log(f"[serve] {replicas}x{kv_layout}:{route_policy}:{mode}: "
+    log(f"[serve] {replicas}x{route_policy}:{mode}: "
         f"{out['decode_tok_per_s']:.1f} tok/s fleet, peak "
         f"{stats.peak_in_flight} in flight, imbalance "
         f"{stats.imbalance:.2f}")
@@ -480,12 +477,8 @@ def main(argv=None):
                    help="number of requests (default: one per slot)")
     p.add_argument("--max-len", type=int, default=0,
                    help="per-slot KV capacity (default: prefill+decode)")
-    p.add_argument("--kv-layout", default="contiguous",
-                   help="KV memory layout: contiguous | paged; with "
-                        "--replicas a comma-separated mix cycles over "
-                        "replicas (e.g. paged,contiguous)")
     p.add_argument("--page-size", type=int, default=0,
-                   help="tokens per KV page (paged; default: tuner's)")
+                   help="tokens per KV page (default: tuner's)")
     p.add_argument("--kv-kernel", choices=("auto", "gather", "pallas"),
                    default="auto",
                    help="paged decode attention implementation: 'gather' "
@@ -498,7 +491,7 @@ def main(argv=None):
                         "the gather; 'auto' follows the tuner "
                         "(plan.serve_kv_kernel: pallas targets get the "
                         "kernel).  The two agree to the activation dtype's "
-                        "rounding; requires --kv-layout paged")
+                        "rounding")
     p.add_argument("--replicas", type=int, default=1,
                    help="serve through a ReplicaRouter over N tuner-split "
                         "engines (1 = single engine)")
@@ -529,8 +522,8 @@ def main(argv=None):
                         "in one burst — token streams are bit-identical "
                         "to --spec-k 0")
     p.add_argument("--prefix-cache", action="store_true",
-                   help="reuse shared-prefix KV across requests (paged "
-                        "layout only): a per-replica cache maps page-"
+                   help="reuse shared-prefix KV across requests: a "
+                        "per-replica cache maps page-"
                         "aligned prompt prefixes to refcounted page runs, "
                         "so a repeat prefix is admitted by page-table "
                         "pointer copies — no chunk steps, no KV writes — "
@@ -619,10 +612,9 @@ def main(argv=None):
     spec_k = None if a.spec_k == "auto" else int(a.spec_k)
     serve_main(arch=a.arch, batch=a.batch, prefill_len=a.prefill,
                decode_tokens=a.decode, mode=a.mode, requests=a.requests,
-               max_len=a.max_len, kv_layout=a.kv_layout,
-               page_size=a.page_size, temperature=a.temperature,
-               top_k=a.top_k, top_p=a.top_p, replicas=a.replicas,
-               route_policy=a.route_policy,
+               max_len=a.max_len, page_size=a.page_size,
+               temperature=a.temperature, top_k=a.top_k, top_p=a.top_p,
+               replicas=a.replicas, route_policy=a.route_policy,
                prefill_chunk=None if a.prefill_chunk < 0
                else a.prefill_chunk,
                prefix_cache=a.prefix_cache, kv_kernel=a.kv_kernel,

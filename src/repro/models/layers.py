@@ -322,7 +322,8 @@ def attention(p: dict, x: jax.Array, cfg, mesh, *, positions: jax.Array,
     new_cache = None
     if mode == "decode":
         assert cache is not None and not cross
-        idx = cache["index"]  # int32 tokens seen so far: scalar, or (b,)
+        # int32 tokens seen so far: (b,) per slot with pages, else scalar
+        idx = cache["index"]
         # the layer scan passes the WHOLE cache (a leading layers dim) and
         # this layer's id: every write lands in place on that buffer and
         # every read indexes its layer, so no layer's cache is sliced out
@@ -331,19 +332,19 @@ def attention(p: dict, x: jax.Array, cfg, mesh, *, positions: jax.Array,
         lead = () if layer is None else (layer,)
         t = cache["k"].shape[-3]
         if "pages" in cache:
-            # PAGED slot-wise decode (continuous batching over a paged KV
-            # pool): this layer's cache is a page pool (num_pages,
-            # page_size, rows, lanes) — a token's (K, dh) as
-            # serving/pool.page_rows packs it — and `pages` is the
-            # (slots, max_pages) int32 page table.  The new kv is
-            # scattered to each row's own
+            # SLOT-WISE decode (continuous batching over the serving KV
+            # pool): every row is a pool slot at its own length `idx`,
+            # this layer's cache is a page pool (num_pages, page_size,
+            # rows, lanes) — a token's (K, dh) as serving/pool.page_rows
+            # packs it — and `pages` is the (slots, max_pages) int32 page
+            # table.  The new kv is scattered to each row's own
             # page/offset; K/V are then read back *through the page table*
-            # (one gather per row) so attention sees the same
-            # (slots, max_pages*page_size, K, dh) layout the contiguous
-            # path uses — identical masks, identical softmax, identical
-            # tokens.  Rows with a zeroed page-table entry (freed /
-            # never-allocated slots) write into the reserved junk page 0
-            # (of this layer), which no live table references.
+            # (one gather per row) as a (slots, max_pages*page_size, K, dh)
+            # tensor under a per-row causal-with-length mask.  The window
+            # is not applied: pool slots are already bounded by max_len.
+            # Rows with a zeroed page-table entry (freed / never-allocated
+            # slots) write into the reserved junk page 0 (of this layer),
+            # which no live table references.
             pages = cache["pages"]
             n_pages, psize = cache["k"].shape[-4:-2]
             max_pages = pages.shape[1]
@@ -378,36 +379,20 @@ def attention(p: dict, x: jax.Array, cfg, mesh, *, positions: jax.Array,
                     out = dot_attention(q, kg, vg, causal=True,
                                         q_offset=idx, kv_len=idx + s)
         else:
-            # RING BUFFER (a scalar index and a window no longer than the
-            # cache): the cache holds only the last `t` positions.  Keys
-            # carry absolute RoPE phases from write time, so order in the
-            # buffer is irrelevant; everything valid is attendable.
-            ring = jnp.ndim(idx) == 0 and window is not None and t <= window
+            # DECODE at one scalar index for the whole batch
+            # (build_decode_step).  As a RING BUFFER (a window no longer
+            # than the cache) the cache holds only the last `t` positions:
+            # keys carry absolute RoPE phases from write time, so order in
+            # the buffer is irrelevant; everything valid is attendable.
+            ring = window is not None and t <= window
             with jax.named_scope("kv_write"):
-                if jnp.ndim(idx) == 1:
-                    # SLOT-WISE decode (continuous batching): every row is
-                    # a pool slot at its own length.  The new kv lands at
-                    # each row's own position(s), and the mask is per-row
-                    # causal-with-length.  Window is not applied: pool
-                    # slots are already bounded by max_len.  A VERIFY
-                    # burst (s > 1) writes s speculative positions per
-                    # row; positions past max_len drop (the host caps
-                    # acceptance at the slot's backed capacity, so dropped
-                    # writes are never attended)
-                    rows = jnp.arange(q.shape[0])[:, None]      # (slots, 1)
-                    pos = idx[:, None] + jnp.arange(s)[None, :]  # (slots, s)
-                    k_all = cache["k"].at[lead + (rows, pos)].set(
-                        k, mode="drop")
-                    v_all = cache["v"].at[lead + (rows, pos)].set(
-                        v, mode="drop")
-                else:
-                    write = jnp.mod(idx, t) if ring else idx
-                    at = lead + (0, write, 0, 0)
-                    one = (1,) * len(lead)
-                    k_all = jax.lax.dynamic_update_slice(
-                        cache["k"], k.reshape(one + k.shape), at)
-                    v_all = jax.lax.dynamic_update_slice(
-                        cache["v"], v.reshape(one + v.shape), at)
+                write = jnp.mod(idx, t) if ring else idx
+                at = lead + (0, write, 0, 0)
+                one = (1,) * len(lead)
+                k_all = jax.lax.dynamic_update_slice(
+                    cache["k"], k.reshape(one + k.shape), at)
+                v_all = jax.lax.dynamic_update_slice(
+                    cache["v"], v.reshape(one + v.shape), at)
             with jax.named_scope("attn"):
                 k_l, v_l = _layer_of(k_all, layer), _layer_of(v_all, layer)
                 if ring:
@@ -421,22 +406,20 @@ def attention(p: dict, x: jax.Array, cfg, mesh, *, positions: jax.Array,
         # CHUNKED PREFILL written straight into the serving pool: x is one
         # bucketed chunk (batch 1, s tokens at global positions
         # [offset, offset+s)) of a single request's prompt, and the cache
-        # is the pool's own storage — contiguous (num_slots, max_len, K,
-        # dh) or paged (num_pages, page_size, rows, lanes) plus the slot's
-        # (max_pages,) page-table row; with "layer", every layer's
-        # storage, written and read at that layer in place (as decode).
-        # The chunk's K/V scatter to their final resting positions (no
-        # intermediate contiguous (1, s) cache to re-scatter later), then
-        # the slot's whole KV is read back so the chunk attends causally
+        # is the pool's own storage — a page pool (num_pages, page_size,
+        # rows, lanes) plus the slot's (max_pages,) page-table row; with
+        # "layer", every layer's storage, written and read at that layer
+        # in place (as decode).  The chunk's K/V scatter to their final
+        # pages (no intermediate (1, s) cache to re-scatter later), then
+        # the slot's pages are read back so the chunk attends causally
         # over every prior chunk through the same indirection decode uses.
         # Bucket padding rows (query j >= the true chunk length) write
         # junk only at positions later chunks / decode overwrite before
-        # any mask admits them; out-of-range rows drop (contiguous) or
-        # land in the reserved junk page 0 (paged).
+        # any mask admits them; out-of-range rows land in the reserved
+        # junk page 0.
         assert cache is not None and not cross
-        slot, off = cache["slot"], cache["offset"]
+        off = cache["offset"]
         layer = cache.get("layer")
-        lead = () if layer is None else (layer,)
         # kv_bound (a STATIC python int >= offset + s) caps the read-back:
         # a 4-token prompt in a max_len=128 pool attends 4-16 positions,
         # not 128.  Bounds are bucketed to powers of two host-side so the
@@ -444,35 +427,19 @@ def attention(p: dict, x: jax.Array, cfg, mesh, *, positions: jax.Array,
         bound = cache["kv_bound"]
         pos = off + jnp.arange(s)                   # (s,) global positions
         Kh, dh = k.shape[2], k.shape[3]
-        paged = "pages_row" in cache
+        pages_row = cache["pages_row"]              # (max_pages,) int32
+        n_pages, psize = cache["k"].shape[-4:-2]
+        max_pages = pages_row.shape[0]
         with jax.named_scope("kv_write"):
-            if paged:
-                pages_row = cache["pages_row"]      # (max_pages,) int32
-                n_pages, psize = cache["k"].shape[-4:-2]
-                max_pages = pages_row.shape[0]
-                fpos = _in_layer(paged_rows(pages_row, pos, psize), layer,
-                                 n_pages * psize)
-                k_all = _set_rows(cache["k"], fpos, k[0])
-                v_all = _set_rows(cache["v"], fpos, v[0])
-            else:
-                k_all = cache["k"].at[lead + (slot, pos)].set(
-                    k[0], mode="drop")
-                v_all = cache["v"].at[lead + (slot, pos)].set(
-                    v[0], mode="drop")
+            fpos = _in_layer(paged_rows(pages_row, pos, psize), layer,
+                             n_pages * psize)
+            k_all = _set_rows(cache["k"], fpos, k[0])
+            v_all = _set_rows(cache["v"], fpos, v[0])
         with jax.named_scope("attn"):
-            if paged:
-                B = min(-(-bound // psize), max_pages)
-                ids = _in_layer(pages_row[:B], layer, n_pages)
-                kg = _take_pages(k_all, ids).reshape(1, B * psize, Kh, dh)
-                vg = _take_pages(v_all, ids).reshape(1, B * psize, Kh, dh)
-            else:
-                L = min(bound, k_all.shape[-3])
-                at = lead + (slot, 0, 0, 0)
-                size = (1,) * len(lead) + (1, L, Kh, dh)
-                kg = jax.lax.dynamic_slice(k_all, at, size).reshape(
-                    1, L, Kh, dh)
-                vg = jax.lax.dynamic_slice(v_all, at, size).reshape(
-                    1, L, Kh, dh)
+            B = min(-(-bound // psize), max_pages)
+            ids = _in_layer(pages_row[:B], layer, n_pages)
+            kg = _take_pages(k_all, ids).reshape(1, B * psize, Kh, dh)
+            vg = _take_pages(v_all, ids).reshape(1, B * psize, Kh, dh)
             out = dot_attention(q, kg, vg, causal=True, q_offset=off,
                                 kv_len=off + s)
         new_cache = {"k": k_all, "v": v_all}

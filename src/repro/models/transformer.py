@@ -199,11 +199,11 @@ class DenseLM(BaseLM):
         if mode in ("decode", "chunk"):
             # the rest of the cache is layer-invariant and closes over the
             # scan body: decode's index (a scalar, or a per-slot vector
-            # under continuous batching) and the paged layout's (slots,
-            # max_pages) page table; the chunk's slot, offset, valid
-            # length and page table row; the STATIC kv_bound and
-            # use_kernel (the fused Pallas paged-decode kernel), never
-            # part of the jit pytree
+            # under continuous batching) and the serving pool's (slots,
+            # max_pages) page table; the chunk's offset, valid length and
+            # page table row; the STATIC kv_bound and use_kernel (the
+            # fused Pallas paged-decode kernel), never part of the jit
+            # pytree
             stores = {n: c for n, c in cache.items() if n in CARRIED}
             rest = {n: c for n, c in cache.items() if n not in CARRIED}
             x, stores = self.scan_cache(params, x, positions, mesh, mode,
@@ -270,15 +270,15 @@ class DenseLM(BaseLM):
         return logits, cache
 
     def chunk_prefill(self, params, cache, tokens, slot, offset, n_valid,
-                      mesh, kv_bound, pages_row=None):
+                      mesh, kv_bound, pages_row):
         """One prompt chunk of one request, written straight into a KV pool.
 
         tokens: (1, c) — a bucketed chunk padded past ``n_valid``; global
-        positions are ``[offset, offset + c)``.  ``cache`` is the pool's
-        cache tree (contiguous slot layout or page pool; ``pages_row`` is
-        the slot's page-table row for the latter).  Returns the logits at
-        the chunk's last *valid* position — the next-token logits once the
-        final chunk lands — and the updated pool cache with the slot's
+        positions are ``[offset, offset + c)``.  ``cache`` is the page
+        pool's cache tree and ``pages_row`` the slot's page-table row, the
+        pages the chunk writes through and reads back.  Returns the logits
+        at the chunk's last *valid* position — the next-token logits once
+        the final chunk lands — and the updated pool cache with the slot's
         index advanced to ``offset + n_valid``.  ``kv_bound`` is a STATIC
         upper bound (>= offset + c, power-of-two bucketed) on the KV
         prefix the chunk reads back, so short prompts do not pay
@@ -292,10 +292,8 @@ class DenseLM(BaseLM):
             jnp.arange(c, dtype=jnp.int32), (b, c))
         x = self.embed_inputs(params, {"tokens": tokens}, mesh, positions)
         chunk_cache = {n: c for n, c in cache.items() if n in CARRIED}
-        chunk_cache.update(slot=slot, offset=offset, n_valid=n_valid,
-                           kv_bound=int(kv_bound))
-        if pages_row is not None:
-            chunk_cache["pages_row"] = pages_row
+        chunk_cache.update(offset=offset, n_valid=n_valid,
+                           kv_bound=int(kv_bound), pages_row=pages_row)
         with jax.named_scope("layers"):
             x, stores = self.backbone(params, x, positions, mesh, "chunk",
                                       cache=chunk_cache)

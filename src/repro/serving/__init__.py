@@ -1,11 +1,11 @@
 """Continuous-batching serving subsystem.
 
-Layered as: KV pool (contiguous ``KVCachePool`` or page-table
-``PagedKVCachePool`` memory layouts, with refcounted pages) +
-``PrefixCache`` (shared-prefix KV page-run reuse over a paged pool) +
-``Scheduler`` (admission, in-flight batching, page-pressure preemption,
-per-request sampling, draft-then-verify speculative decoding) +
-``ServeEngine`` facade (tuner-sized pools, jitted steps, ``kv_layout``
+Layered as: KV pool (the page-table ``PagedKVCachePool``, with
+refcounted pages) + ``PrefixCache`` (shared-prefix KV page-run reuse
+over the pool) + ``Scheduler`` (admission, in-flight batching,
+page-pressure preemption, per-request sampling, draft-then-verify
+speculative decoding) +
+``ServeEngine`` facade (tuner-sized pools, jitted steps, ``kv_kernel``
 selection, ``spec_k``) + ``ReplicaRouter`` (N engines behind one
 admission queue with pluggable routing policies, overflow re-routing,
 open-loop arrival release, SLO-aware admission, and ``AutoscalePolicy``
@@ -14,8 +14,8 @@ ring events, the ``MetricsRegistry`` schema both ``to_metrics`` views
 are built on, and the Prometheus / Chrome-trace exporters).
 """
 
-from repro.serving.engine import KV_LAYOUTS, SERVABLE_FAMILIES, ServeEngine
-from repro.serving.pool import KVCachePool, PagedKVCachePool, PoolExhausted
+from repro.serving.engine import SERVABLE_FAMILIES, ServeEngine
+from repro.serving.pool import PagedKVCachePool, PoolExhausted
 from repro.serving.prefill import PrefillManager
 from repro.serving.prefix_cache import PrefixCache, prefix_key
 from repro.serving.router import (ADMISSION_MODES, ROUTE_POLICIES,
@@ -39,8 +39,8 @@ from repro.serving.trace import (ARRIVAL_MODES, bursty_arrivals,
                                  trace_repetitiveness, uniform_trace,
                                  with_arrivals, zipf_trace)
 
-__all__ = ["ServeEngine", "SERVABLE_FAMILIES", "KV_LAYOUTS", "KVCachePool",
-           "PagedKVCachePool", "PoolExhausted", "PrefillManager",
+__all__ = ["ServeEngine", "SERVABLE_FAMILIES", "PagedKVCachePool",
+           "PoolExhausted", "PrefillManager",
            "PrefixCache", "prefix_key", "ReplicaRouter", "RouterStats",
            "ROUTE_POLICIES", "ADMISSION_MODES", "AutoscalePolicy",
            "AutoscaleEvent", "RejectedRequest", "prefix_replica",

@@ -3,40 +3,34 @@
 Glues the existing layers together the same way the training driver does:
 
     AppSpec(arch, decode shape) + TargetSpec --BuildService--> DeploymentPlan
-        (the tuner's serve-mode branch sizes BOTH KV layouts from the HBM
-         budget: a contiguous slots x max_len pool and a paged
-         num_pages x page_size pool, and records them in the plan/napkin)
-    model_for(cfg) + build_prefill_step +
-        build_decode_step_slots / build_decode_step_slots_paged
+        (the tuner's serve-mode branch sizes the KV page pool from the HBM
+         budget: num_pages x page_size, and records it in the plan/napkin)
+    model_for(cfg) + build_prefill_step + build_decode_step_slots
         --> jitted steps (decode donates the pool cache)
-    KVCachePool | PagedKVCachePool + Scheduler
+    PagedKVCachePool + Scheduler
         --> continuous or gang-scheduled batching
 
-``kv_layout`` selects the memory layer:
-
-* ``"contiguous"`` — every slot pins max_len positions of HBM; the slot
-  count is the tuner's worst-case cap (``plan.serve_slots``).
-* ``"paged"`` — slots hold page lists over a budget-sized page pool
-  (``plan.serve_num_pages`` x ``plan.serve_page_size``); concurrency is
-  bounded by actual tokens, so heavy-tailed traces admit far more
-  requests in the same budget (at the cost of page-pressure preemptions
-  when the tail bites).
+The memory layer is a page pool (``plan.serve_num_pages`` x
+``plan.serve_page_size``) with per-slot page tables: slots hold page
+lists, so concurrency is bounded by the tokens requests actually hold,
+not by worst cases (at the cost of page-pressure preemptions when the
+tail bites).
 
 Prompt ingestion runs through the chunk-prefill step
-(``build_prefill_chunk_step[_paged]``), which scatters each chunk's KV
-straight into pool slots/pages — no intermediate contiguous ``(1, s)``
-cache.  ``prefill_chunk`` picks the grain: the tuner's
+(``build_prefill_chunk_step``), which scatters each chunk's KV straight
+into the slot's pages — no intermediate ``(1, s)`` cache.
+``prefill_chunk`` picks the grain: the tuner's
 ``plan.serve_prefill_chunk`` by default (chunks interleave with decode
 ticks inside ``Scheduler.step``), or 0 for blocking full-prompt prefill
 at admission (the old cadence, kept as the TTFT baseline — both modes
 are token-identical by construction).
 
-``prefix_cache=True`` (paged layout only) attaches a shared-prefix KV
-cache (``serving/prefix_cache.PrefixCache``) to every pool this engine
-builds: admissions whose prompt prefix is already resident reuse the
-cached page run by pointer copy and prefill only the cold suffix.  The
-tuner budgets the cache's LRU pin cap (``plan.serve_prefix_cache_pages``)
-out of the same page pool.  Cached and cache-off runs are token-
+``prefix_cache=True`` attaches a shared-prefix KV cache
+(``serving/prefix_cache.PrefixCache``) to every pool this engine builds:
+admissions whose prompt prefix is already resident reuse the cached page
+run by pointer copy and prefill only the cold suffix.  The tuner budgets
+the cache's LRU pin cap (``plan.serve_prefix_cache_pages``) out of the
+same page pool.  Cached and cache-off runs are token-
 identical by construction — the cache only changes *where* prefix KV
 comes from, never its bits.
 
@@ -44,7 +38,7 @@ comes from, never its bits.
 
 * ``"gather"`` — read K/V back *through* the page table into a
   materialized ``(slots, max_pages*page_size, K, dh)`` tensor, then
-  attend (the reference path; only option for the contiguous layout).
+  attend (the reference path).
 * ``"pallas"`` — the fused Pallas paged-attention kernel
   (``kernels/paged_attention.py``): the page table is walked inside the
   kernel, each slot's live K/V pages are copied once, in double-buffered
@@ -68,8 +62,8 @@ gather path's distance from float32 ones (tests/test_serving_paged.py),
 so a near-tie may pick another token.
 
 ``launch/serve.py`` is a thin CLI over this class; the serving benchmark
-drives both layouts and both policies through engines that share the
-request traces, so every comparison is apples-to-apples.
+drives both policies through engines that share the request traces, so
+every comparison is apples-to-apples.
 
 ``replicas`` > 1 declares this engine one of N co-resident replicas
 behind a ``ReplicaRouter``: the tuner splits the HBM budget N ways and
@@ -92,7 +86,7 @@ prefix-shared page.  ``spec_k=None`` defers to the tuner
 (``plan.serve_spec_k``, picked from the trace's repetitiveness — see
 ``repetitiveness=``); 0 disables.  Typical usage::
 
-    eng = ServeEngine(arch="picolm-4-smoke", kv_layout="paged", spec_k=4)
+    eng = ServeEngine(arch="picolm-4-smoke", spec_k=4)
     stats = eng.run(repetitive_trace(32, eng.cfg.vocab_size))
     stats.accepted_per_verify     # tokens emitted per verify step (> 1
     stats.spec_accepted_tokens    #  when drafts are being accepted)
@@ -109,19 +103,15 @@ from repro.core.target import serve_target
 from repro.kernels.ops import interpret_mode
 from repro.models.params import init_params
 from repro.models.transformer import model_for
-from repro.serving.pool import KVCachePool, PagedKVCachePool
+from repro.serving.pool import PagedKVCachePool
 from repro.serving.sampling import make_sampler
 from repro.serving.scheduler import Scheduler, ServeStats
 from repro.training.steps import (build_decode_step_slots,
-                                  build_decode_step_slots_paged,
                                   build_prefill_chunk_step,
-                                  build_prefill_chunk_step_paged,
                                   build_prefill_step,
-                                  build_verify_step_slots,
-                                  build_verify_step_slots_paged)
+                                  build_verify_step_slots)
 
 SERVABLE_FAMILIES = ("dense", "moe")
-KV_LAYOUTS = ("contiguous", "paged")
 KV_KERNELS = ("auto", "gather", "pallas")
 
 
@@ -131,26 +121,21 @@ class ServeEngine:
     def __init__(self, arch: str = "deepseek-7b-smoke",
                  target: str | None = None, num_slots: int = 8,
                  max_len: int = 128, seed: int = 0,
-                 eos_id: int | None = None, kv_layout: str = "contiguous",
+                 eos_id: int | None = None, kv_layout: str = "paged",
                  page_size: int = 0, num_pages: int = 0,
                  replicas: int = 1, prefill_chunk: int | None = None,
                  prefix_cache: bool = False, kv_kernel: str = "auto",
                  spec_k: int | None = 0, drafter=None,
                  repetitiveness: float = 0.0, log=print):
-        if kv_layout not in KV_LAYOUTS:
-            raise ValueError(f"kv_layout {kv_layout!r} not in {KV_LAYOUTS}")
+        # the pool is paged; the keyword stays for callers written when
+        # there was a second layout, which still pass it
+        if kv_layout != "paged":
+            raise ValueError(
+                f"kv_layout {kv_layout!r}: the serving pool is paged")
         if kv_kernel not in KV_KERNELS:
             raise ValueError(f"kv_kernel {kv_kernel!r} not in {KV_KERNELS}")
-        if kv_kernel == "pallas" and kv_layout != "paged":
-            raise ValueError(
-                "kv_kernel='pallas' is the fused *paged* decode kernel — "
-                f"it needs kv_layout='paged', not {kv_layout!r}")
         if replicas < 1:
             raise ValueError(f"replicas {replicas} < 1")
-        if prefix_cache and kv_layout != "paged":
-            raise ValueError(
-                "prefix_cache reuses page runs by pointer copy — it needs "
-                f"kv_layout='paged', not {kv_layout!r}")
         # `replicas` tells the tuner how many co-resident engines split the
         # HBM budget (ReplicaRouter fleets); num_slots stays the *per
         # replica* ask, so the fleet-wide batch is num_slots x replicas
@@ -163,7 +148,7 @@ class ServeEngine:
                                        "global_batch": num_slots * replicas,
                                        "serve_replicas": replicas,
                                        "serve_repetitiveness": repetitiveness},
-                      run=f"serve --engine continuous --kv-layout {kv_layout}")
+                      run="serve --engine continuous")
         cfg = app.model_config
         if cfg.family not in SERVABLE_FAMILIES:
             raise NotImplementedError(
@@ -176,50 +161,39 @@ class ServeEngine:
         tgt = serve_target(target)
         result = BuildService().build(app, tgt, lower=False)
         self.plan = result.plan
-        self.kv_layout = kv_layout
         self.replicas = replicas
         self.max_len = self.plan.serve_max_len or max_len
-        if kv_layout == "paged":
-            # the page pool, not the slot count, is the HBM reservation:
-            # slots are page-table rows, so the engine keeps the requested
-            # concurrency (capped only by one-page-per-active-request)
-            self.page_size = page_size or self.plan.serve_page_size or 16
-            if num_pages:
-                self.num_pages = num_pages
-            elif self.plan.serve_num_pages and \
-                    self.page_size == self.plan.serve_page_size:
-                self.num_pages = self.plan.serve_num_pages
-            elif self.plan.serve_num_pages:
-                # tuner sized the pool for its own page size — carry the
-                # *token* budget over to the requested page size
-                tokens = (self.plan.serve_num_pages - 1) * \
-                    self.plan.serve_page_size
-                self.num_pages = max(tokens // self.page_size, 1) + 1
-            else:
-                self.num_pages = 0
-            usable = (self.num_pages - 1) if self.num_pages else num_slots
-            self.num_slots = max(1, min(num_slots, usable))
-            if self.num_slots < num_slots:
-                log(f"[serve] pool capped by page budget: {num_slots} -> "
-                    f"{self.num_slots} slots (1 page per active request)")
+        # the page pool, not the slot count, is the HBM reservation: slots
+        # are page-table rows, so the engine keeps the requested
+        # concurrency (capped only by one-page-per-active-request)
+        self.page_size = page_size or self.plan.serve_page_size or 16
+        if num_pages:
+            self.num_pages = num_pages
+        elif self.plan.serve_num_pages and \
+                self.page_size == self.plan.serve_page_size:
+            self.num_pages = self.plan.serve_num_pages
+        elif self.plan.serve_num_pages:
+            # tuner sized the pool for its own page size — carry the
+            # *token* budget over to the requested page size
+            tokens = (self.plan.serve_num_pages - 1) * \
+                self.plan.serve_page_size
+            self.num_pages = max(tokens // self.page_size, 1) + 1
         else:
-            self.page_size = 0
             self.num_pages = 0
-            # the tuner may cap the pool below the requested batch (HBM
-            # budget): a contiguous slot is a worst-case reservation
-            self.num_slots = self.plan.serve_slots or num_slots
-            if self.num_slots < num_slots:
-                log(f"[serve] pool capped by HBM budget: "
-                    f"{num_slots} -> {self.num_slots} slots")
+        usable = (self.num_pages - 1) if self.num_pages else num_slots
+        self.num_slots = max(1, min(num_slots, usable))
+        if self.num_slots < num_slots:
+            log(f"[serve] pool capped by page budget: {num_slots} -> "
+                f"{self.num_slots} slots (1 page per active request)")
         self.cfg = cfg
         self.model = model_for(cfg, remat="none")
         self.mesh = None if tgt.num_chips == 1 else result.mesh
         self.eos_id = eos_id
         self.seed = seed
         self.log = log
-        # shared-prefix KV cache (paged only): the tuner carves an LRU
-        # pin budget out of the same page pool; default off so cache-off
-        # baselines (and every pre-cache benchmark cell) are untouched.
+        # shared-prefix KV cache: the tuner carves an LRU pin budget out
+        # of the same page pool; default off so cache-off baselines (and
+        # every pre-cache benchmark cell) are untouched.
         # The plan's quota is a page count for the PLAN's pool — make_pool
         # re-caps it against the pool actually built, so an explicit
         # --num-pages/--page-size override can never void the ~1/4 bound.
@@ -237,24 +211,16 @@ class ServeEngine:
         self.sampler = make_sampler(seed)
         prefill = build_prefill_step(self.model, self.mesh)
         self._prefill = jax.jit(prefill)
-        if kv_layout == "paged":
-            # "auto" follows the tuner's call for this target; the plan
-            # field is only "" for non-serve shapes, so default to gather
-            self.kv_kernel = kv_kernel if kv_kernel != "auto" \
-                else (self.plan.serve_kv_kernel or "gather")
-            decode = build_decode_step_slots_paged(
-                self.model, self.mesh,
-                use_kernel=(self.kv_kernel == "pallas"))
-            chunk = build_prefill_chunk_step_paged(self.model, self.mesh)
-            verify = build_verify_step_slots_paged(self.model, self.mesh)
-        else:
-            self.kv_kernel = "gather"
-            decode = build_decode_step_slots(self.model, self.mesh)
-            chunk = build_prefill_chunk_step(self.model, self.mesh)
-            verify = build_verify_step_slots(self.model, self.mesh)
+        # "auto" follows the tuner's call for this target; the plan field
+        # is only "" for non-serve shapes, so default to gather
+        self.kv_kernel = kv_kernel if kv_kernel != "auto" \
+            else (self.plan.serve_kv_kernel or "gather")
+        decode = build_decode_step_slots(
+            self.model, self.mesh, use_kernel=(self.kv_kernel == "pallas"))
+        chunk = build_prefill_chunk_step(self.model, self.mesh)
+        verify = build_verify_step_slots(self.model, self.mesh)
         log(f"[serve] target={tgt.name} platform={jax.default_backend()} "
-            f"layers={cfg.num_layers} kv_layout={kv_layout} "
-            f"kv_kernel={self.kv_kernel} interpret="
+            f"layers={cfg.num_layers} kv_kernel={self.kv_kernel} interpret="
             f"{interpret_mode() if self.kv_kernel == 'pallas' else None}")
         self._decode = jax.jit(decode, donate_argnums=(1,))
         # kv_bound (arg 6) is static: it sizes the chunk's KV read-back,
@@ -298,23 +264,19 @@ class ServeEngine:
         ``prefix_cache`` overrides the engine default for this pool."""
         use_cache = self.prefix_cache if prefix_cache is None \
             else prefix_cache
-        if self.kv_layout == "paged":
-            pool = PagedKVCachePool(self.model, self.num_slots, self.max_len,
-                                    page_size=self.page_size,
-                                    num_pages=self.num_pages)
-            if use_cache:
-                from repro.core.tuning import prefix_cache_quota
-                from repro.serving.prefix_cache import PrefixCache
-                # the tuner's quota, but never more than ~1/4 of the pool
-                # that actually got built (it may be smaller than the
-                # plan's when --num-pages/--page-size override the tuner)
-                cap = prefix_cache_quota(pool.num_pages)
-                budget = min(self.prefix_cache_pages or cap, cap)
-                PrefixCache(pool, max_pages=max(budget, 1))
-            return pool
+        pool = PagedKVCachePool(self.model, self.num_slots, self.max_len,
+                                page_size=self.page_size,
+                                num_pages=self.num_pages)
         if use_cache:
-            raise ValueError("prefix_cache needs the paged KV layout")
-        return KVCachePool(self.model, self.num_slots, self.max_len)
+            from repro.core.tuning import prefix_cache_quota
+            from repro.serving.prefix_cache import PrefixCache
+            # the tuner's quota, but never more than ~1/4 of the pool that
+            # actually got built (it may be smaller than the plan's when
+            # --num-pages/--page-size override the tuner)
+            cap = prefix_cache_quota(pool.num_pages)
+            budget = min(self.prefix_cache_pages or cap, cap)
+            PrefixCache(pool, max_pages=max(budget, 1))
+        return pool
 
     def run(self, requests, policy: str = "continuous",
             prefill_chunk: int | None = None,
@@ -361,5 +323,5 @@ class ServeEngine:
                           slo_e2e_steps=slo_e2e_steps,
                           tracer=tracer)
         stats = sched.run(list(requests))
-        self.log(f"[serve:{self.kv_layout}:{policy}] {stats.summary()}")
+        self.log(f"[serve:{policy}] {stats.summary()}")
         return stats

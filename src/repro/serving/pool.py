@@ -1,18 +1,7 @@
-"""KV cache pools — the serving stack's memory layer, in two layouts.
+"""The paged KV cache pool — the serving stack's memory layer.
 
-``KVCachePool`` (contiguous) owns one donated cache tree shaped like the
-model's decode cache but with a *slot* batch axis and a per-slot length
-vector:
-
-    k, v : (layers, num_slots, max_len, kv_heads, head_dim)
-    index: (num_slots,) int32 — tokens written per slot
-
-Every admitted request pins ``max_len`` positions of HBM for its whole
-lifetime, whatever its actual length — simple, but the pool's capacity is
-*worst cases*, not tokens.
-
-``PagedKVCachePool`` breaks that reservation: KV storage is a pool of
-fixed-size pages plus a per-slot page-table indirection,
+``PagedKVCachePool`` stores KV as a pool of fixed-size pages plus a
+per-slot page-table indirection,
 
     k, v      : (layers, num_pages, page_size, rows, lanes)
     index     : (num_slots,) int32 — tokens written per slot
@@ -26,14 +15,14 @@ stores one array instead of K and V, ``latent: (layers, num_pages,
 page_size, lanes)``, a token's latent row padded to 128-lane tiles
 (``models/mla.latent_lanes``); an MoE model's pool also carries
 ``route_counts`` (num_experts,) int32, the tokens its steps have routed
-to each expert, added up on the device (``page_stores``).  So a request
-only ever holds ``ceil(len / page_size)`` pages and the
-tuner's HBM budget buys admitted *tokens* instead of admitted worst
-cases.  Page 0 is a reserved junk page: inactive slots (zeroed
-page-table rows) scatter their dead writes there and nothing ever reads
-it through a live page table.  Pages grow on demand during decode
-(``prepare_decode``); when the pool is out of pages the scheduler
-preempts a request and resumes it later.
+to each expert, added up on the device (``page_stores``).  A request
+only ever holds ``ceil(len / page_size)`` pages, so the tuner's HBM
+budget buys admitted *tokens*, not admitted worst cases.  Page 0 is a
+reserved junk page: inactive slots (zeroed page-table rows) scatter
+their dead writes there and nothing ever reads it through a live page
+table.  Pages grow on demand during decode (``prepare_decode``); when
+the pool is out of pages the scheduler preempts a request and resumes
+it later.
 
 Pages are **refcounted** (``page_refs``): normally a page has one owner
 and ``free`` returns it immediately, but an attached shared-prefix cache
@@ -44,12 +33,11 @@ preempted sharer can never free a page another request still reads.
 Under page pressure the allocator reclaims cache-only pages (LRU) before
 reporting starvation.
 
-Both pools hand out slots/pages from deterministic LIFO free lists with
-an O(1) boolean free-mask (no linear membership scans), scatter prefilled
-requests in with ``insert``, and ride the whole pool through one
-slot-wise decode step per iteration so requests of different lengths
-share every matmul.  Buffers are donated on both the insert and the
-decode path; the engine swaps the tree via ``update``.
+The pool hands out slots and pages from deterministic LIFO free lists
+with an O(1) boolean free-mask (no linear membership scans), and rides
+whole through one slot-wise decode step per iteration so requests of
+different lengths share every matmul.  Buffers are donated on both the
+insert and the decode path; the engine swaps the tree via ``update``.
 """
 
 from __future__ import annotations
@@ -98,7 +86,7 @@ class _FreeList:
         return bool(self._mask[idx])
 
 
-def _check_servable(cfg, layout="paged"):
+def _check_servable(cfg):
     if cfg.family not in ("dense", "moe"):
         raise NotImplementedError(
             f"KV pools serve attention-cache families (dense/moe), "
@@ -108,19 +96,6 @@ def _check_servable(cfg, layout="paged"):
             "slot-wise decode does not apply sliding-window attention "
             "yet; a windowed config served here would silently attend "
             "the full history")
-    if cfg.kv_lora_rank and layout != "paged":
-        raise NotImplementedError(
-            "latent attention is served from the paged pool only")
-
-
-@partial(jax.jit, donate_argnums=(0,))
-def _scatter_insert(cache, slot, pk, pv):
-    """Write a batch-1 prefill cache (L, 1, s, K, dh) into `slot`[0:s)."""
-    s = pk.shape[2]
-    k = jax.lax.dynamic_update_slice(cache["k"], pk, (0, slot, 0, 0, 0))
-    v = jax.lax.dynamic_update_slice(cache["v"], pv, (0, slot, 0, 0, 0))
-    index = cache["index"].at[slot].set(s)
-    return {"k": k, "v": v, "index": index}
 
 
 LANES = 128     # a TPU vector register's lanes: the minor dim of a tile
@@ -163,7 +138,7 @@ def page_stores(cfg, num_pages: int, page_size: int) -> dict:
 
 
 @partial(jax.jit, donate_argnums=(0,))
-def _scatter_insert_paged(cache, slot, pages_row, prefill):
+def _scatter_insert(cache, slot, pages_row, prefill):
     """Write a batch-1 prefill cache — each store (L, 1, s, ...) — through
     `pages_row`.
 
@@ -183,149 +158,15 @@ def _scatter_insert_paged(cache, slot, pages_row, prefill):
     return out
 
 
-class KVCachePool:
-    """Fixed-capacity contiguous slot pool over a model's decode cache."""
-
-    layout = "contiguous"
-
-    def __init__(self, model, num_slots: int, max_len: int):
-        cfg = model.cfg
-        _check_servable(cfg, self.layout)
-        if num_slots < 1 or max_len < 1:
-            raise ValueError((num_slots, max_len))
-        self.cfg = cfg
-        self.num_slots = num_slots
-        self.max_len = max_len
-        kv_shape = (cfg.num_layers, num_slots, max_len,
-                    cfg.num_kv_heads, cfg.head_dim)
-        self.cache = {"k": jnp.zeros(kv_shape, cfg.activation_dtype),
-                      "v": jnp.zeros(kv_shape, cfg.activation_dtype),
-                      "index": jnp.zeros((num_slots,), jnp.int32)}
-        self._free = _FreeList(num_slots)
-        self.lengths = np.zeros((num_slots,), np.int64)  # host mirror
-
-    # -- capacity ----------------------------------------------------------
-    @property
-    def num_free(self) -> int:
-        return len(self._free)
-
-    @property
-    def free_tokens(self) -> int:
-        """Admittable KV tokens left (contiguous: free worst-case slots) —
-        the load signal a router's least-loaded policy balances."""
-        return self.num_free * self.max_len
-
-    def can_admit(self, prompt_len: int, active_slots=(),
-                  hit=None) -> bool:
-        """A contiguous slot IS the worst-case reservation: one free slot
-        admits any prompt that fits max_len.  (``hit`` — a prefix-cache
-        probe — only ever applies to paged pools and is ignored here.)"""
-        return self.num_free > 0 and prompt_len <= self.max_len
-
-    def can_ever_serve(self, n_tokens: int) -> bool:
-        """Whether a request resident at `n_tokens` could ever fit an
-        otherwise-empty pool (contiguous: max_len is the only bound)."""
-        return n_tokens <= self.max_len
-
-    # -- slot lifecycle ----------------------------------------------------
-    def alloc(self) -> int:
-        if not self._free:
-            raise PoolExhausted(
-                f"all {self.num_slots} KV slots are in flight")
-        return self._free.pop()
-
-    def free(self, slot: int) -> None:
-        if not 0 <= slot < self.num_slots:
-            raise ValueError(f"slot {slot} out of range")
-        if self._free.is_free(slot):
-            raise ValueError(f"slot {slot} is already free")
-        self.lengths[slot] = 0
-        self._free.push(slot)
-
-    # -- cache plumbing ----------------------------------------------------
-    def insert(self, slot: int, prefill_cache: dict) -> None:
-        """Scatter a (batch=1) prefill cache into `slot` positions [0, s).
-
-        Legacy/test path: the serving engine now writes prompt KV straight
-        into the pool from the chunked prefill step (``reserve_prefix`` +
-        ``adopt``) and never materializes this intermediate cache."""
-        pk, pv = prefill_cache["k"], prefill_cache["v"]
-        s = pk.shape[2]
-        if s > self.max_len:
-            raise ValueError(f"prefill length {s} > pool max_len {self.max_len}")
-        self.cache = _scatter_insert(self.cache, jnp.int32(slot), pk, pv)
-        self.lengths[slot] = s
-
-    def reserve_prefix(self, slot: int, n_tokens: int) -> None:
-        """Reserve room for an `n_tokens` prompt before chunked prefill
-        (contiguous: a slot IS the reservation — just bounds-check)."""
-        if n_tokens > self.max_len:
-            raise ValueError(
-                f"prefix of {n_tokens} tokens > pool max_len {self.max_len}")
-
-    def chunk_extras(self, slot: int) -> tuple:
-        """Extra per-chunk arguments for the jitted chunk-prefill step."""
-        return ()
-
-    @property
-    def kv_bound_cap(self) -> int:
-        """Largest KV prefix a chunk could ever need to read back."""
-        return self.max_len
-
-    def adopt(self, new_cache: dict) -> None:
-        """Take ownership of the cache returned by a (donating) chunk
-        step; the host length mirror advances via ``set_length``."""
-        self.cache = new_cache
-
-    def set_length(self, slot: int, n_tokens: int) -> None:
-        self.lengths[slot] = n_tokens
-
-    def prepare_decode(self, active_slots) -> list:
-        """Contiguous slots never grow — nothing can starve."""
-        return []
-
-    def decode_extras(self) -> tuple:
-        """Extra per-step arguments for the jitted decode step."""
-        return ()
-
-    def grow_for_burst(self, slot: int, want_tokens: int) -> int:
-        """KV positions backed for a speculative verify burst starting at
-        the slot's current length.  Contiguous slots reserve max_len up
-        front, so burst capacity is just the slot's length headroom."""
-        return max(int(min(want_tokens, self.max_len - self.lengths[slot])),
-                   0)
-
-    def sync_index(self) -> None:
-        """Re-upload the host length mirror as the device index vector.
-
-        After a verify step the device index is stale by design (the step
-        returns it unchanged — acceptance is a host decision), so the
-        scheduler calls this once per spec step.  Free slots sync to 0,
-        which is harmless: admission re-seeds their index before any
-        decode reads it."""
-        self.cache = dict(self.cache,
-                          index=jnp.asarray(self.lengths, jnp.int32))
-
-    def update(self, new_cache: dict, active_slots=()) -> None:
-        """Adopt the cache returned by a (donating) decode step; the length
-        mirror advances only for the slots that were active this step."""
-        self.cache = new_cache
-        for slot in active_slots:
-            self.lengths[slot] += 1
-
-
 class PagedKVCachePool:
     """Page-table KV pool: slots hold page lists, not max_len reservations.
 
     ``num_pages`` counts the whole pool *including* the reserved junk page
     0, so ``num_pages - 1`` pages are allocatable.  A slot may hold at most
-    ``max_pages = ceil(max_len / page_size)`` pages (the same per-request
-    cap as a contiguous slot).  The page table lives on the host (alloc /
-    free are pure bookkeeping, no device traffic) and is shipped to the
-    decode step as a small int32 array each iteration.
+    ``max_pages = ceil(max_len / page_size)`` pages.  The page table lives
+    on the host (alloc / free are pure bookkeeping, no device traffic) and
+    is shipped to the decode step as a small int32 array each iteration.
     """
-
-    layout = "paged"
 
     def __init__(self, model, num_slots: int, max_len: int,
                  page_size: int = 16, num_pages: int = 0):
@@ -381,11 +222,12 @@ class PagedKVCachePool:
 
     @property
     def free_tokens(self) -> int:
-        """Admittable KV tokens left (paged: free pages worth of tokens,
-        gated on a free page-table row existing at all).  Cache-only
-        prefix pages count as free — they are reclaimed before anything
-        starves — and a page shared by N requests is simply not free, so
-        the router's least-loaded signal never double-counts it."""
+        """Admittable KV tokens left — the load signal a router's
+        least-loaded policy balances: free pages worth of tokens, gated on
+        a free page-table row existing at all.  Cache-only prefix pages
+        count as free — they are reclaimed before anything starves — and
+        a page shared by N requests is simply not free, so the signal
+        never double-counts it."""
         if not self.num_free:
             return 0
         return (self.free_pages + self.reclaimable_pages) * self.page_size
@@ -514,7 +356,7 @@ class PagedKVCachePool:
         """Allocate pages for a (batch=1) prefill cache and scatter it in.
 
         Legacy/test path — it costs one extra copy of the prompt's KV:
-        the contiguous ``(1, s)`` cache is materialized by the prefill
+        the whole-prompt ``(1, s)`` cache is materialized by the prefill
         step and then re-scattered through the page table.  The serving
         engine now writes through ``reserve_prefix`` + the chunked
         prefill step, which scatters each chunk's KV to its final
@@ -524,7 +366,7 @@ class PagedKVCachePool:
         if s > self.max_len:
             raise ValueError(f"prefill length {s} > pool max_len {self.max_len}")
         self.reserve_prefix(slot, s)
-        self.cache = _scatter_insert_paged(
+        self.cache = _scatter_insert(
             self.cache, jnp.int32(slot),
             jnp.asarray(self.page_table[slot]), stores)
         self.lengths[slot] = s
@@ -551,9 +393,12 @@ class PagedKVCachePool:
 
     @property
     def kv_bound_cap(self) -> int:
+        """Largest KV prefix a chunk could ever need to read back."""
         return self.max_pages * self.page_size
 
     def adopt(self, new_cache: dict) -> None:
+        """Take ownership of the cache returned by a (donating) chunk
+        step; the host length mirror advances via ``set_length``."""
         self.cache = new_cache
 
     def set_length(self, slot: int, n_tokens: int) -> None:
@@ -571,6 +416,8 @@ class PagedKVCachePool:
         return starved
 
     def decode_extras(self) -> tuple:
+        """Extra per-step arguments for the jitted decode step: the page
+        table."""
         return (jnp.asarray(self.page_table),)
 
     def grow_for_burst(self, slot: int, want_tokens: int) -> int:
@@ -600,12 +447,19 @@ class PagedKVCachePool:
                        self.max_len - int(self.lengths[slot])), 0)
 
     def sync_index(self) -> None:
-        """Re-upload the host length mirror as the device index (see the
-        contiguous pool's ``sync_index``)."""
+        """Re-upload the host length mirror as the device index vector.
+
+        After a verify step the device index is stale by design (the step
+        returns it unchanged — acceptance is a host decision), so the
+        scheduler calls this once per spec step.  Free slots sync to 0,
+        which is harmless: admission re-seeds their index before any
+        decode reads it."""
         self.cache = dict(self.cache,
                           index=jnp.asarray(self.lengths, jnp.int32))
 
     def update(self, new_cache: dict, active_slots=()) -> None:
+        """Adopt the cache returned by a (donating) decode step; the length
+        mirror advances only for the slots that were active this step."""
         self.cache = new_cache
         for slot in active_slots:
             self.lengths[slot] += 1

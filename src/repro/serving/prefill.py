@@ -1,7 +1,7 @@
 """PrefillManager — chunked prompt ingestion as its own schedulable stage.
 
 Prompt ingestion used to be an inline side effect of admission: the
-scheduler ran one blocking full-prompt prefill, materialized a contiguous
+scheduler ran one blocking full-prompt prefill, materialized a whole
 ``(1, s)`` cache, and ``insert`` re-scattered it into the pool — stalling
 the decode loop for the whole prompt and (under the router) stalling the
 whole lockstep fleet, since admissions run serially on the driver thread.
@@ -12,11 +12,10 @@ tunable stage out of a monolithic deployment step:
   ``plan.serve_prefill_chunk``); each chunk is padded to a power-of-two
   bucket so the jit cache stays at ~log2(chunk) entries;
 * each chunk runs through the **chunk-prefill step**
-  (``training/steps.build_prefill_chunk_step[_paged]``), which computes
-  the chunk's KV and scatters it **directly into pool slots/pages** —
-  its final resting place, one write, no contiguous intermediate — while
-  attending causally over every prior chunk through the pool's own
-  indirection (page table or slot row);
+  (``training/steps.build_prefill_chunk_step``), which computes the
+  chunk's KV and scatters it **directly into the slot's pages** — its
+  final resting place, one write, no intermediate cache — while
+  attending causally over every prior chunk through the page table;
 * the scheduler interleaves at most one chunk budget's worth of prefill
   tokens between decode ticks (``Scheduler.step``), so in-flight requests
   keep streaming while a new prompt is ingested, and a router overlaps
@@ -122,7 +121,7 @@ class PrefillManager:
     @property
     def prefix_cache(self):
         """The pool's attached shared-prefix cache (None when disabled)."""
-        return getattr(self.pool, "prefix_cache", None)
+        return self.pool.prefix_cache
 
     # -- lifecycle -----------------------------------------------------------
     def submit(self, entry, st, prompt: np.ndarray) -> PrefillJob:

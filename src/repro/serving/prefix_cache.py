@@ -101,10 +101,6 @@ class PrefixCache:
     """
 
     def __init__(self, pool, max_pages: int = 0):
-        if getattr(pool, "layout", None) != "paged":
-            raise ValueError(
-                "PrefixCache needs a paged pool (page-run sharing has no "
-                f"meaning for layout {getattr(pool, 'layout', None)!r})")
         if max_pages < 0:
             raise ValueError(f"max_pages {max_pages} < 0")
         self.pool = pool

@@ -2,8 +2,7 @@
 
 The single-engine stack tunes one KV pool from one HBM budget; the
 north-star traffic level needs the same automatic sizing across a fleet.
-The router fronts N ``ServeEngine`` replicas (mixed KV layouts allowed —
-e.g. two paged and one contiguous) and owns admission:
+The router fronts N ``ServeEngine`` replicas and owns admission:
 
 * every request enters a router-level FIFO;
 * a **routing policy** picks the replica for the queue head among the
@@ -11,8 +10,7 @@ e.g. two paged and one contiguous) and owns admission:
 
   - ``round_robin``      — ring order, skipping full replicas;
   - ``least_loaded``     — the replica with the most free KV *tokens*
-                           (``pool.free_tokens`` — worst-case slots for
-                           contiguous pools, free pages for paged ones);
+                           (``pool.free_tokens``: its free pages);
   - ``prefix_affinity``  — rendezvous (highest-random-weight) hash of the
                            prompt prefix, so likely-shared prefixes land
                            on the same replica and the mapping is *stable
@@ -583,8 +581,8 @@ class ReplicaRouter:
     @classmethod
     def build(cls, arch: str = "deepseek-7b-smoke",
               target: str | None = None, replicas: int = 2,
-              kv_layout: str = "contiguous", num_slots: int = 8,
-              max_len: int = 128, seed: int = 0, eos_id: int | None = None,
+              num_slots: int = 8, max_len: int = 128, seed: int = 0,
+              eos_id: int | None = None,
               policy: str = "least_loaded", page_size: int = 0,
               num_pages: int = 0, prefill_chunk: int | None = None,
               prefix_cache: bool = False, kv_kernel: str = "auto",
@@ -592,35 +590,20 @@ class ReplicaRouter:
               repetitiveness: float = 0.0, log=print) -> "ReplicaRouter":
         """Build an N-replica fleet, splitting the tuner budget N ways.
 
-        ``kv_layout`` may be comma-separated (``"paged,contiguous"``) and
-        is cycled across replica slots — one engine is built per distinct
-        layout and *shared* between its slots (jitted steps and weights
-        compile once; pools stay per-replica).
+        One engine is built and *shared* between the replica slots
+        (jitted steps and weights compile once; pools stay per-replica).
         """
         from repro.serving.engine import ServeEngine
         if replicas < 1:
             raise ValueError(f"replicas {replicas} < 1")
-        layouts = [l.strip() for l in kv_layout.split(",") if l.strip()]
-        if not layouts:
-            raise ValueError(f"no kv layout in {kv_layout!r}")
-        built: dict[str, object] = {}
-        fleet = []
-        for i in range(replicas):
-            lay = layouts[i % len(layouts)]
-            if lay not in built:
-                built[lay] = ServeEngine(
-                    arch=arch, target=target, num_slots=num_slots,
-                    max_len=max_len, seed=seed, eos_id=eos_id,
-                    kv_layout=lay, page_size=page_size, num_pages=num_pages,
-                    replicas=replicas, prefill_chunk=prefill_chunk,
-                    # mixed fleets: the cache / fused decode kernel only
-                    # apply to paged slots
-                    prefix_cache=prefix_cache and lay == "paged",
-                    kv_kernel=kv_kernel if lay == "paged" else "auto",
-                    spec_k=spec_k, drafter=drafter,
-                    repetitiveness=repetitiveness, log=log)
-            fleet.append(built[lay])
-        return cls(fleet, policy=policy, log=log)
+        engine = ServeEngine(
+            arch=arch, target=target, num_slots=num_slots, max_len=max_len,
+            seed=seed, eos_id=eos_id, page_size=page_size,
+            num_pages=num_pages, replicas=replicas,
+            prefill_chunk=prefill_chunk, prefix_cache=prefix_cache,
+            kv_kernel=kv_kernel, spec_k=spec_k, drafter=drafter,
+            repetitiveness=repetitiveness, log=log)
+        return cls([engine] * replicas, policy=policy, log=log)
 
     # -- validation ---------------------------------------------------------
     def _validate(self, requests, scheds) -> None:
@@ -791,9 +774,7 @@ class ReplicaRouter:
         the TTFT baseline).  ``prefix_cache`` likewise overrides the
         per-replica shared-prefix KV cache (None: each engine's own
         setting) — caches are per replica, which composes with
-        ``prefix_affinity`` colocating sharers on one replica.  In a
-        mixed-layout fleet the override applies to the paged replicas
-        only; contiguous pools have no pages to share.
+        ``prefix_affinity`` colocating sharers on one replica.
 
         The fleet shares one virtual step clock: blocking prefills at
         dispatch advance it serially (they run one after another on the
@@ -830,9 +811,7 @@ class ReplicaRouter:
                 "autoscale requires the continuous scheduling policy (a "
                 "draining replica keeps stepping while closed to admission)")
         shared = VirtualClock()
-        scheds = [Scheduler(e.make_pool(prefix_cache=(
-                                prefix_cache if e.kv_layout == "paged"
-                                else None)),
+        scheds = [Scheduler(e.make_pool(prefix_cache=prefix_cache),
                             e.prefill_fn, e.decode_fn,
                             eos_id=e.eos_id, policy=policy,
                             sampler=e.sampler, clock=self.clock,

@@ -7,8 +7,8 @@ the request id and the generation step (rather than the slot or the wall
 clock) makes sampling deterministic across admission order, slot
 assignment, *and* preemption — a request that is preempted and later
 resumed re-draws exactly the token stream it would have produced
-uninterrupted, which is what keeps the paged-vs-contiguous equivalence
-tests honest under page pressure.  It is also what makes speculative
+uninterrupted, which is what keeps the preemption equivalence tests
+honest under page pressure.  It is also what makes speculative
 decoding bit-identical: the verify step samples positions
 ``step .. step+k`` with the very same per-row math, so an accepted burst
 reproduces the sequential draws token for token.

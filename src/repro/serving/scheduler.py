@@ -1,6 +1,6 @@
 """Request scheduler: admission, in-flight batching, eviction, preemption.
 
-Two policies over the same KV pool (contiguous or paged) and jitted steps:
+Two policies over the same paged KV pool and jitted steps:
 
 * ``continuous`` — between decode steps, every freed slot is immediately
   re-prefilled from the queue (continuous batching / in-flight batching).
@@ -8,10 +8,9 @@ Two policies over the same KV pool (contiguous or paged) and jitted steps:
   *last* request finishes, then admit the next batch.  This is the old
   ``launch/serve.py`` behaviour, kept as the benchmark baseline.
 
-The scheduler is layout-agnostic: it admits through ``pool.can_admit``
-(contiguous pools count free *slots*; paged pools count free *pages*,
-with headroom reserved for in-flight requests about to cross a page
-boundary), grows paged slots before each decode step via
+The scheduler admits through ``pool.can_admit`` (free *pages*, with
+headroom reserved for in-flight requests about to cross a page
+boundary), grows slots before each decode step via
 ``pool.prepare_decode``, and — when the page pool is starved mid-decode —
 **preempts** the youngest in-flight request: its slot and pages are
 freed and it is re-queued at the front.  A preempted request is resumed
@@ -52,8 +51,8 @@ re-routing instead of raising).
 
 * ``prefill_chunk == 0`` — *blocking*: the whole (bucketed) prompt runs
   as one chunk inline at admission, exactly the old cadence — but the
-  chunk step scatters its KV straight into pool slots/pages, so even
-  this path no longer materializes a contiguous ``(1, s)`` cache for
+  chunk step scatters its KV straight into the slot's pages, so even
+  this path no longer materializes a whole-prompt ``(1, s)`` cache for
   ``insert`` to re-scatter.
 * ``prefill_chunk > 0`` — *chunked*: admission reserves the slot and the
   prompt's pages, queues a ``PrefillJob``, and ``step`` interleaves at
@@ -457,7 +456,7 @@ class Scheduler:
             PrefillManager(self.pool, self.chunk_step_fn, self.prefill_chunk,
                            tracer=self.tracer, vclock=self.vclock,
                            replica_id=self.replica_id)
-        pc = getattr(self.pool, "prefix_cache", None)
+        pc = self.pool.prefix_cache
         if pc is not None and hasattr(pc, "bind_tracer"):
             pc.bind_tracer(self.tracer, self.vclock, self.replica_id)
 
@@ -591,7 +590,7 @@ class Scheduler:
     def _probe_prefix(self, entry: _Entry):
         """Read-only shared-prefix cache probe for `entry` (None when no
         cache is attached or prefill bypasses the chunk pipeline)."""
-        cache = getattr(self.pool, "prefix_cache", None)
+        cache = self.pool.prefix_cache
         if cache is None or self._mgr is None:
             return None
         return cache.probe(entry.pending_tokens())
@@ -671,7 +670,7 @@ class Scheduler:
             self.vclock.advance_serial(-(-job.remaining // self.chunk_unit))
             self._finish_prefill(job, self._mgr.drain(job))
             return
-        # legacy path (no chunk step): prefill to a contiguous (1, s)
+        # legacy path (no chunk step): prefill to a whole-prompt (1, s)
         # cache, then scatter it into the pool.  Prefill lengths are
         # bucketed to powers of two so resumes (whose lengths are
         # arbitrary) reuse one compiled prefill per bucket: the prompt is
@@ -981,7 +980,7 @@ class Scheduler:
             len(r.tokens) for r in done
             if r.meets_slo(self.slo_ttft_steps, self.slo_e2e_steps))
         mgr = self._mgr
-        pc = getattr(self.pool, "prefix_cache", None)
+        pc = self.pool.prefix_cache
         expert_tokens, held_share = self._routing()
         return ServeStats(
             results=done, wall_s=wall, decode_steps=self._steps,

@@ -4,8 +4,8 @@ The serving decode loop emits one token per jitted step per slot, so
 tokens/step — the bench's gated metric — is hard-capped by batch
 occupancy.  Speculative decoding breaks the cap: a cheap **drafter**
 proposes k tokens per slot from the slot's own history, one VERIFY step
-(``training/steps.build_verify_step_slots[_paged]``) scores all k+1
-positions against pool KV at once, and the scheduler accepts the longest
+(``training/steps.build_verify_step_slots``) scores all k+1 positions
+against pool KV at once, and the scheduler accepts the longest
 prefix of drafts that matches what the per-``(rid, step)`` sampler would
 have drawn sequentially — so speculative streams are **bit-identical** to
 non-speculative ones, and a verify step that accepts a tokens advances

@@ -137,38 +137,25 @@ def build_prefill_step(model, mesh=None):
 
 
 def build_prefill_chunk_step(model, mesh=None):
-    """Chunked prefill straight into a *contiguous* serving KV pool.
+    """Chunked prefill straight into the serving KV pool.
 
-    ``cache`` is the pool's cache tree — K/V shaped ``(layers, num_slots,
-    max_len, kv_heads, head_dim)`` plus the per-slot ``index`` vector —
-    and ``tokens`` is one bucketed ``(1, c)`` chunk of one request's
-    prompt.  The chunk's K/V scatter directly to ``[slot, offset:offset+c)``
-    (no intermediate contiguous ``(1, s)`` cache that ``insert`` would
-    have to re-scatter), the chunk attends causally over everything the
-    slot already holds, and the returned logits sit at the chunk's last
-    valid position (``n_valid`` <= c covers bucket padding).  Jittable
-    with ``kv_bound`` static (it sizes the slot's KV read-back — a short
-    prompt attends its own bucketed prefix, not max_len); the engine
-    donates the cache argument.
-    """
-    def chunk_step(params, cache, tokens, slot, offset, n_valid, kv_bound):
-        return model.chunk_prefill(params, cache, tokens, slot, offset,
-                                   n_valid, mesh, kv_bound)
-    return chunk_step
-
-
-def build_prefill_chunk_step_paged(model, mesh=None):
-    """Chunked prefill straight into a *paged* serving KV pool.
-
-    Same contract as ``build_prefill_chunk_step``, but K/V are the page
-    pool ``(layers, num_pages, page_size, rows, lanes)`` (a token's K or V
-    heads as ``serving/pool.page_rows`` stores them) and
+    ``cache`` is the pool's cache tree — K/V as the page pool ``(layers,
+    num_pages, page_size, rows, lanes)`` (a token's K or V heads as
+    ``serving/pool.page_rows`` stores them; or one latent pool,
+    ``serving/pool.page_stores``) plus the per-slot ``index`` vector — and
+    ``tokens`` is one bucketed ``(1, c)`` chunk of one request's prompt.
     ``pages_row`` is the slot's ``(max_pages,)`` page-table row: chunk
     token at global position j lands in page ``pages_row[j // page_size]``
-    at offset ``j % page_size`` — its final resting place, one write,
-    in place in the pool the layer scan carries (as the decode step).
-    Pages must be reserved by the pool before the call; rows past the
-    reserved region (bucket padding) fall into the junk page 0.
+    at offset ``j % page_size`` — its final resting place, one write, in
+    place in the pool the layer scan carries (as the decode step), with
+    no intermediate ``(1, s)`` cache for ``insert`` to re-scatter.  Pages
+    must be reserved by the pool before the call; rows past the reserved
+    region (bucket padding) fall into the junk page 0.  The chunk attends
+    causally over everything the slot already holds, and the returned
+    logits sit at the chunk's last valid position (``n_valid`` <= c
+    covers bucket padding).  Jittable with ``kv_bound`` static (it sizes
+    the slot's KV read-back — a short prompt attends its own bucketed
+    prefix, not max_len); the engine donates the cache argument.
     """
     def chunk_step(params, cache, tokens, slot, offset, n_valid, kv_bound,
                    pages_row):
@@ -184,33 +171,21 @@ def build_decode_step(model, mesh=None):
     return decode_step
 
 
-def build_decode_step_slots(model, mesh=None):
-    """Slot-wise decode for the continuous-batching serving engine.
+def build_decode_step_slots(model, mesh=None, use_kernel: bool = False):
+    """Slot-wise decode over the serving KV pool (PagedKVCachePool), for
+    the continuous-batching engine.
 
-    ``cache['index']`` is a per-slot length vector (one row per KV-pool
+    ``cache['index']`` is a per-slot length vector (one row per pool
     slot) and ``active`` flags the slots holding a live request.  Inactive
     slots still ride through the batched matmuls — the fixed price of
     slot-indexed batching — but their lengths do not advance, so a freed
     slot can be re-prefilled between steps without disturbing its
-    neighbours.  Jittable; the engine donates the cache argument.
-    """
-    def decode_step(params, cache, tokens, active):
-        logits, new_cache = model.decode_step(params, cache, tokens, mesh)
-        keep = active.astype(bool)
-        new_index = jnp.where(keep, new_cache["index"], cache["index"])
-        return logits, dict(new_cache, index=new_index)
-    return decode_step
-
-
-def build_decode_step_slots_paged(model, mesh=None, use_kernel: bool = False):
-    """Slot-wise decode over a *paged* KV pool (PagedKVCachePool).
-
-    Same contract as ``build_decode_step_slots``, but the cache's K/V are
-    a page pool ``(layers, num_pages, page_size, rows, lanes)`` (or one
-    latent pool, ``serving/pool.page_stores``) and
-    the per-slot ``(num_slots, max_pages)`` int32 page table arrives as an
-    extra argument each step (the pool keeps it on the host so page
-    alloc/free never touches the device).  The model reads and writes K/V
+    neighbours.  The cache's K/V are a page pool ``(layers, num_pages,
+    page_size, rows, lanes)`` (or one latent pool,
+    ``serving/pool.page_stores``) and the per-slot ``(num_slots,
+    max_pages)`` int32 page table arrives as an extra argument each step
+    (the pool keeps it on the host so page alloc/free never touches the
+    device).  The model reads and writes K/V
     through the table; a slot whose table row is zeroed (freed) scatters
     its dead write into the reserved junk page 0 (of each layer).
     Jittable; the engine donates the cache argument only — the page table
@@ -253,39 +228,26 @@ def _pool_cache(new_cache, index):
 
 
 def build_verify_step_slots(model, mesh=None):
-    """Speculative VERIFY step over a contiguous slot pool.
+    """Speculative VERIFY step over the serving KV pool.
 
     ``tokens`` is ``(num_slots, k+1)`` — each row's pending token followed
     by its k drafted tokens — and the step returns logits at **every**
     speculated position ``(num_slots, k+1, vocab)``, scoring all of them
     against pool KV in one jitted call (the multi-position generalization
     of the single-token decode scatter in ``models/layers.attention``).
-    Positions past a slot's capacity drop harmlessly; rejected-draft KV is
-    overwritten by the next step before any causal mask admits it.
-
-    The returned cache keeps ``index`` UNCHANGED: how many of the k+1
-    positions became real tokens is the host's acceptance decision, so the
-    scheduler re-uploads its post-acceptance length mirror
-    (``pool.sync_index``) instead of trusting a device-side +k+1.
-    """
-    def verify_step(params, cache, tokens, active):
-        logits, new_cache = model.decode_step(params, cache, tokens, mesh)
-        return logits, dict(new_cache, index=cache["index"])
-    return verify_step
-
-
-def build_verify_step_slots_paged(model, mesh=None):
-    """Speculative VERIFY step over a paged KV pool.
-
-    Same contract as ``build_verify_step_slots`` plus the page table
-    argument; inactive rows divert through junk page 0 exactly like
-    ``build_decode_step_slots_paged``, and per-position page lookup keeps
+    Rejected-draft KV is overwritten by the next step before any causal
+    mask admits it.  Inactive rows divert through junk page 0 exactly
+    like ``build_decode_step_slots``, and per-position page lookup keeps
     the same ok-guard, so a burst past a slot's page-run capacity can
     never scribble into a (possibly prefix-shared) live page.  The fused
     Pallas kernel is single-token-only, so verify always reads through
     the gather path, which agrees with the kernel to the activation
     dtype's rounding (tests/test_kernels_paged.py).
-    ``index`` stays host-authoritative (see ``build_verify_step_slots``).
+
+    The returned cache keeps ``index`` UNCHANGED: how many of the k+1
+    positions became real tokens is the host's acceptance decision, so the
+    scheduler re-uploads its post-acceptance length mirror
+    (``pool.sync_index``) instead of trusting a device-side +k+1.
     """
     def verify_step(params, cache, tokens, active, pages):
         keep = active.astype(bool)

@@ -182,15 +182,13 @@ def _serve_plan(replicas: int):
 
 
 def test_tuner_splits_serve_budget_per_replica():
-    """Per-replica slot/page counts shrink as the fleet grows: N replicas
+    """Per-replica page counts shrink as the fleet grows: N replicas
     share one HBM budget, so each gets ~1/N of the KV pool."""
     plans = {n: _serve_plan(n) for n in (1, 2, 4)}
     assert plans[1].serve_replicas == 1 and plans[4].serve_replicas == 4
-    assert plans[1].serve_slots > plans[2].serve_slots > plans[4].serve_slots
     assert plans[1].serve_num_pages > plans[2].serve_num_pages \
         > plans[4].serve_num_pages
     # ~proportional: a 4-way split leaves each replica about a quarter
-    assert plans[4].serve_slots <= plans[1].serve_slots // 4 + 1
     assert plans[4].serve_num_pages <= plans[1].serve_num_pages // 4 + 1
 
 
@@ -211,10 +209,10 @@ def test_tuner_fleet_capacity_within_a_page_per_replica():
 def test_tuner_replica_split_napkin_renders_and_roundtrips():
     from repro.core.plan import DeploymentPlan
     plan = _serve_plan(3)
-    for key in ("serve_fleet_capacity", "serve_fleet_tokens", "serve_pool",
+    for key in ("serve_fleet_capacity", "serve_fleet_tokens",
                 "serve_pool_paged"):
         assert key in plan.napkin, key
-    assert "per replica" in plan.napkin["serve_pool"]
+    assert "per replica" in plan.napkin["serve_pool_paged"]
     report = plan.report()
     assert "serve replicas  : 3" in report
     assert "serve_fleet_capacity" in report
@@ -222,7 +220,7 @@ def test_tuner_replica_split_napkin_renders_and_roundtrips():
     assert again.serve_replicas == 3
     assert again.serve_num_pages == plan.serve_num_pages
     # replicas=1 keeps the original single-engine phrasing
-    assert "per replica" not in _serve_plan(1).napkin["serve_pool"]
+    assert "per replica" not in _serve_plan(1).napkin["serve_pool_paged"]
 
 
 @pytest.mark.parametrize("platform,kind,count,want", [
@@ -297,7 +295,7 @@ def test_serving_takes_one_chip_of_the_host(monkeypatch, count):
         raise Built(target.name)
 
     monkeypatch.setattr(engine_mod.BuildService, "build", build)
-    for make in (lambda: engine_mod.ServeEngine(kv_layout="paged"),
+    for make in (lambda: engine_mod.ServeEngine(),
                  lambda: ReplicaRouter.build(replicas=1)):
         with pytest.raises(Built, match="^local:tpu-v5e$"):
             make()

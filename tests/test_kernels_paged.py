@@ -1,5 +1,5 @@
 """Fused paged-attention decode kernel: equivalence sweep vs the gather
-oracle and the contiguous slot-decode path, plus junk-page masking.
+oracle and slot-wise attention over the same KV, plus junk-page masking.
 
 The kernel (kernels/paged_attention.py) is held to the gather-then-attend
 oracle within the dtype's tolerance (2e-2 in bf16, 2e-5 in float32): it
@@ -10,7 +10,7 @@ sweep crosses page size x pages-per-slot x GQA ratio x per-slot lengths
 lengths spanning several of the kernel's page blocks with a partial last
 page and block, pages past a slot's length that would poison any read —
 and checks three-way agreement: paged-Pallas == gather oracle ==
-contiguous slot-decode attention over the same KV.
+slot-wise ``dot_attention`` over the same KV laid out per slot.
 """
 
 import sys
@@ -86,10 +86,10 @@ def test_paged_kernel_three_way_equivalence(psize, mp, K, G, dh, lens,
     want = np.asarray(
         ref.paged_attention_ref(q, kp, vp, table, kv_len), np.float32)
     np.testing.assert_allclose(out, want, rtol=_tol(dtype), atol=_tol(dtype))
-    # contiguous slot decode: the same KV laid out (slots, t, K, dh),
+    # slot-wise attention: the same KV laid out (slots, t, K, dh),
     # attended with per-row lengths — live slots only (a fully-masked
-    # contiguous row softmaxes to uniform, by design its output is
-    # discarded upstream)
+    # row softmaxes to uniform, by design its output is discarded
+    # upstream)
     t = mp * psize
     kc = jnp.take(kp, table, axis=0).reshape(len(lens), t, K, dh)
     vc = jnp.take(vp, table, axis=0).reshape(len(lens), t, K, dh)

@@ -16,9 +16,8 @@ from repro.core.tuning import (active_param_count, kv_bytes_per_token,
 from repro.kernels.mla_attention import mla_decode_attention_pallas
 from repro.models import mla, moe
 from repro.models.params import init_params
-from repro.models.transformer import model_for
 from repro.serving.engine import ServeEngine
-from repro.serving.pool import KVCachePool, PagedKVCachePool
+from repro.serving.pool import PagedKVCachePool
 from repro.serving.trace import zipf_trace
 
 SMOKE = "deepseek-v2-lite-smoke"
@@ -205,8 +204,8 @@ def test_sizing_counts_the_latent_row_and_the_held_experts():
 
 
 def test_engine_and_pools_take_latent_attention():
-    eng = ServeEngine(arch=SMOKE, num_slots=2, max_len=32, kv_layout="paged",
-                      page_size=8, num_pages=9, log=lambda *a, **k: None)
+    eng = ServeEngine(arch=SMOKE, num_slots=2, max_len=32, page_size=8,
+                      num_pages=9, log=lambda *a, **k: None)
     pool = eng.make_pool()
     lanes = mla.latent_lanes(eng.cfg)
     assert lanes == 128
@@ -214,16 +213,13 @@ def test_engine_and_pools_take_latent_attention():
     assert pool.cache["route_counts"].shape == (eng.cfg.num_experts,)
     assert "k" not in pool.cache and "v" not in pool.cache
     assert isinstance(pool, PagedKVCachePool)
-    with pytest.raises(NotImplementedError, match="paged"):
-        KVCachePool(model_for(eng.cfg), 2, 32)
 
 
 @pytest.mark.parametrize("kernel", ["gather", "pallas"])
 def test_engine_serves_and_counts_routing(kernel):
     cfg = smoke_config(SMOKE)
     eng = ServeEngine(arch=SMOKE, num_slots=3, max_len=48, seed=0,
-                      kv_layout="paged", kv_kernel=kernel,
-                      log=lambda *a, **k: None)
+                      kv_kernel=kernel, log=lambda *a, **k: None)
     reqs = zipf_trace(5, cfg.vocab_size, max_prompt=16, max_new=6, seed=1)
     chunked = eng.run(reqs, prefill_chunk=4)
     blocking = eng.run(reqs, prefill_chunk=0)

@@ -1,5 +1,6 @@
-"""Continuous-batching serving engine: KV pool slot lifecycle, scheduler
-determinism, and the static/continuous equivalence + efficiency contracts."""
+"""Continuous-batching serving engine: scheduler determinism, the
+static/continuous equivalence + efficiency contracts, and the engine's
+greedy streams against an independent scalar-index decode reference."""
 
 import jax
 import jax.numpy as jnp
@@ -7,9 +8,8 @@ import numpy as np
 import pytest
 
 from repro.configs import smoke_config
-from repro.models.params import init_params
 from repro.models.transformer import model_for
-from repro.serving import (KVCachePool, PoolExhausted, Request, ServeEngine,
+from repro.serving import (PagedKVCachePool, Request, ServeEngine,
                            uniform_trace, zipf_trace)
 from repro.training.steps import build_decode_step, build_prefill_step
 
@@ -23,63 +23,12 @@ def engine():
 
 
 # ---------------------------------------------------------------------------
-# KVCachePool
-
-
-def _model():
-    return model_for(smoke_config("deepseek-7b"), remat="none")
-
-
-def test_pool_alloc_exhaustion_and_reuse():
-    pool = KVCachePool(_model(), num_slots=3, max_len=16)
-    s0, s1, s2 = pool.alloc(), pool.alloc(), pool.alloc()
-    assert sorted((s0, s1, s2)) == [0, 1, 2]
-    assert pool.num_free == 0
-    with pytest.raises(PoolExhausted):
-        pool.alloc()
-    pool.free(s1)
-    assert pool.num_free == 1
-    assert pool.alloc() == s1          # freed slots are reissued
-    with pytest.raises(PoolExhausted):
-        pool.alloc()
-
-
-def test_pool_double_free_and_range_rejected():
-    pool = KVCachePool(_model(), num_slots=2, max_len=8)
-    s = pool.alloc()
-    pool.free(s)
-    with pytest.raises(ValueError):
-        pool.free(s)
-    with pytest.raises(ValueError):
-        pool.free(99)
+# The pool
 
 
 def test_pool_rejects_unservable_family():
     with pytest.raises(NotImplementedError):
-        KVCachePool(model_for(smoke_config("xlstm-1.3b")), 2, 8)
-
-
-def test_pool_insert_sets_slot_length():
-    model = _model()
-    params = init_params(model.param_table(), jax.random.PRNGKey(0))
-    pool = KVCachePool(model, num_slots=3, max_len=32)
-    toks = jnp.ones((1, 5), jnp.int32)
-    _, cache = model.prefill(params, {"tokens": toks}, None)
-    slot = pool.alloc()
-    pool.insert(slot, cache)
-    assert pool.lengths[slot] == 5
-    assert int(np.asarray(pool.cache["index"])[slot]) == 5
-    # inserted keys land at positions [0, 5) of that slot only
-    k = np.asarray(pool.cache["k"], np.float32)
-    assert np.abs(k[:, slot, :5]).sum() > 0
-    assert np.abs(k[:, slot, 5:]).sum() == 0
-    other = [i for i in range(3) if i != slot]
-    assert np.abs(k[:, other]).sum() == 0
-    with pytest.raises(ValueError, match="max_len"):
-        big = {kk: jnp.zeros((model.cfg.num_layers, 1, 33,
-                              model.cfg.num_kv_heads, model.cfg.head_dim))
-               for kk in ("k", "v")}
-        pool.insert(slot, big)
+        PagedKVCachePool(model_for(smoke_config("xlstm-1.3b")), 2, 8)
 
 
 # ---------------------------------------------------------------------------
@@ -117,16 +66,11 @@ def test_prompt_longer_than_pool_rejected(engine):
         engine.run(bad)
 
 
-def test_continuous_matches_static_path_for_uniform_requests(engine):
-    """The keystone equivalence: slot-wise continuous decode must produce
-    token-identical output to the original scalar-index static path."""
-    n, plen, nnew = 4, 16, 8
-    reqs = uniform_trace(n, engine.cfg.vocab_size, prompt_len=plen,
-                         max_new=nnew, seed=11)
-    cont = engine.run(reqs, policy="continuous")
-
-    # reference: batched prefill + scalar-index decode (launch/serve.py's
-    # pre-engine behaviour), cache padded to the pool's max_len
+def _scalar_decode_reference(engine, reqs, nnew):
+    """Greedy tokens ``(len(reqs), nnew)`` from batched prefill +
+    scalar-index decode (launch/serve.py's pre-engine behaviour), cache
+    padded to the pool's max_len: no pool, no page table, no slot-wise
+    step.  ``reqs`` share one prompt length."""
     model, params = engine.model, engine.params
     prefill = jax.jit(build_prefill_step(model))
     decode = jax.jit(build_decode_step(model), donate_argnums=(1,))
@@ -142,10 +86,48 @@ def test_continuous_matches_static_path_for_uniform_requests(engine):
         logits, cache = decode(params, cache, t)
         t = jnp.argmax(logits[:, -1], -1).astype(jnp.int32)[:, None]
         ref.append(np.asarray(t))
-    ref_tokens = np.concatenate(ref, axis=1)
+    return np.concatenate(ref, axis=1)
 
+
+def test_continuous_matches_static_path_for_uniform_requests(engine):
+    """The keystone equivalence: slot-wise continuous decode must produce
+    token-identical output to the original scalar-index static path."""
+    n, plen, nnew = 4, 16, 8
+    reqs = uniform_trace(n, engine.cfg.vocab_size, prompt_len=plen,
+                         max_new=nnew, seed=11)
+    cont = engine.run(reqs, policy="continuous")
     got = np.stack([r.tokens for r in cont.results])
-    np.testing.assert_array_equal(got, ref_tokens)
+    np.testing.assert_array_equal(
+        got, _scalar_decode_reference(engine, reqs, nnew))
+
+
+_REFERENCE_ENGINES: dict = {}
+
+
+@pytest.mark.parametrize("prefill_chunk", [None, 0],
+                         ids=["chunked", "blocking"])
+@pytest.mark.parametrize("arch", ["deepseek-7b-smoke",
+                                  "mistral-large-123b-smoke",
+                                  "granite-moe-3b-a800m-smoke"],
+                         ids=["dense-mha", "dense-gqa", "moe"])
+def test_paged_engine_matches_scalar_decode_reference(arch, prefill_chunk):
+    """Dense MHA, dense GQA and MoE: the engine's greedy streams through
+    the page pool, prompts ingested in chunks or whole, under both
+    scheduling policies, are the scalar-index reference's token for
+    token."""
+    if arch not in _REFERENCE_ENGINES:
+        _REFERENCE_ENGINES[arch] = ServeEngine(
+            arch=arch, num_slots=4, max_len=64, seed=0, page_size=16,
+            log=lambda *a, **k: None)
+    engine = _REFERENCE_ENGINES[arch]
+    n, plen, nnew = 4, 24, 10
+    reqs = uniform_trace(n, engine.cfg.vocab_size, prompt_len=plen,
+                         max_new=nnew, seed=5)
+    ref = _scalar_decode_reference(engine, reqs, nnew)
+    for policy in ("continuous", "static"):
+        stats = engine.run(reqs, policy=policy, prefill_chunk=prefill_chunk)
+        got = np.stack([r.tokens for r in stats.results])
+        np.testing.assert_array_equal(got, ref)
 
 
 def test_static_policy_same_tokens_fewer_shared_steps(engine):
@@ -171,18 +153,21 @@ def test_tuner_serve_branch_sizes_pool():
     cfg = get_config("deepseek-7b-smoke")
     shape = ShapeConfig("d", 128, 8, "decode")
     plan = tune(cfg, shape, get_target("local:cpu"))
-    assert plan.serve_slots == 8 and plan.serve_max_len == 128
-    assert "serve_pool" in plan.napkin
+    assert plan.serve_max_len == 128
+    # the requested batch's worst case fits: 8 slots x 128 tokens of pages
+    # plus the junk page
+    assert plan.serve_num_pages == 8 * 128 // plan.serve_page_size + 1
+    assert "serve_pool_paged" in plan.napkin
     # plan roundtrips with the new fields
     from repro.core.plan import DeploymentPlan
     again = DeploymentPlan.from_json(plan.to_json())
-    assert again.serve_slots == 8 and again.serve_max_len == 128
+    assert again.serve_max_len == 128
+    assert again.serve_num_pages == plan.serve_num_pages
 
     # a giant request against the full model must be HBM-capped
     big = ShapeConfig("d", 32768, 4096, "decode")
     plan_big = tune(get_config("deepseek-7b"), big, get_target("local:cpu"))
-    assert plan_big.serve_slots < 4096
-    assert any("capped" in n for n in plan_big.notes)
+    assert plan_big.serve_num_pages < 4096 * 32768 // 16 + 1
 
 
 def test_engine_rejects_cacheless_family():

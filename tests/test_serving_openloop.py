@@ -30,7 +30,7 @@ def engine_for(slots=SLOTS):
     if key not in _ENGINES:
         _ENGINES[key] = ServeEngine(
             arch=ARCH, target="local:cpu", num_slots=slots, max_len=MAX_LEN,
-            seed=0, kv_layout="contiguous", log=lambda *a, **k: None)
+            seed=0, log=lambda *a, **k: None)
     return _ENGINES[key]
 
 

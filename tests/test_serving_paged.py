@@ -1,8 +1,8 @@
 """Paged KV cache: page allocator lifecycle, fragmentation/exhaustion,
-preemption-and-resume determinism, per-request sampling, and the keystone
-equivalence — paged and contiguous layouts produce token-identical output
-on the same mixed-length traces, while paged admits strictly more
-concurrent requests under the same tuner HBM budget."""
+preemption-and-resume determinism, per-request sampling, the equivalence
+of every page size on the same mixed-length traces, the fused kernel
+against the gather path, and the plan each benchmark cell's engine
+reads."""
 
 import sys
 from pathlib import Path
@@ -12,14 +12,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+ROOT = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(ROOT))                  # bench/, for its configs
 sys.path.insert(0, str(Path(__file__).parent))
 from _hypothesis_compat import given, settings, strategies as st  # noqa: E402
 
 from repro.configs import smoke_config
 from repro.models.params import init_params
 from repro.models.transformer import model_for
-from repro.serving import (KVCachePool, PagedKVCachePool, PoolExhausted,
-                           Request, ServeEngine, zipf_trace)
+from repro.serving import (PagedKVCachePool, PoolExhausted, Request,
+                           ServeEngine, zipf_trace)
 
 ARCH = "deepseek-7b-smoke"
 SLOTS, MAX_LEN = 4, 64
@@ -27,17 +29,22 @@ SLOTS, MAX_LEN = 4, 64
 _ENGINES: dict = {}
 
 
-def engine_for(layout, page_size=0, num_pages=0, slots=SLOTS,
-               max_len=MAX_LEN, target="local:cpu", kv_kernel="auto"):
+def engine_for(page_size=0, num_pages=0, slots=SLOTS, max_len=MAX_LEN,
+               target="local:cpu", kv_kernel="auto"):
     """Engines are expensive (jit); share them across tests by config."""
-    key = (layout, page_size, num_pages, slots, max_len, target, kv_kernel)
+    key = (page_size, num_pages, slots, max_len, target, kv_kernel)
     if key not in _ENGINES:
         _ENGINES[key] = ServeEngine(
             arch=ARCH, target=target, num_slots=slots, max_len=max_len,
-            seed=0, kv_layout=layout, page_size=page_size,
-            num_pages=num_pages, kv_kernel=kv_kernel,
-            log=lambda *a, **k: None)
+            seed=0, page_size=page_size, num_pages=num_pages,
+            kv_kernel=kv_kernel, log=lambda *a, **k: None)
     return _ENGINES[key]
+
+
+def roomy():
+    """The reference engine: one MAX_LEN page per slot, so a slot's KV
+    is one run and the pool never preempts."""
+    return engine_for(page_size=MAX_LEN)
 
 
 def _model():
@@ -120,30 +127,23 @@ def test_paged_pool_exhaustion_and_errors():
     pool = PagedKVCachePool(model, num_slots=2, max_len=32, page_size=8,
                             num_pages=3)          # 2 usable pages
     s0, s1 = pool.alloc(), pool.alloc()
+    assert sorted((s0, s1)) == [0, 1] and pool.num_free == 0
     with pytest.raises(PoolExhausted, match="slots"):
         pool.alloc()
+    pool.free(s1)
+    assert pool.num_free == 1
+    assert pool.alloc() == s1           # a freed slot is the next reissued
     pool.insert(s0, _prefill_cache(model, params, 16))   # takes both pages
     with pytest.raises(PoolExhausted, match="pages"):
         pool.insert(s1, _prefill_cache(model, params, 8))
     with pytest.raises(ValueError, match="max_len"):
         pool.insert(s1, _prefill_cache(model, params, 33))
-    # free-mask error paths: same errors as the contiguous pool, O(1) now
+    # free-mask error paths, O(1)
     pool.free(s0)
     with pytest.raises(ValueError, match="already free"):
         pool.free(s0)
     with pytest.raises(ValueError, match="out of range"):
         pool.free(99)
-
-
-def test_contiguous_pool_free_mask_same_errors():
-    pool = KVCachePool(_model(), num_slots=2, max_len=8)
-    s = pool.alloc()
-    pool.free(s)
-    with pytest.raises(ValueError, match="already free"):
-        pool.free(s)
-    with pytest.raises(ValueError, match="out of range"):
-        pool.free(99)
-    assert pool.alloc() == s            # LIFO reissue preserved
 
 
 def test_paged_insert_scatters_through_page_table():
@@ -152,6 +152,8 @@ def test_paged_insert_scatters_through_page_table():
     pool = PagedKVCachePool(model, num_slots=2, max_len=32, page_size=8)
     s0 = pool.alloc()
     pool.insert(s0, _prefill_cache(model, params, 12))
+    assert pool.lengths[s0] == 12
+    assert int(np.asarray(pool.cache["index"])[s0]) == 12
     k = np.asarray(pool.cache["k"], np.float32)
     p0, p1 = pool.page_table[s0, 0], pool.page_table[s0, 1]
     assert np.abs(k[:, p0]).sum() > 0                  # page fully written
@@ -163,34 +165,7 @@ def test_paged_insert_scatters_through_page_table():
 
 
 # ---------------------------------------------------------------------------
-# Engine equivalence: paged == contiguous, token-identical
-
-
-def test_paged_matches_contiguous_on_mixed_length_trace():
-    ec = engine_for("contiguous")
-    ep = engine_for("paged", page_size=16)
-    reqs = zipf_trace(12, ec.cfg.vocab_size, max_prompt=24, max_new=32,
-                      seed=3)
-    a = ec.run(reqs, policy="continuous")
-    b = ep.run(reqs, policy="continuous")
-    assert _tokens(a) == _tokens(b)
-    assert a.generated_tokens == b.generated_tokens
-    # and under gang scheduling too
-    sa = ec.run(reqs, policy="static")
-    sb = ep.run(reqs, policy="static")
-    assert _tokens(sa) == _tokens(sb) == _tokens(a)
-
-
-def test_paged_matches_contiguous_moe_family():
-    """The page table rides the MoE backbone's scan (aux-loss carry) too."""
-    ec = ServeEngine(arch="granite-moe-3b-a800m-smoke", num_slots=3,
-                     max_len=48, seed=0, log=lambda *a, **k: None)
-    ep = ServeEngine(arch="granite-moe-3b-a800m-smoke", num_slots=3,
-                     max_len=48, seed=0, kv_layout="paged", page_size=8,
-                     log=lambda *a, **k: None)
-    reqs = zipf_trace(6, ec.cfg.vocab_size, max_prompt=16, max_new=10,
-                      seed=1)
-    assert _tokens(ec.run(reqs)) == _tokens(ep.run(reqs))
+# Engine equivalence: every page size decodes the same tokens
 
 
 @pytest.mark.parametrize("arch", ["granite-moe-3b-a800m-smoke",
@@ -210,8 +185,7 @@ def test_moe_serving_matches_capacity_dispatch_where_none_drop(
         return y, jnp.zeros((cfg.num_experts,), jnp.int32)
 
     kw = dict(arch=_f32_arch(monkeypatch, arch), num_slots=3, max_len=48,
-              seed=0, kv_layout="paged", page_size=8,
-              log=lambda *a, **k: None)
+              seed=0, page_size=8, log=lambda *a, **k: None)
     reqs = zipf_trace(6, smoke_config(arch).vocab_size, max_prompt=16,
                       max_new=10, seed=1)
     dropless = _tokens(ServeEngine(**kw).run(reqs, prefill_chunk=4))
@@ -223,10 +197,10 @@ def test_moe_serving_matches_capacity_dispatch_where_none_drop(
 @given(page_size=st.sampled_from([8, 16, 32]),
        trace_seed=st.integers(min_value=0, max_value=30))
 def test_paged_equivalence_sweep(page_size, trace_seed):
-    """Hypothesis sweep: for any page size and mixed-length trace, the two
-    memory layouts decode token-identical streams."""
-    ec = engine_for("contiguous")
-    ep = engine_for("paged", page_size=page_size)
+    """Hypothesis sweep: for any page size and mixed-length trace, the
+    pool decodes the stream the one-page-per-slot reference does."""
+    ec = roomy()
+    ep = engine_for(page_size=page_size)
     reqs = zipf_trace(6, ec.cfg.vocab_size, max_prompt=16, max_new=12,
                       seed=trace_seed)
     assert _tokens(ec.run(reqs)) == _tokens(ep.run(reqs))
@@ -239,8 +213,8 @@ def test_paged_equivalence_sweep(page_size, trace_seed):
 def test_preemption_and_resume_deterministic_and_equivalent():
     """Scarce pages force mid-decode preemptions; resumed requests must
     re-generate exactly the stream an uninterrupted run produces."""
-    ec = engine_for("contiguous")
-    scarce = engine_for("paged", page_size=8, num_pages=13)  # 96 KV tokens
+    ec = engine_for(page_size=8)                     # never preempts
+    scarce = engine_for(page_size=8, num_pages=13)   # 96 KV tokens
     reqs = zipf_trace(12, ec.cfg.vocab_size, max_prompt=24, max_new=32,
                       seed=3)
     ref = ec.run(reqs, policy="continuous")
@@ -260,15 +234,15 @@ def test_pool_exhausted_on_page_starvation_mid_decode():
     Without an eos the worst case is certain and rejected before any work
     (completed results are never thrown away); with an eos the request is
     admitted optimistically and starves mid-decode."""
-    tiny = engine_for("paged", page_size=8, num_pages=3, slots=2)
+    tiny = engine_for(page_size=8, num_pages=3, slots=2)
     reqs = zipf_trace(2, tiny.cfg.vocab_size, max_prompt=24, max_new=40,
                       seed=7)
     with pytest.raises(PoolExhausted):
         tiny.run(reqs)
 
     hopeful = ServeEngine(arch=ARCH, num_slots=2, max_len=64, seed=0,
-                          kv_layout="paged", page_size=8, num_pages=3,
-                          eos_id=-1, log=lambda *a, **k: None)
+                          page_size=8, num_pages=3, eos_id=-1,
+                          log=lambda *a, **k: None)
     req = Request(rid=0, prompt=np.arange(1, 17, dtype=np.int32),
                   max_new_tokens=40)
     with pytest.raises(PoolExhausted, match="mid-decode"):
@@ -278,7 +252,7 @@ def test_pool_exhausted_on_page_starvation_mid_decode():
 def test_oversized_request_rejected_before_any_work_is_discarded():
     """A trace mixing servable requests with one that can never fit must
     fail fast — not after the servable ones already ran."""
-    tiny = engine_for("paged", page_size=8, num_pages=3, slots=2)
+    tiny = engine_for(page_size=8, num_pages=3, slots=2)
     good = zipf_trace(3, tiny.cfg.vocab_size, max_prompt=8, max_new=4,
                       seed=0)                     # <= 11 resident tokens
     bad = [Request(rid=9, prompt=np.ones((16,), np.int32),
@@ -289,7 +263,7 @@ def test_oversized_request_rejected_before_any_work_is_discarded():
 
 def test_top_k_beyond_sampler_cap_rejected():
     from repro.serving.sampling import K_CAP
-    ec = engine_for("contiguous")
+    ec = engine_for(page_size=16)
     bad = zipf_trace(1, ec.cfg.vocab_size, max_prompt=8, max_new=4, seed=0,
                      temperature=1.0, top_k=K_CAP + 1)
     with pytest.raises(ValueError, match="top_k"):
@@ -301,21 +275,21 @@ def test_top_k_beyond_sampler_cap_rejected():
 
 
 def test_sampling_deterministic_and_layout_agnostic():
-    ec = engine_for("contiguous")
-    ep = engine_for("paged", page_size=16)
+    ec = roomy()
+    ep = engine_for(page_size=16)
     reqs = zipf_trace(6, ec.cfg.vocab_size, max_prompt=16, max_new=12,
                       seed=5, temperature=0.8, top_k=8)
     s1 = ep.run(reqs)
     s2 = ep.run(reqs)
     assert _tokens(s1) == _tokens(s2)          # deterministic replay
     sc = ec.run(reqs)
-    assert _tokens(s1) == _tokens(sc)          # layout-independent draws
+    assert _tokens(s1) == _tokens(sc)          # page-size-independent draws
     for r in s1.results:
         assert all(0 <= t < ec.cfg.vocab_size for t in r.tokens)
 
 
 def test_top_k_one_is_greedy_and_temperature_changes_tokens():
-    ec = engine_for("contiguous")
+    ec = engine_for(page_size=16)
     greedy = zipf_trace(6, ec.cfg.vocab_size, max_prompt=16, max_new=12,
                         seed=5)
     k1 = zipf_trace(6, ec.cfg.vocab_size, max_prompt=16, max_new=12,
@@ -341,7 +315,7 @@ def test_kernel_on_token_identical_to_gather_engine_level(monkeypatch):
     with kv_kernel="pallas" and kv_kernel="gather" in float32 yields
     identical token streams under both schedulers."""
     kw = dict(arch=_f32_arch(monkeypatch, ARCH), num_slots=SLOTS,
-              max_len=MAX_LEN, seed=0, kv_layout="paged", page_size=16,
+              max_len=MAX_LEN, seed=0, page_size=16,
               log=lambda *a, **k: None)
     ep = ServeEngine(**kw)
     ek = ServeEngine(kv_kernel="pallas", **kw)
@@ -364,7 +338,7 @@ def test_kernel_survives_preemption_and_junk_rows(monkeypatch):
     mask in-kernel.  In float32 the token streams and the preemption
     counts match the gather path's."""
     kw = dict(arch=_f32_arch(monkeypatch, ARCH), num_slots=SLOTS,
-              max_len=MAX_LEN, seed=0, kv_layout="paged", page_size=8,
+              max_len=MAX_LEN, seed=0, page_size=8,
               num_pages=13, log=lambda *a, **k: None)
     scarce = ServeEngine(kv_kernel="gather", **kw)
     scarce_k = ServeEngine(kv_kernel="pallas", **kw)
@@ -383,8 +357,8 @@ def test_kernel_on_token_identical_to_gather_moe_family(monkeypatch):
     """The MoE backbone hands its decode layers the same carried pool:
     kernel-on and gather streams match there too, in float32."""
     kw = dict(arch=_f32_arch(monkeypatch, "granite-moe-3b-a800m"),
-              num_slots=3, max_len=48, seed=0, kv_layout="paged",
-              page_size=8, log=lambda *a, **k: None)
+              num_slots=3, max_len=48, seed=0, page_size=8,
+              log=lambda *a, **k: None)
     eg = ServeEngine(kv_kernel="gather", **kw)
     ek = ServeEngine(kv_kernel="pallas", **kw)
     reqs = zipf_trace(6, eg.cfg.vocab_size, max_prompt=16, max_new=10,
@@ -402,7 +376,7 @@ def test_kernel_bf16_logits_as_close_to_float32_as_gather(arch):
     kernel is held to the gather path's error, not to the gather path.
     (An inactive slot's logits are discarded: the kernel attends nothing
     there, the gather path a uniform average of the junk page.)"""
-    from repro.training.steps import build_decode_step_slots_paged
+    from repro.training.steps import build_decode_step_slots
     cfg = smoke_config(arch)
     model = model_for(cfg, remat="none")
     params = init_params(model.param_table(), jax.random.PRNGKey(0))
@@ -420,7 +394,7 @@ def test_kernel_bf16_logits_as_close_to_float32_as_gather(arch):
     live = [s for s in range(4) if s != idle]
 
     def logits(model, params, cache, use_kernel):
-        step = jax.jit(build_decode_step_slots_paged(model,
+        step = jax.jit(build_decode_step_slots(model,
                                                      use_kernel=use_kernel))
         return np.asarray(step(params, cache, *args)[0], np.float32)[live]
 
@@ -438,10 +412,14 @@ def test_kernel_bf16_logits_as_close_to_float32_as_gather(arch):
 
 
 def test_contiguous_engine_rejects_pallas_kv_kernel():
-    with pytest.raises(ValueError, match="kv_kernel"):
-        ServeEngine(arch=ARCH, num_slots=2, max_len=32, seed=0,
-                    kv_layout="contiguous", kv_kernel="pallas",
-                    log=lambda *a, **k: None)
+    """The serving pool is paged: any other kv_layout is refused, with
+    the fused kernel or without it."""
+    for layout, kernel in (("contiguous", "pallas"), ("contiguous", "auto"),
+                           ("", "gather")):
+        with pytest.raises(ValueError, match="kv_layout"):
+            ServeEngine(arch=ARCH, num_slots=2, max_len=32, seed=0,
+                        kv_layout=layout, kv_kernel=kernel,
+                        log=lambda *a, **k: None)
 
 
 # ---------------------------------------------------------------------------
@@ -469,8 +447,8 @@ def test_full_slot_extra_write_routes_to_junk_not_shared_page():
     pool.page_table[s1, 0] = last
     pool.page_refs[last] += 1
 
-    from repro.training.steps import build_decode_step_slots_paged
-    step = jax.jit(build_decode_step_slots_paged(model))  # non-donating
+    from repro.training.steps import build_decode_step_slots
+    step = jax.jit(build_decode_step_slots(model))  # non-donating
     cache = dict(pool.cache)
     before_k = np.asarray(cache["k"], np.float32).copy()
     before_v = np.asarray(cache["v"], np.float32).copy()
@@ -488,44 +466,7 @@ def test_full_slot_extra_write_routes_to_junk_not_shared_page():
 
 
 # ---------------------------------------------------------------------------
-# Budget: tuner sizing + admit-more acceptance
-
-
-def _tight_target():
-    """CPU target whose budget affords ~3 contiguous worst-case slots."""
-    from repro.core.target import TARGETS, TargetSpec, register
-    from repro.core.tuning import param_count_estimate
-
-    name = "test:serve-tight"
-    if name not in TARGETS:
-        from repro.core.tuning import kv_bytes_per_token
-        cfg = smoke_config("deepseek-7b")
-        hbm = (2 * param_count_estimate(cfg) +
-               3.5 * kv_bytes_per_token(cfg) * MAX_LEN) / 0.85
-        register(TargetSpec(
-            name=name, chip="cpu", mesh_shape=(1,), mesh_axes=("data",),
-            peak_flops=5e10, hbm_bw=2e10, hbm_bytes=hbm, ici_bw=1e9,
-            scheduler="local", kernels="reference"))
-    return name
-
-
-def test_paged_admits_more_concurrent_requests_same_budget():
-    """Acceptance: same tuner HBM budget, same Zipf trace — the paged
-    layout holds strictly more requests in flight than contiguous."""
-    tgt = _tight_target()
-    ec = engine_for("contiguous", slots=8, target=tgt)
-    ep = engine_for("paged", slots=8, target=tgt)
-    assert ec.num_slots < 8                      # tuner capped worst-case
-    reqs = zipf_trace(16, ec.cfg.vocab_size, max_prompt=32, max_new=32,
-                      seed=0)
-    a = ec.run(reqs, policy="continuous")
-    b = ep.run(reqs, policy="continuous")
-    assert b.peak_active > a.peak_active
-    assert _tokens(a) == _tokens(b)              # same tokens, more overlap
-    # the paged pool spends (at most) the same order of HBM
-    cont_bytes = ec.num_slots * ec.max_len
-    paged_bytes = ep.num_pages * ep.page_size
-    assert paged_bytes <= cont_bytes * 1.25
+# Budget: tuner sizing
 
 
 def test_tuner_sizes_paged_pool_and_reports_delta():
@@ -539,21 +480,18 @@ def test_tuner_sizes_paged_pool_and_reports_delta():
                 get_target("local:cpu"))
     assert plan.serve_page_size == 16
     assert plan.serve_num_pages > 1
-    for key in ("kv_pages", "page_size", "serve_pool_paged",
-                "serve_capacity_delta"):
+    for key in ("kv_pages", "page_size", "serve_pool_paged"):
         assert key in plan.napkin, key
     assert "serve kv pages" in plan.report()
     again = DeploymentPlan.from_json(plan.to_json())
     assert again.serve_page_size == plan.serve_page_size
     assert again.serve_num_pages == plan.serve_num_pages
 
-    # a budget-bound target buys fewer pages than the worst case, and the
-    # napkin quotes the paged capacity win over contiguous
+    # a budget-bound target buys fewer pages than the worst case
     big = ShapeConfig("d", 32768, 4096, "decode")
     plan_big = tune(get_config("deepseek-7b"), big, get_target("local:cpu"))
     worst = 4096 * (32768 // 16) + 1
     assert plan_big.serve_num_pages < worst
-    assert "serve_capacity_delta" in plan_big.napkin
 
 
 # ---------------------------------------------------------------------------
@@ -590,7 +528,7 @@ def test_paged_decode_step_writes_only_the_new_tokens(use_kernel):
     K/V lands at (every layer, its page, its offset) — the first token of
     a fresh page included — an inactive slot's dead write goes to the
     junk page 0, and every other position is bit-identical to before."""
-    from repro.training.steps import build_decode_step_slots_paged
+    from repro.training.steps import build_decode_step_slots
     model = _model()
     params = init_params(model.param_table(), jax.random.PRNGKey(0))
     pool = _random_pool(model)
@@ -601,7 +539,7 @@ def test_paged_decode_step_writes_only_the_new_tokens(use_kernel):
         pool.set_length(slot, n)
     index = jnp.asarray([lengths.get(s, 0) for s in range(3)], jnp.int32)
     cache = dict(pool.cache, index=index)
-    step = jax.jit(build_decode_step_slots_paged(model,
+    step = jax.jit(build_decode_step_slots(model,
                                                  use_kernel=use_kernel))
     active = jnp.asarray([s != idle for s in range(3)], jnp.int32)
     _, new = step(params, cache, jnp.ones((3, 1), jnp.int32), active,
@@ -622,7 +560,7 @@ def test_paged_chunk_step_writes_only_the_chunk():
     """One chunk step (16 bucketed tokens, 12 valid, at offset 8 of a slot
     holding 20): the chunk's K/V land at its positions' (page, offset) in
     every layer and nothing else in the pool moves."""
-    from repro.training.steps import build_prefill_chunk_step_paged
+    from repro.training.steps import build_prefill_chunk_step
     model = _model()
     params = init_params(model.param_table(), jax.random.PRNGKey(0))
     pool = _random_pool(model)
@@ -630,7 +568,7 @@ def test_paged_chunk_step_writes_only_the_chunk():
     slot = pool.alloc()
     pool.reserve_prefix(slot, 20)
     cache = dict(pool.cache)
-    step = jax.jit(build_prefill_chunk_step_paged(model),
+    step = jax.jit(build_prefill_chunk_step(model),
                    static_argnums=(6,))
     toks = jnp.arange(1, 17, dtype=jnp.int32)[None]
     _, new = step(params, cache, toks, jnp.int32(slot), jnp.int32(8),
@@ -641,3 +579,52 @@ def test_paged_chunk_step_writes_only_the_chunk():
         _changed_only_at(np.asarray(cache[name], np.float32),
                          np.asarray(new[name], np.float32), written)
     assert int(new["index"][slot]) == 20
+
+
+# ---------------------------------------------------------------------------
+# The plan each benchmark cell's engine reads, pinned
+
+
+# each cell's driver and its traffic's (slots, max_len), and the plan
+# fields the engine reads at them on one v5e
+PINNED_PLANS = {
+    "deepseek-7b": ("serve", 8, 2432, dict(
+        serve_max_len=2432, serve_page_size=16, serve_num_pages=99,
+        serve_prefill_chunk=128, serve_prefix_cache_pages=24,
+        serve_kv_kernel="pallas", serve_spec_k=0, serve_slo_ttft_steps=24,
+        serve_slo_e2e_steps=1240)),
+    "stablelm-2-1.6b": ("serve", 6, 4096, dict(
+        serve_max_len=4096, serve_page_size=16, serve_num_pages=1537,
+        serve_prefill_chunk=128, serve_prefix_cache_pages=384,
+        serve_kv_kernel="pallas", serve_spec_k=0, serve_slo_ttft_steps=36,
+        serve_slo_e2e_steps=2084)),
+    "deepseek-v2-lite": ("serve_mla", 64, 4096, dict(
+        serve_max_len=4096, serve_page_size=16, serve_num_pages=15156,
+        serve_prefill_chunk=128, serve_prefix_cache_pages=3788,
+        serve_kv_kernel="pallas", serve_spec_k=0, serve_slo_ttft_steps=36,
+        serve_slo_e2e_steps=2084)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PINNED_PLANS))
+def test_serving_plan_pinned(name):
+    """The tuner's plan for each benchmark cell's deployment, registered
+    from ``bench/configs`` as the cell's driver registers it, holds these
+    literal values in every field the engine reads."""
+    import importlib
+    import json
+    from unittest import mock
+    import repro.serving.engine as engine_mod
+
+    config = json.loads((ROOT / "bench" / "configs" /
+                         f"{name}.json").read_text())
+    drv, slots, max_len, want = PINNED_PLANS[name]
+    driver = importlib.import_module(f"bench.drivers.{drv}")
+    with mock.patch.object(engine_mod, "init_params",
+                           lambda table, rng: None):
+        engine = ServeEngine(arch=driver.program_arch(config),
+                             target="local:tpu-v5e", num_slots=slots,
+                             max_len=max_len, seed=0,
+                             log=lambda *a, **k: None)
+    assert {f: getattr(engine.plan, f) for f in want} == want
+    assert engine.num_slots == slots

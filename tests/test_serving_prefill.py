@@ -1,5 +1,5 @@
 """Chunked-prefill pipeline: token-identity with blocking prefill (swept
-over chunk sizes, KV layouts, and preemption resumes), scheduler- and
+over chunk sizes, page sizes, and preemption resumes), scheduler- and
 router-level overlap (a replica mid-prefill keeps serving decode ticks),
 the deterministic TTFT step proxy, the long-prompt trace preset, and the
 tuner's chunk-size + napkin plumbing."""
@@ -24,14 +24,13 @@ SLOTS, MAX_LEN = 4, 64
 _ENGINES: dict = {}
 
 
-def engine_for(layout="contiguous", page_size=0, num_pages=0, slots=SLOTS,
-               max_len=MAX_LEN):
+def engine_for(page_size=0, num_pages=0, slots=SLOTS, max_len=MAX_LEN):
     """Engines are expensive (jit); share them across tests by config."""
-    key = (layout, page_size, num_pages, slots, max_len)
+    key = (page_size, num_pages, slots, max_len)
     if key not in _ENGINES:
         _ENGINES[key] = ServeEngine(
             arch=ARCH, num_slots=slots, max_len=max_len, seed=0,
-            kv_layout=layout, page_size=page_size, num_pages=num_pages,
+            page_size=page_size, num_pages=num_pages,
             log=lambda *a, **k: None)
     return _ENGINES[key]
 
@@ -55,7 +54,7 @@ def test_chunked_matches_blocking_across_chunk_sizes():
     for chunk in (4, 8, 16, 64):
         got = e.run(reqs, prefill_chunk=chunk)
         assert _tokens(got) == _tokens(ref), f"chunk={chunk}"
-    # no preemptions on a roomy contiguous pool: every prompt token was
+    # no preemptions on a roomy pool: every prompt token was
     # ingested through the chunk pipeline exactly once
     chunked = e.run(reqs, prefill_chunk=8)
     assert chunked.prefill_tokens == sum(len(r.prompt) for r in reqs)
@@ -73,12 +72,13 @@ def test_chunked_matches_blocking_moe_family():
 
 @settings(max_examples=6, deadline=None)
 @given(chunk=st.sampled_from([4, 8, 16, 32]),
-       layout=st.sampled_from(["contiguous", "paged"]),
+       page_size=st.sampled_from([16, MAX_LEN]),
        trace_seed=st.integers(min_value=0, max_value=30))
-def test_chunked_equivalence_sweep(chunk, layout, trace_seed):
-    """Hypothesis sweep: any chunk size x layout x mixed-length trace is
-    token-identical to the blocking full-prompt prefill."""
-    e = engine_for(layout, page_size=16 if layout == "paged" else 0)
+def test_chunked_equivalence_sweep(chunk, page_size, trace_seed):
+    """Hypothesis sweep: any chunk size x page size (MAX_LEN: one page per
+    slot) x mixed-length trace is token-identical to the blocking
+    full-prompt prefill."""
+    e = engine_for(page_size=page_size)
     reqs = zipf_trace(6, e.cfg.vocab_size, max_prompt=16, max_new=12,
                       seed=trace_seed)
     assert _tokens(e.run(reqs, prefill_chunk=chunk)) == \
@@ -90,7 +90,7 @@ def test_chunked_preemption_resume_equivalent():
     prompt+generated through the chunk pipeline — the resumed stream must
     match an uninterrupted blocking run exactly."""
     roomy = engine_for()
-    scarce = engine_for("paged", page_size=8, num_pages=13)  # 96 KV tokens
+    scarce = engine_for(page_size=8, num_pages=13)  # 96 KV tokens
     reqs = zipf_trace(12, roomy.cfg.vocab_size, max_prompt=24, max_new=32,
                       seed=3)
     ref = roomy.run(reqs, prefill_chunk=0)

@@ -16,9 +16,9 @@ sys.path.insert(0, str(Path(__file__).parent))
 sys.path.insert(0, str(Path(__file__).parent.parent / "benchmarks"))
 from _hypothesis_compat import given, settings, strategies as st  # noqa: E402
 
-from repro.serving import (KVCachePool, PagedKVCachePool, PrefixCache,
-                           ReplicaRouter, ServeEngine, prefix_key,
-                           prefix_replica, sharedprefix_trace)
+from repro.serving import (PagedKVCachePool, PrefixCache, ReplicaRouter,
+                           ServeEngine, prefix_key, prefix_replica,
+                           sharedprefix_trace)
 
 ARCH = "deepseek-7b-smoke"
 
@@ -32,7 +32,7 @@ def engine_for(page_size=16, num_pages=0, slots=6, max_len=64):
     if key not in _ENGINES:
         _ENGINES[key] = ServeEngine(
             arch=ARCH, num_slots=slots, max_len=max_len, seed=0,
-            kv_layout="paged", page_size=page_size, num_pages=num_pages,
+            page_size=page_size, num_pages=num_pages,
             log=lambda *a, **k: None)
     return _ENGINES[key]
 
@@ -241,15 +241,6 @@ def test_admission_reserves_only_cold_suffix():
     assert not pool.can_admit(len(pa), hit=None)
 
 
-def test_prefix_cache_requires_paged_layout():
-    with pytest.raises(ValueError, match="paged"):
-        PrefixCache(KVCachePool(model(), num_slots=2, max_len=32))
-    with pytest.raises(ValueError, match="paged"):
-        ServeEngine(arch=ARCH, num_slots=2, max_len=32,
-                    kv_layout="contiguous", prefix_cache=True,
-                    log=lambda *a, **k: None)
-
-
 # ---------------------------------------------------------------------------
 # Equivalence: cached == cold, bit-identical
 
@@ -321,28 +312,6 @@ def test_router_per_replica_caches_compose():
     assert hot.prefill_tokens_saved > 0
     assert hot.prefill_tokens + hot.prefill_tokens_saved == \
         cold.prefill_tokens
-
-
-def test_mixed_layout_fleet_applies_cache_to_paged_replicas_only():
-    """A documented paged+contiguous fleet must run with the per-run
-    prefix_cache override (paged replicas cache, contiguous ones do
-    not) instead of crashing on the contiguous pool."""
-    e_paged = engine_for()
-    e_cont = ServeEngine(arch=ARCH, num_slots=2, max_len=64, seed=0,
-                         kv_layout="contiguous", log=lambda *a, **k: None)
-    router = ReplicaRouter([e_paged, e_cont], policy="prefix_affinity",
-                           log=lambda *a, **k: None)
-    reqs = sharedprefix_trace(8, e_paged.cfg.vocab_size, seed=7)
-    cold = router.run(reqs)
-    hot = router.run(reqs, prefix_cache=True)      # must not raise
-    assert _tokens(hot) == _tokens(cold)
-    # and Build-level mixing composes the same way
-    mixed = ReplicaRouter.build(arch=ARCH, replicas=2,
-                                kv_layout="paged,contiguous", num_slots=2,
-                                max_len=64, prefix_cache=True,
-                                log=lambda *a, **k: None)
-    assert mixed.engines[0].prefix_cache
-    assert not mixed.engines[1].prefix_cache
 
 
 # ---------------------------------------------------------------------------

@@ -24,15 +24,15 @@ SLOTS, MAX_LEN = 4, 64
 _ENGINES: dict = {}
 
 
-def engine_for(layout="contiguous", page_size=0, num_pages=0, slots=SLOTS,
-               max_len=MAX_LEN, target="local:cpu", eos_id=None):
+def engine_for(page_size=0, num_pages=0, slots=SLOTS, max_len=MAX_LEN,
+               target="local:cpu", eos_id=None):
     """Engines are expensive (jit); share them across tests by config."""
-    key = (layout, page_size, num_pages, slots, max_len, target, eos_id)
+    key = (page_size, num_pages, slots, max_len, target, eos_id)
     if key not in _ENGINES:
         _ENGINES[key] = ServeEngine(
             arch=ARCH, target=target, num_slots=slots, max_len=max_len,
-            seed=0, kv_layout=layout, page_size=page_size,
-            num_pages=num_pages, eos_id=eos_id, log=lambda *a, **k: None)
+            seed=0, page_size=page_size, num_pages=num_pages,
+            eos_id=eos_id, log=lambda *a, **k: None)
     return _ENGINES[key]
 
 
@@ -45,7 +45,7 @@ def _tokens(stats):
 
 
 def _tight_target():
-    """CPU target whose budget affords ~3 contiguous worst-case slots."""
+    """CPU target whose budget holds ~3 worst-case requests' KV."""
     from repro.core.target import TARGETS, TargetSpec, register
     from repro.core.tuning import kv_bytes_per_token, param_count_estimate
 
@@ -93,7 +93,7 @@ def test_router_rejects_request_no_replica_can_ever_serve():
                         max_new_tokens=4)]
     with pytest.raises(ValueError, match="any replica"):
         router.run(too_long)
-    scarce = engine_for("paged", page_size=8, num_pages=3, slots=2)
+    scarce = engine_for(page_size=8, num_pages=3, slots=2)
     tiny_fleet = router_for([scarce, scarce], "least_loaded")
     fat = [Request(rid=0, prompt=np.ones((16,), np.int32),
                    max_new_tokens=40)]         # 55 resident > 16 capacity
@@ -133,23 +133,23 @@ def test_round_robin_skips_full_replicas():
 
 
 def test_least_loaded_picks_replica_with_most_free_tokens():
-    one = engine_for(slots=1)                  # 1 x 64 free tokens
-    four = engine_for(slots=4)                 # 4 x 64 free tokens
+    one = engine_for(slots=1)                  # 4 pages: 64 free tokens
+    four = engine_for(slots=4)                 # 16 pages: 256 free tokens
     router = router_for([one, one, four], "least_loaded")
     reqs = uniform_trace(3, one.cfg.vocab_size, prompt_len=8, max_new=4,
                          seed=0)
     a = router.run(reqs)
     # first pick is the roomiest replica (2); once it holds one request
-    # (3 x 64 free) it still beats the single-slot replicas (1 x 64), so
-    # everything lands there while slots remain
+    # (15 pages, 240 free tokens) it still beats the single-slot replicas
+    # (64), so everything lands there while slots remain
     assert a.replica_of[0] == 2
     assert all(idx == 2 for idx in a.replica_of.values())
 
 
 def test_least_loaded_balances_paged_fleet_by_free_pages():
     # two paged replicas with different page pools: the bigger pool wins
-    big = engine_for("paged", page_size=8, num_pages=13)   # 96 KV tokens
-    small = engine_for("paged", page_size=8, num_pages=5)  # 32 KV tokens
+    big = engine_for(page_size=8, num_pages=13)   # 96 KV tokens
+    small = engine_for(page_size=8, num_pages=5)  # 32 KV tokens
     router = router_for([small, big], "least_loaded")
     reqs = uniform_trace(1, big.cfg.vocab_size, prompt_len=8, max_new=4,
                          seed=1)
@@ -198,7 +198,7 @@ def test_starved_request_reroutes_instead_of_rejecting():
     """A request that solo-starves a scarce paged replica is evicted and
     completes on a roomier replica — with exactly the token stream an
     uninterrupted run on the roomy replica produces."""
-    scarce = engine_for("paged", page_size=8, num_pages=3, slots=2,
+    scarce = engine_for(page_size=8, num_pages=3, slots=2,
                         eos_id=-1)            # 16 KV tokens, optimistic
     roomy = engine_for(slots=2, eos_id=-1)    # fleet-uniform eos
     router = router_for([scarce, roomy], "round_robin")
@@ -221,8 +221,7 @@ def test_starved_request_with_no_roomier_replica_fails_fast():
     remaining generation, the router raises like the bare engine would —
     it must not grind one token per re-prefill bounce on the replica that
     just proved it cannot finish the request (optimistic eos bound)."""
-    scarce = engine_for("paged", page_size=8, num_pages=3, slots=2,
-                        eos_id=-1)
+    scarce = engine_for(page_size=8, num_pages=3, slots=2, eos_id=-1)
     router = router_for([scarce], "least_loaded")
     req = Request(rid=0, prompt=np.arange(1, 17, dtype=np.int32),
                   max_new_tokens=40)
@@ -238,7 +237,7 @@ def test_preempt_tie_break_by_rid_not_submission_order():
     """Two requests admitted in the same step that later both starve: the
     victim is the one with the higher request id, however the trace was
     ordered at submission (previously: whichever was inserted later)."""
-    eng = engine_for("paged", page_size=8, num_pages=5, slots=3)
+    eng = engine_for(page_size=8, num_pages=5, slots=3)
     mk = lambda rid: Request(rid=rid,
                              prompt=np.arange(1, 9, dtype=np.int32) + rid,
                              max_new_tokens=20)
@@ -272,24 +271,26 @@ def test_single_replica_router_token_identical_to_bare_engine():
 
 
 def test_mixed_layout_fleet_token_identical_to_single_engine():
-    """Routing across a paged + contiguous mix never changes tokens."""
-    ec = engine_for()
-    ep = engine_for("paged", page_size=16)
-    router = router_for([ep, ec], "least_loaded")
-    reqs = zipf_trace(10, ec.cfg.vocab_size, max_prompt=16, max_new=12,
+    """Routing across three replicas of different page sizes and pools
+    (one scarce enough to preempt) never changes tokens."""
+    e = engine_for()
+    scarce = engine_for(page_size=8, num_pages=13)  # 96 KV tokens
+    router = router_for([scarce, e, e], "least_loaded")
+    reqs = zipf_trace(10, e.cfg.vocab_size, max_prompt=16, max_new=12,
                       seed=5)
     a = router.run(reqs)
-    assert _tokens(a) == _tokens(ec.run(reqs))
+    assert _tokens(a) == _tokens(e.run(reqs))
     assert len(a.replica_of) == 10
+    assert len(set(a.replica_of.values())) == 3
 
 
 def test_least_loaded_3_replicas_sustains_2_5x_in_flight():
     """Acceptance: on the tight-budget Zipf trace, a least_loaded router
     over 3 replicas holds >= 2.5x the in-flight requests of one
-    contiguous engine — with token-identical output."""
+    three-slot engine — with token-identical output."""
     tgt = _tight_target()
-    single = engine_for(slots=8, target=tgt)
-    assert single.num_slots == 3               # tuner capped worst-case
+    single = engine_for(slots=3, target=tgt)
+    assert single.num_slots == 3
     router = router_for([single] * 3, "least_loaded")
     reqs = zipf_trace(18, single.cfg.vocab_size, max_prompt=32, max_new=24,
                       seed=0)
@@ -312,10 +313,10 @@ def test_least_loaded_3_replicas_sustains_2_5x_in_flight():
        policy=st.sampled_from(["round_robin", "least_loaded",
                                "prefix_affinity"]))
 def test_router_conserves_requests_across_preemptions(trace_seed, n, policy):
-    """For random Zipf traces over a mixed fleet whose paged replica is
-    scarce enough to preempt: every admitted request completes exactly
-    once, on exactly one replica — no duplicated or dropped ids."""
-    scarce = engine_for("paged", page_size=8, num_pages=13)  # 96 KV tokens
+    """For random Zipf traces over a mixed fleet whose scarce replica
+    preempts: every admitted request completes exactly once, on exactly
+    one replica — no duplicated or dropped ids."""
+    scarce = engine_for(page_size=8, num_pages=13)  # 96 KV tokens
     roomy = engine_for()
     router = router_for([scarce, roomy], policy)
     reqs = zipf_trace(n, roomy.cfg.vocab_size, max_prompt=16, max_new=12,

@@ -1,6 +1,6 @@
 """Draft-then-verify speculative decoding: the keystone bit-identity
 property (speculative streams == sequential streams, over draft length x
-KV layout x trace x sampling style x preemption pressure), burst page
+page size x trace x sampling style x preemption pressure), burst page
 charging (accepted bursts spend only genuinely free pages; overflow
 verify writes land in junk page 0, never a refcounted shared page), the
 n-gram drafter, the tuner's spec_k pick, and the top_k/top_p request
@@ -25,7 +25,7 @@ from repro.serving import (K_CAP, NGramDrafter, PagedKVCachePool,
                            ReplicaRouter, Request, ServeEngine,
                            effective_top_k, repetitive_trace,
                            trace_repetitiveness, uniform_trace, zipf_trace)
-from repro.training.steps import build_verify_step_slots_paged
+from repro.training.steps import build_verify_step_slots
 
 ARCH = "deepseek-7b-smoke"
 SPEC_ARCH = "picolm-4-smoke"
@@ -35,15 +35,15 @@ _ENGINES: dict = {}
 _BASELINES: dict = {}
 
 
-def engine_for(arch=ARCH, layout="contiguous", page_size=0, num_pages=0,
-               slots=SLOTS, max_len=MAX_LEN):
+def engine_for(arch=ARCH, page_size=16, num_pages=0, slots=SLOTS,
+               max_len=MAX_LEN):
     """Engines are expensive (jit); share them across tests by config."""
-    key = (arch, layout, page_size, num_pages, slots, max_len)
+    key = (arch, page_size, num_pages, slots, max_len)
     if key not in _ENGINES:
         _ENGINES[key] = ServeEngine(
             arch=arch, target="local:cpu", num_slots=slots, max_len=max_len,
-            seed=0, kv_layout=layout, page_size=page_size,
-            num_pages=num_pages, log=lambda *a, **k: None)
+            seed=0, page_size=page_size, num_pages=num_pages,
+            log=lambda *a, **k: None)
     return _ENGINES[key]
 
 
@@ -67,21 +67,19 @@ def _trace(kind, engine, sampled, n=8, max_new=12):
 
 @settings(max_examples=8, deadline=None)
 @given(k=st.sampled_from([1, 2, 4]),
-       layout=st.sampled_from(["contiguous", "paged"]),
+       page_size=st.sampled_from([16, MAX_LEN]),
        kind=st.sampled_from(["zipf", "repetitive"]),
        sampled=st.booleans(),
        tight=st.booleans())
-def test_spec_streams_bit_identical(k, layout, kind, sampled, tight):
-    """spec_k in {1,2,4} x layout x trace x greedy/sampled x page
-    pressure: every combination must reproduce the spec-off streams
-    exactly.  `tight` shrinks the paged page pool so preemption and
-    re-prefill resume interleave with verify bursts."""
-    if tight and layout != "paged":
-        layout = "paged"           # page pressure only exists with pages
+def test_spec_streams_bit_identical(k, page_size, kind, sampled, tight):
+    """spec_k in {1,2,4} x page size (MAX_LEN: one page per slot) x trace
+    x greedy/sampled x page pressure: every combination must reproduce
+    the spec-off streams exactly.  `tight` shrinks the page pool so
+    preemption and re-prefill resume interleave with verify bursts."""
     if tight:
-        engine = engine_for(layout="paged", page_size=8, num_pages=12)
+        engine = engine_for(page_size=8, num_pages=12)
     else:
-        engine = engine_for(layout=layout)
+        engine = engine_for(page_size=page_size)
     reqs = _trace(kind, engine, sampled)
     base_key = (id(engine), kind, sampled)
     if base_key not in _BASELINES:
@@ -97,7 +95,7 @@ def test_spec_accepts_bursts_on_repetitive_smallvocab():
     """On the 4-token-vocab probe arch the greedy continuation is n-gram
     predictable: the drafter must clear >1 accepted-tokens/verify-step
     and finish in strictly fewer scheduler ticks, with identical output."""
-    engine = engine_for(arch=SPEC_ARCH, layout="paged")
+    engine = engine_for(arch=SPEC_ARCH)
     reqs = repetitive_trace(8, engine.cfg.vocab_size, max_new=32, seed=0)
     base = engine.run(reqs, spec_k=0)
     spec = engine.run(reqs, spec_k=4)
@@ -110,8 +108,8 @@ def test_spec_identical_under_preemption_pressure():
     """A page pool too small for the working set: preemptions and
     re-prefill resumes must interleave with verify bursts without
     perturbing the streams."""
-    engine = engine_for(arch=SPEC_ARCH, layout="paged", page_size=8,
-                        num_pages=10, max_len=64)
+    engine = engine_for(arch=SPEC_ARCH, page_size=8, num_pages=10,
+                        max_len=64)
     reqs = repetitive_trace(8, engine.cfg.vocab_size, max_new=24, seed=1)
     base = engine.run(reqs, spec_k=0)
     spec = engine.run(reqs, spec_k=4)
@@ -123,11 +121,11 @@ def test_spec_through_router_fleet():
     """An N=2 fleet with spec on is token-identical to the spec-off
     fleet, and RouterStats aggregates the replica counters."""
     e_on = ServeEngine(arch=SPEC_ARCH, target="local:cpu", num_slots=2,
-                       max_len=MAX_LEN, seed=0, kv_layout="paged",
-                       spec_k=4, log=lambda *a, **k: None)
+                       max_len=MAX_LEN, seed=0, spec_k=4,
+                       log=lambda *a, **k: None)
     reqs = repetitive_trace(6, e_on.cfg.vocab_size, max_new=16, seed=2)
     r_on = ReplicaRouter([e_on] * 2, log=lambda *a, **k: None).run(reqs)
-    e_off = engine_for(arch=SPEC_ARCH, layout="paged", slots=2)
+    e_off = engine_for(arch=SPEC_ARCH, slots=2)
     r_off = ReplicaRouter([e_off] * 2, log=lambda *a, **k: None).run(reqs)
     assert _tokens(r_on) == _tokens(r_off)
     assert r_on.spec_verify_steps == \
@@ -215,7 +213,7 @@ def test_verify_overflow_writes_divert_to_junk_page():
     assert pool.grow_for_burst(s0, 4) == 0             # nothing to back
     before_shared = np.asarray(pool.cache["k"][:, shared])
     before_junk = np.asarray(pool.cache["k"][:, 0])
-    verify = build_verify_step_slots_paged(model)
+    verify = build_verify_step_slots(model)
     logits, new_cache = verify(
         params, pool.cache, jnp.ones((2, 4), jnp.int32),
         jnp.ones((2,), jnp.int32), jnp.asarray(pool.page_table))
@@ -286,9 +284,8 @@ def test_tuner_wires_repetitiveness_into_plan():
     assert "serve_spec" in plans[0.9].napkin
     # spec_k=None defers the ENGINE to the plan's pick
     eng = ServeEngine(arch=SPEC_ARCH, target="local:cpu", num_slots=2,
-                      max_len=MAX_LEN, seed=0, kv_layout="paged",
-                      spec_k=None, repetitiveness=0.9,
-                      log=lambda *a, **k: None)
+                      max_len=MAX_LEN, seed=0, spec_k=None,
+                      repetitiveness=0.9, log=lambda *a, **k: None)
     assert eng.spec_k == spec_k_for(0.9)
 
 
@@ -313,7 +310,7 @@ def test_effective_top_k_surfaced_in_stats():
     assert effective_top_k(K_CAP, 4) == 4
     assert effective_top_k(8, 256) == 8
     assert effective_top_k(0, 256) == 0
-    engine = engine_for(arch=SPEC_ARCH, layout="paged")
+    engine = engine_for(arch=SPEC_ARCH)
     reqs = repetitive_trace(2, engine.cfg.vocab_size, max_new=4, seed=0,
                             temperature=0.7, top_k=K_CAP)
     stats = engine.run(reqs)
@@ -351,14 +348,14 @@ def test_top_p_one_is_bitwise_passthrough():
 
 def test_top_p_filters_and_stays_deterministic_across_layouts():
     kw = dict(max_prompt=16, max_new=10, seed=7, temperature=1.5, top_p=0.5)
-    e_cont = engine_for(layout="contiguous")
-    e_paged = engine_for(layout="paged")
-    nucleus = engine_for().run(zipf_trace(8, e_cont.cfg.vocab_size, **kw))
+    e_cont = engine_for(page_size=MAX_LEN)     # one page per slot
+    e_paged = engine_for()
+    nucleus = e_cont.run(zipf_trace(8, e_cont.cfg.vocab_size, **kw))
     # the filter really bites: some stream must differ from top_p=1.0
     full = e_cont.run(zipf_trace(8, e_cont.cfg.vocab_size,
                                  **{**kw, "top_p": 1.0}))
     assert _tokens(nucleus) != _tokens(full)
-    # same draw on a repeat run and across KV layouts
+    # same draw on a repeat run and across page sizes
     again = e_cont.run(zipf_trace(8, e_cont.cfg.vocab_size, **kw))
     paged = e_paged.run(zipf_trace(8, e_paged.cfg.vocab_size, **kw))
     assert _tokens(nucleus) == _tokens(again) == _tokens(paged)

@@ -30,15 +30,14 @@ ARCH = "picolm-4-smoke"
 _ENGINES: dict = {}
 
 
-def engine_for(layout="paged", page_size=8, num_pages=5, slots=3,
-               max_len=64, spec_k=0):
+def engine_for(page_size=8, num_pages=5, slots=3, max_len=64, spec_k=0):
     """Engines are expensive (jit); share them across tests by config."""
-    key = (layout, page_size, num_pages, slots, max_len, spec_k)
+    key = (page_size, num_pages, slots, max_len, spec_k)
     if key not in _ENGINES:
         _ENGINES[key] = ServeEngine(
             arch=ARCH, target="local:cpu", num_slots=slots, max_len=max_len,
-            seed=0, kv_layout=layout, page_size=page_size,
-            num_pages=num_pages, spec_k=spec_k, log=lambda *a, **k: None)
+            seed=0, page_size=page_size, num_pages=num_pages,
+            spec_k=spec_k, log=lambda *a, **k: None)
     return _ENGINES[key]
 
 
@@ -335,7 +334,7 @@ def test_validator_flags_structural_drift():
     data["cells"]["mystery_cell"] = {"tokens_per_s": 1.0}
     data["cells"]["paged_continuous"].pop("decode_steps")
     data["cells"]["paged_spec_on"]["surprise"] = 1
-    data["cells"]["contiguous_static"]["tokens_per_step"] = "fast"
+    data["cells"]["paged_static"]["tokens_per_step"] = "fast"
     del data["trace_seed"]
     problems = "\n".join(validate_bench.check(data))
     assert "mystery_cell" in problems

@@ -146,19 +146,19 @@ def _paged_step(one_chip, monkeypatch, step):
              "index": S((slots,), jnp.int32)}
     i32 = jnp.int32
     if step == "decode":
-        fn = steps.build_decode_step_slots_paged(model, use_kernel=True)
+        fn = steps.build_decode_step_slots(model, use_kernel=True)
         args = (params, cache, S((slots, 1), i32), S((slots,), i32),
                 S((slots, max_pages), i32))
         jitted = jax.jit(fn, donate_argnums=(1,))
     elif step == "verify":
-        fn = steps.build_verify_step_slots_paged(model)
+        fn = steps.build_verify_step_slots(model)
         args = (params, cache, S((slots, 4), i32), S((slots,), i32),
                 S((slots, max_pages), i32))
         jitted = jax.jit(fn, donate_argnums=(1,))
     else:
         # a one-token chunk too: XLA is freest to pick its own pool
         # layout for a one-row scatter
-        fn = steps.build_prefill_chunk_step_paged(model)
+        fn = steps.build_prefill_chunk_step(model)
         tokens = 1 if step == "chunk1" else 128
         args = (params, cache, S((1, tokens), i32), S((), i32), S((), i32),
                 S((), i32), max_pages * page_size, S((max_pages,), i32))
@@ -243,12 +243,12 @@ def test_mla_step_keeps_the_latent_pool_in_place(one_chip, monkeypatch,
     cache["index"] = S((slots,), jnp.int32)
     i32 = jnp.int32
     if step == "decode":
-        fn = steps.build_decode_step_slots_paged(model, use_kernel=True)
+        fn = steps.build_decode_step_slots(model, use_kernel=True)
         args = (params, cache, S((slots, 1), i32), S((slots,), i32),
                 S((slots, max_pages), i32))
         jitted = jax.jit(fn, donate_argnums=(1,))
     else:
-        fn = steps.build_prefill_chunk_step_paged(model)
+        fn = steps.build_prefill_chunk_step(model)
         tokens = 1 if step == "chunk1" else 128
         args = (params, cache, S((1, tokens), i32), S((), i32), S((), i32),
                 S((), i32), max_pages * page_size, S((max_pages,), i32))
